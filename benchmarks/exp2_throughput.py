@@ -61,8 +61,7 @@ def _count_launches(state, cfg, keys):
     from repro.kernels import gather as _ga
     from repro.kernels import ops as kops
 
-    slots = [(_fs, "find_scan_tlp"), (_fs, "find_scan_pipeline"),
-             (_ds, "digest_scan_tlp"), (_ds, "digest_scan_pipeline"),
+    slots = [(_fs, "find_scan"), (_ds, "digest_scan"),
              (_ga, "gather_rows")]
     originals = {(m, n): getattr(m, n) for m, n in slots}
     counts = {"n": 0}
@@ -174,15 +173,10 @@ def run(csv: Csv | None = None, backend: str = "jnp"):
     # config D (paper Table 5): HBM keys + HMEM (host-tier) values. The
     # paper's claim: the pointer-returning find* is tier-INDEPENDENT (keys
     # never leave HBM); value-copying find pays the host link per row.
-    from repro.core import table as table_mod
 
     tabled = HKVTable.create(capacity=CAPACITY, dim=64, value_tier="hmem",
                              backend="jnp")
     tabled, keys = _fill(tabled, rng, 1.0, ins_shared)
-    # re-pin after the fill: each jitted insert returns a fresh values
-    # array placed by XLA's default (device) memory, undoing the
-    # create-time pinned_host placement the tier measurement needs
-    tabled = tabled.with_state(table_mod.place_value_tier(tabled.state))
     tabled = tabled.with_backend(backend)
     hot = u64.from_uint64(rng.choice(keys, size=BATCH))
     findd_j = jax.jit(lambda t, h, l: t.find(U64(h, l)).values)

@@ -172,8 +172,7 @@ def _count_kernel_launches(emb, table, toks, grads):
     from repro.kernels import scatter as _sc
     from repro.kernels import update_scan as _upd
 
-    slots = [(_upd, "update_scan_tlp"), (_upd, "update_scan_pipeline"),
-             (_ds, "digest_scan_tlp"), (_ds, "digest_scan_pipeline"),
+    slots = [(_upd, "update_scan"), (_ds, "digest_scan"),
              (_ga, "gather_rows"), (_sc, "scatter_rows")]
     originals = {(m, n): getattr(m, n) for m, n in slots}
     counts = {"n": 0}

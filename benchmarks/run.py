@@ -87,6 +87,9 @@ def main() -> None:
                  "the flag")
     sel = set(args)
     commit = _commit() if json_out else ""
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     def want(name):
         return not sel or name in sel

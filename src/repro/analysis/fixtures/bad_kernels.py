@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
 
 N, S, V = 4, 8, 8
 
@@ -34,7 +33,7 @@ def unpaired_dma(values, rows, *, interpret: bool = True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=compat.HBM)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
         out_specs=pl.BlockSpec((1, v), lambda i, r: (i, 0)),
         scratch_shapes=[pltpu.VMEM((1, v), values.dtype),
                         pltpu.SemaphoreType.DMA],
@@ -84,7 +83,7 @@ def direct_hbm_read(values, rows, *, interpret: bool = True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=compat.HBM)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
         out_specs=pl.BlockSpec((1, v), lambda i, r: (i, 0)),
     )
     return pl.pallas_call(

@@ -30,15 +30,17 @@ host/device boundary.
 
 from __future__ import annotations
 
+import re
 from typing import Iterable, Optional
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.analysis.findings import Finding
 from repro.analysis import registry as registry_mod
 
-Literal = jax.core.Literal
+Literal = jax.extend.core.Literal
 
 CHECKER = "kernel-contracts"
 
@@ -46,7 +48,7 @@ CHECKER = "kernel-contracts"
 _PASS = {"convert_element_type", "broadcast_in_dim", "reshape", "squeeze",
          "expand_dims", "copy", "slice", "transpose", "rev", "dynamic_slice"}
 # call-like primitives whose params embed exactly one ClosedJaxpr under "jaxpr"
-_CALLS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
+_CALLS = {"jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
           "custom_vjp_call", "remat", "checkpoint"}
 _DMA = {"dma_start", "dma_wait"}
 _SEM_OK = _DMA | {"semaphore_signal", "semaphore_wait", "get_barrier_semaphore"}
@@ -81,12 +83,14 @@ def iter_pallas_calls(jaxpr):
 
 
 def _space(var) -> Optional[str]:
-    """Memory space of a ref var ('any', 'smem', 'vmem', 'semaphore_mem',
-    'None' for blocked default), or None for non-ref values."""
-    s = str(getattr(var, "aval", ""))
-    if "MemRef<" not in s:
+    """Memory space of a ref var ('any', 'hbm', 'smem', 'vmem',
+    'semaphore_mem', 'None' for blocked default), or None for non-ref
+    values."""
+    m = re.match(r"(?:Mem)?Ref(?:<([^>(]*)[^>]*>)?\{",
+                 str(getattr(var, "aval", "")))
+    if m is None:
         return None
-    return s.split("MemRef<", 1)[1].split(">", 1)[0].split("(")[0]
+    return m.group(1) or "None"
 
 
 def _dtype(var):
@@ -234,7 +238,7 @@ def _check_memory_spaces(name, path, body, events):
     for prim, eqn, origins in events:
         for k, v in enumerate(eqn.invars):
             sp = _space(v)
-            if sp == "any" and prim not in _DMA:
+            if sp in ("any", "hbm") and prim not in _DMA:
                 out.append(Finding(
                     CHECKER, "memory-space", name,
                     f"direct {prim} on an ANY/HBM-space ref (arg "

@@ -17,9 +17,11 @@ import dataclasses
 from typing import Callable, Sequence
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from repro.core.predicates import KINDS
+from repro.core.table import value_plane_shape
 
 B, S, V, N = 8, 8, 8, 8   # buckets, slots/bucket, value width, queries
 Q_TILE = 4                # pipeline-variant tile (must divide N)
@@ -31,7 +33,7 @@ def _planes():
         "digests": jnp.zeros((B, S), jnp.uint8),
         "key_hi": u32(), "key_lo": u32(),
         "score_hi": u32(), "score_lo": u32(),
-        "values": jnp.zeros((B * S, V), jnp.float32),
+        "values": jnp.zeros(value_plane_shape(B * S, V), jnp.float32),
     }
 
 
@@ -48,9 +50,9 @@ def _queries():
 class KernelSpec:
     name: str                 # registry id, e.g. "find_scan_tlp"
     path: str                 # repo-relative source file for findings
-    build: Callable[[], jax.core.ClosedJaxpr]
+    build: Callable[[], jax.extend.core.ClosedJaxpr]
 
-    def trace(self) -> jax.core.ClosedJaxpr:
+    def trace(self) -> jax.extend.core.ClosedJaxpr:
         return self.build()
 
 
@@ -58,37 +60,22 @@ def _trace(fn, *args, **kwargs):
     return jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)
 
 
-def _spec_digest_tlp():
+def _spec_digest(variant):
     from repro.kernels import digest_scan as m
     p, q = _planes(), _queries()
-    return _trace(m.digest_scan_tlp, p["digests"], p["key_hi"], p["key_lo"],
-                  q["bucket1"], q["qdigest"], q["qkey_hi"], q["qkey_lo"])
+    return _trace(m.digest_scan, p["digests"], p["key_hi"], p["key_lo"],
+                  q["bucket1"], q["qdigest"], q["qkey_hi"], q["qkey_lo"],
+                  variant=variant, q_tile=Q_TILE, interpret=True)
 
 
-def _spec_digest_pipeline():
-    from repro.kernels import digest_scan as m
-    p, q = _planes(), _queries()
-    return _trace(m.digest_scan_pipeline, p["digests"], p["key_hi"],
-                  p["key_lo"], q["bucket1"], q["qdigest"], q["qkey_hi"],
-                  q["qkey_lo"], q_tile=Q_TILE)
-
-
-def _spec_find_tlp():
+def _spec_find(variant):
     from repro.kernels import find_scan as m
     p, q = _planes(), _queries()
-    return _trace(m.find_scan_tlp, p["digests"], p["key_hi"], p["key_lo"],
+    return _trace(m.find_scan, p["digests"], p["key_hi"], p["key_lo"],
                   p["score_hi"], p["score_lo"], p["values"],
                   q["bucket1"], q["bucket2"], q["qdigest"], q["qkey_hi"],
-                  q["qkey_lo"])
-
-
-def _spec_find_pipeline():
-    from repro.kernels import find_scan as m
-    p, q = _planes(), _queries()
-    return _trace(m.find_scan_pipeline, p["digests"], p["key_hi"],
-                  p["key_lo"], p["score_hi"], p["score_lo"], p["values"],
-                  q["bucket1"], q["bucket2"], q["qdigest"], q["qkey_hi"],
-                  q["qkey_lo"], q_tile=Q_TILE)
+                  q["qkey_lo"], width=V, variant=variant, q_tile=Q_TILE,
+                  use_digest=True, interpret=True)
 
 
 def _spec_upsert_probe():
@@ -96,14 +83,16 @@ def _spec_upsert_probe():
     p, q = _planes(), _queries()
     return _trace(m.upsert_probe, p["digests"], p["key_hi"], p["key_lo"],
                   p["score_hi"], p["score_lo"], q["bucket1"], q["bucket2"],
-                  q["qdigest"], q["qkey_hi"], q["qkey_lo"])
+                  q["qdigest"], q["qkey_hi"], q["qkey_lo"], q_tile=Q_TILE,
+                  use_digest=True, interpret=True)
 
 
 def _spec_claim_scan():
     from repro.kernels import upsert_scan as m
     p, q = _planes(), _queries()
     return _trace(m.claim_scan, p["key_hi"], p["key_lo"], p["score_hi"],
-                  p["score_lo"], q["bucket1"], jnp.zeros((N,), jnp.int32))
+                  p["score_lo"], q["bucket1"], jnp.zeros((N,), jnp.int32),
+                  q_tile=Q_TILE, interpret=True)
 
 
 def _spec_sweep(kind):
@@ -111,14 +100,14 @@ def _spec_sweep(kind):
     p = _planes()
     op = jnp.zeros((), jnp.uint32)
     return _trace(m.sweep_match, p["key_hi"], p["key_lo"], p["score_hi"],
-                  p["score_lo"], op, op, op, op, kind=kind)
+                  p["score_lo"], op, op, op, op, kind=kind, interpret=True)
 
 
 def _spec_bucket_stats():
     from repro.kernels import score_scan as m
     p = _planes()
     return _trace(m.bucket_stats, p["key_hi"], p["key_lo"], p["score_hi"],
-                  p["score_lo"], bucket_tile=B)
+                  p["score_lo"], bucket_tile=B, interpret=True)
 
 
 def _spec_update(variant):
@@ -132,18 +121,19 @@ def _spec_update(variant):
     dim = V - 1
     qvalid = jnp.ones((N,), jnp.int32)
     grads = jnp.zeros((N, dim), jnp.float32)
-    fn = m.update_scan_tlp if variant == "tlp" else m.update_scan_pipeline
-    kw = {} if variant == "tlp" else {"q_tile": Q_TILE}
-    return _trace(fn, p["digests"], p["key_hi"], p["key_lo"], p["values"],
-                  q["bucket1"], q["bucket2"], q["qdigest"], q["qkey_hi"],
-                  q["qkey_lo"], qvalid, grads, opt=opt, dim=dim, **kw)
+    return _trace(m.update_scan, p["digests"], p["key_hi"], p["key_lo"],
+                  p["values"], q["bucket1"], q["bucket2"], q["qdigest"],
+                  q["qkey_hi"], q["qkey_lo"], qvalid, grads, opt=opt,
+                  dim=dim, width=V, variant=variant, q_tile=Q_TILE,
+                  use_digest=True, interpret=True)
 
 
 def _spec_gather():
     from repro.kernels import gather as m
     p = _planes()
     return _trace(m.gather_rows, p["values"], jnp.zeros((N,), jnp.int32),
-                  jnp.zeros((N,), jnp.int32))
+                  jnp.zeros((N,), jnp.int32), width=V, q_tile=Q_TILE,
+                  interpret=True)
 
 
 def _spec_scatter(add):
@@ -151,19 +141,19 @@ def _spec_scatter(add):
     p = _planes()
     return _trace(m.scatter_rows, p["values"], jnp.zeros((N,), jnp.int32),
                   jnp.zeros((N, V), jnp.float32), jnp.zeros((N,), jnp.int32),
-                  add=add)
+                  add=add, q_tile=Q_TILE, interpret=True)
 
 
 def kernel_specs() -> Sequence[KernelSpec]:
     specs = [
         KernelSpec("digest_scan_tlp", "src/repro/kernels/digest_scan.py",
-                   _spec_digest_tlp),
+                   lambda: _spec_digest("tlp")),
         KernelSpec("digest_scan_pipeline", "src/repro/kernels/digest_scan.py",
-                   _spec_digest_pipeline),
+                   lambda: _spec_digest("pipeline")),
         KernelSpec("find_scan_tlp", "src/repro/kernels/find_scan.py",
-                   _spec_find_tlp),
+                   lambda: _spec_find("tlp")),
         KernelSpec("find_scan_pipeline", "src/repro/kernels/find_scan.py",
-                   _spec_find_pipeline),
+                   lambda: _spec_find("pipeline")),
         KernelSpec("upsert_probe", "src/repro/kernels/upsert_scan.py",
                    _spec_upsert_probe),
         KernelSpec("claim_scan", "src/repro/kernels/upsert_scan.py",

@@ -129,8 +129,8 @@ def locate(state: HKVState, cfg: HKVConfig, keys: U64, probe: Probe | None = Non
     return Locate(found=found, bucket=bucket, slot=slot, row=bucket * s + slot)
 
 
-def gather_values(state: HKVState, loc: Locate, dim: int | None = None,
-                  tier: str = "hbm") -> jax.Array:
+def gather_values(state: HKVState, loc: Locate, dim: int | None,
+                  cfg: HKVConfig) -> jax.Array:
     """Position-addressed value gather (paper §3.6): row = bucket*S + slot.
 
     Missing keys return zeros.  `dim` trims aux optimizer-state columns.
@@ -138,7 +138,8 @@ def gather_values(state: HKVState, loc: Locate, dim: int | None = None,
     """
     from repro.core import table as table_mod
 
-    rows = table_mod.tier_gather(tier, state.values, loc.row)
+    rows = table_mod.tier_gather(cfg.value_tier, state.values, loc.row,
+                                 cfg.total_value_dim)
     if dim is not None:
         rows = rows[:, :dim]
     return jnp.where(loc.found[:, None], rows, jnp.zeros_like(rows))

@@ -179,10 +179,9 @@ def _dedupe_sort(keys: U64):
     valid keys).
     """
     n = keys.hi.shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    hi_s, lo_s, idx_s = jax.lax.sort((keys.hi, keys.lo, iota), num_keys=2, is_stable=True)
-    keys_s = U64(hi_s, lo_s)
-    prev = U64(jnp.roll(hi_s, 1), jnp.roll(lo_s, 1))
+    idx_s = u64.lexsort((keys.hi, keys.lo))
+    keys_s = U64(keys.hi[idx_s], keys.lo[idx_s])
+    prev = U64(jnp.roll(keys_s.hi, 1), jnp.roll(keys_s.lo, 1))
     is_first = jnp.concatenate(
         [jnp.ones((1,), bool), u64.ne(keys_s, prev).astype(bool)[1:]]
     )
@@ -276,39 +275,32 @@ def _jnp_victim_at_rank(state: HKVState, cfg: HKVConfig, bkt_g: jax.Array,
     n = bkt_g.shape[0]
     s = cfg.slots_per_bucket
     row_occ = ~u64.is_empty(U64(state.key_hi[bkt_g], state.key_lo[bkt_g]))
-    slot_iota = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (n, s))
-    v_occ, v_shi, v_slo, v_khi, v_klo, v_slot = jax.lax.sort(
-        (
-            row_occ.astype(jnp.uint32),
-            state.score_hi[bkt_g],
-            state.score_lo[bkt_g],
-            state.key_hi[bkt_g],
-            state.key_lo[bkt_g],
-            slot_iota,
-        ),
-        dimension=1,
-        num_keys=6,
-        is_stable=False,
-    )
+    occ, shi, slo, khi, klo = (row_occ.astype(jnp.uint32),
+                               state.score_hi[bkt_g], state.score_lo[bkt_g],
+                               state.key_hi[bkt_g], state.key_lo[bkt_g])
+    # slot order breaks the remaining ties: lexsort keeps index order
+    order = u64.lexsort((occ, shi, slo, khi, klo), axis=1)
     r_cl = jnp.clip(rank, 0, s - 1)[:, None]
-    take = lambda a: jnp.take_along_axis(a, r_cl, axis=1)[:, 0]
+    v_slot = jnp.take_along_axis(order, r_cl, axis=1)
+    take = lambda a: jnp.take_along_axis(a, v_slot, axis=1)[:, 0]
     return (
-        take(v_slot),
-        take(v_occ).astype(bool),
-        U64(take(v_shi), take(v_slo)),
-        U64(take(v_khi), take(v_klo)),
+        v_slot[:, 0],
+        take(occ).astype(bool),
+        U64(take(shi), take(slo)),
+        U64(take(khi), take(klo)),
     )
 
 
 def _jnp_gather_values(cfg: HKVConfig, values: jax.Array, rows: jax.Array,
                        mask: jax.Array) -> jax.Array:
-    out = table_mod.tier_gather(cfg.value_tier, values, jnp.where(mask, rows, 0))
+    out = table_mod.tier_gather(cfg.value_tier, values, jnp.where(mask, rows, 0),
+                                cfg.total_value_dim)
     return jnp.where(mask[:, None], out, jnp.zeros_like(out))
 
 
 def _jnp_scatter_values(cfg: HKVConfig, values: jax.Array, rows: jax.Array,
                         updates: jax.Array, mask: jax.Array) -> jax.Array:
-    oob = values.shape[0]  # mode='drop' discards masked-out lanes
+    oob = cfg.capacity  # past the last slot: dropped (masked-out lanes)
     return table_mod.tier_scatter(
         cfg.value_tier, values, jnp.where(mask, rows, oob), updates
     )
@@ -355,7 +347,7 @@ def upsert(
     """
     n = keys.hi.shape[0]
     b, s = cfg.num_buckets, cfg.slots_per_bucket
-    vdim = state.values.shape[1]
+    vdim = cfg.total_value_dim
     policy = cfg.policy
     if insert_values is None:
         insert_values = values
@@ -416,23 +408,12 @@ def upsert(
 
     # bucket-sort the misses: (bucket, score desc, key asc) — canonical order
     bkt_key = jnp.where(miss, target, b).astype(jnp.int32)
-    (bkt_m, _nsh, _nsl, khi_m, klo_m, idx_m, vrow_m, dig_m, shi_m, slo_m, gid_m) = jax.lax.sort(
-        (
-            bkt_key,
-            ~init_sc.hi,       # bitwise-not => descending score order
-            ~init_sc.lo,
-            keys_s.hi,
-            keys_s.lo,
-            idx_s,
-            last_idx_s,
-            probe_s.digest,
-            init_sc.hi,
-            init_sc.lo,
-            gid,
-        ),
-        num_keys=5,
-        is_stable=False,
-    )
+    # (bitwise-not => descending score order)
+    order = u64.lexsort((bkt_key, ~init_sc.hi, ~init_sc.lo, keys_s.hi,
+                         keys_s.lo))
+    (bkt_m, khi_m, klo_m, idx_m, vrow_m, dig_m, shi_m, slo_m, gid_m) = (
+        a[order] for a in (bkt_key, keys_s.hi, keys_s.lo, idx_s, last_idx_s,
+                           probe_s.digest, init_sc.hi, init_sc.lo, gid))
     mask_m = bkt_m < b
     iota = jnp.arange(n, dtype=jnp.int32)
     is_newb = jnp.concatenate([jnp.ones((1,), bool), (bkt_m[1:] != bkt_m[:-1])])

@@ -133,8 +133,8 @@ def _gather_shared(state: HKVState, cfg: HKVConfig, loc, dim):
         from repro.kernels import ops as kernel_ops
 
         return kernel_ops.gather_rows_kernel(
-            state, loc, state.values.shape[1] if dim is None else dim)
-    return find_mod.gather_values(state, loc, dim, cfg.value_tier)
+            state, cfg, loc, cfg.total_value_dim if dim is None else dim)
+    return find_mod.gather_values(state, loc, dim, cfg)
 
 
 @roles.reader
@@ -159,11 +159,11 @@ def find(state: HKVState, cfg: HKVConfig, keys: U64,
             return FindResult(values=r.values[:, : cfg.dim], found=r.found,
                               score_hi=r.score_hi, score_lo=r.score_lo)
         loc = find_mod.locate(state, cfg, keys)
-        vals = find_mod.gather_values(state, loc, cfg.dim, cfg.value_tier)
+        vals = find_mod.gather_values(state, loc, cfg.dim, cfg)
     elif _resolve_backend(backend) == "kernel":
         vals = _gather_shared(state, cfg, loc, cfg.dim)
     else:
-        vals = find_mod.gather_values(state, loc, cfg.dim, cfg.value_tier)
+        vals = find_mod.gather_values(state, loc, cfg.dim, cfg)
     if telemetry is not None:
         telemetry.record(
             "find", _obs().observe_find(state, cfg, keys, loc.found))
@@ -240,11 +240,11 @@ def find_rows(state: HKVState, cfg: HKVConfig, keys: U64,
             return FindRowsResult(rows=r.values, found=r.found, row=r.row,
                                   score_hi=r.score_hi, score_lo=r.score_lo)
         loc = find_mod.locate(state, cfg, keys)
-        rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        rows = find_mod.gather_values(state, loc, None, cfg)
     elif _resolve_backend(backend) == "kernel":
         rows = _gather_shared(state, cfg, loc, None)
     else:
-        rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        rows = find_mod.gather_values(state, loc, None, cfg)
     if telemetry is not None:
         telemetry.record(
             "find_rows", _obs().observe_find(state, cfg, keys, loc.found))
@@ -294,6 +294,7 @@ def export_batch(
         cfg.value_tier, state.values,
         jnp.arange(bucket_start * s, (bucket_start + bucket_count) * s,
                    dtype=jnp.int32),
+        cfg.total_value_dim,
     )
     return ExportResult(
         key_hi=khi,
@@ -351,12 +352,12 @@ def assign(
     b, s = cfg.num_buckets, cfg.slots_per_bucket
     # last-writer-wins on within-batch duplicates: scatter in batch order
     row = jnp.where(loc.found, loc.row, b * s)
-    vdim = state.values.shape[1]
+    vdim = cfg.total_value_dim
     if values.shape[1] < vdim:  # caller wrote only the embedding columns
         pad = jnp.zeros((values.shape[0], vdim - values.shape[1]), state.values.dtype)
         old = table_mod.tier_gather(
-            cfg.value_tier, state.values, jnp.clip(loc.row, 0, b * s - 1)
-        )[:, values.shape[1]:]
+            cfg.value_tier, state.values, jnp.clip(loc.row, 0, b * s - 1),
+            vdim)[:, values.shape[1]:]
         values = jnp.concatenate([values, jnp.where(loc.found[:, None], old, pad)], axis=1)
     new_values = table_mod.tier_scatter(
         cfg.value_tier, state.values, row, values.astype(state.values.dtype)
@@ -400,9 +401,9 @@ def assign_add(
             "assign_add", _obs().observe_update(state, cfg, keys, loc.found))
     b, s = cfg.num_buckets, cfg.slots_per_bucket
     row = jnp.where(loc.found, loc.row, b * s)
-    if deltas.shape[1] < state.values.shape[1]:
+    if deltas.shape[1] < cfg.total_value_dim:
         pad = jnp.zeros(
-            (deltas.shape[0], state.values.shape[1] - deltas.shape[1]), state.values.dtype
+            (deltas.shape[0], cfg.total_value_dim - deltas.shape[1]), state.values.dtype
         )
         deltas = jnp.concatenate([deltas.astype(state.values.dtype), pad], axis=1)
     return state._replace(values=table_mod.tier_scatter(
@@ -495,11 +496,11 @@ def update_rows(
         return UpdateRowsResult(state=r.state, found=r.found)
     if loc is None:
         loc = find_mod.locate(state, cfg, keys)
-        rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        rows = find_mod.gather_values(state, loc, None, cfg)
     elif _resolve_backend(backend) == "kernel":
         rows = _gather_shared(state, cfg, loc, None)
     else:
-        rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        rows = find_mod.gather_values(state, loc, None, cfg)
     if telemetry is not None:
         telemetry.record(
             "update_rows", _obs().observe_update(state, cfg, keys, loc.found))
@@ -558,7 +559,7 @@ def insert_or_assign(
     Consumer code: prefer `HKVTable.insert_or_assign` (repro.core.api).
     """
     res = merge_mod.upsert(
-        state, cfg, keys, _pad_aux(values, state), custom_scores=custom_scores,
+        state, cfg, keys, pad_rows(values, cfg), custom_scores=custom_scores,
         stages=_upsert_stages(backend, cfg),
     )
     if telemetry is not None:
@@ -596,7 +597,7 @@ def insert_and_evict(
         state,
         cfg,
         keys,
-        _pad_aux(values, state),
+        pad_rows(values, cfg),
         custom_scores=custom_scores,
         return_evicted=True,
         stages=_upsert_stages(backend, cfg),
@@ -653,7 +654,7 @@ def find_or_insert(
         state,
         cfg,
         keys,
-        _pad_aux(init_values, state),
+        pad_rows(init_values, cfg),
         custom_scores=custom_scores,
         write_hit_values=False,
         return_evicted=return_evicted,
@@ -677,10 +678,9 @@ def _gather_post(res: MergeResult, cfg: HKVConfig, init_values: jax.Array,
     if _resolve_backend(backend) == "kernel" and cfg.value_tier == "hbm":
         from repro.kernels import ops as kernel_ops
 
-        vals = kernel_ops.gather_rows_kernel(res.state, res.loc, cfg.dim)
+        vals = kernel_ops.gather_rows_kernel(res.state, cfg, res.loc, cfg.dim)
     else:
-        vals = find_mod.gather_values(res.state, res.loc, cfg.dim,
-                                      cfg.value_tier)
+        vals = find_mod.gather_values(res.state, res.loc, cfg.dim, cfg)
     return jnp.where(res.loc.found[:, None], vals, init_values[:, : cfg.dim])
 
 
@@ -702,7 +702,7 @@ def accum_or_assign(
     admission-controlled)."""
     n = keys.hi.shape[0]
     d = merge_mod.dedupe_keys(keys)
-    v = _pad_aux(values, state)
+    v = pad_rows(values, cfg)
     v_sum = jax.ops.segment_sum(v[d.idx_sorted], d.gid, num_segments=n)[d.gid]
     # phase 1: += on existing keys (updater-style, but score-touching)
     state2 = assign_add(state, cfg, d.unique, v_sum)
@@ -744,7 +744,7 @@ def ingest(
     touched per policy — find_or_insert without the value readback (the
     deferred-structural overlapped-ingest schedule, §3.5/Exp#3e)."""
     res = merge_mod.upsert(
-        state, cfg, keys, _pad_aux(init_values, state),
+        state, cfg, keys, pad_rows(init_values, cfg),
         custom_scores=custom_scores, write_hit_values=False,
         stages=_upsert_stages(backend, cfg),
     )
@@ -777,7 +777,7 @@ def erase(state: HKVState, cfg: HKVConfig, keys: U64, *,
         score_lo=state.score_lo.at[hb, loc.slot].set(jnp.zeros((n,), jnp.uint32), mode="drop"),
         values=table_mod.tier_scatter(
             cfg.value_tier, state.values, row,
-            jnp.zeros((n, state.values.shape[1]), state.values.dtype),
+            jnp.zeros((n, cfg.total_value_dim), state.values.dtype),
         ),
     )
 
@@ -840,7 +840,8 @@ def _erase_slots(state: HKVState, cfg: HKVConfig, mask: jax.Array) -> HKVState:
         score_hi=jnp.where(mask, jnp.uint32(0), state.score_hi),
         score_lo=jnp.where(mask, jnp.uint32(0), state.score_lo),
         values=table_mod.tier_mask_rows(cfg.value_tier, state.values,
-                                        ~mask.reshape(-1)),
+                                        ~mask.reshape(-1),
+                                        cfg.total_value_dim),
     )
 
 
@@ -886,24 +887,13 @@ def evict_if(state: HKVState, cfg: HKVConfig, pred, budget: int, *,
     budget = min(budget, c)
     mask = _sweep_mask(state, cfg, pred, backend)
     flat = mask.reshape(-1)
-    iota = jnp.arange(c, dtype=jnp.int32)
     # candidates first, ordered coldest-first: sort by (non-candidate,
     # score, key); keys are unique table-wide, so the order is total
-    nc, _sh, _sl, _kh, _kl, row = jax.lax.sort(
-        (
-            (~flat).astype(jnp.uint32),
-            state.score_hi.reshape(-1),
-            state.score_lo.reshape(-1),
-            state.key_hi.reshape(-1),
-            state.key_lo.reshape(-1),
-            iota,
-        ),
-        num_keys=5,
-        is_stable=False,
-    )
-    top = lambda a: a[:budget]
-    row_t = top(row)
-    lane = top(nc) == 0
+    row = u64.lexsort(((~flat).astype(jnp.uint32),
+                       state.score_hi.reshape(-1), state.score_lo.reshape(-1),
+                       state.key_hi.reshape(-1), state.key_lo.reshape(-1)))
+    row_t = row[:budget]
+    lane = flat[row_t]
     if limit is not None:
         lane &= jnp.arange(budget, dtype=jnp.int32) < limit
     bkt = row_t // s
@@ -911,7 +901,8 @@ def evict_if(state: HKVState, cfg: HKVConfig, pred, budget: int, *,
     khi = state.key_hi[bkt, slot]
     klo = state.key_lo[bkt, slot]
     vals = table_mod.tier_gather(cfg.value_tier, state.values,
-                                 jnp.where(lane, row_t, 0))
+                                 jnp.where(lane, row_t, 0),
+                                 cfg.total_value_dim)
     vals = jnp.where(lane[:, None], vals, jnp.zeros_like(vals))
     stream = EvictionStream(
         key_hi=jnp.where(lane, khi, 0),
@@ -937,7 +928,7 @@ def evict_if(state: HKVState, cfg: HKVConfig, pred, budget: int, *,
             jnp.zeros((nlanes,), jnp.uint32), mode="drop"),
         values=table_mod.tier_scatter(
             cfg.value_tier, state.values, jnp.where(lane, row_t, c),
-            jnp.zeros((nlanes, state.values.shape[1]), state.values.dtype),
+            jnp.zeros((nlanes, cfg.total_value_dim), state.values.dtype),
         ),
     )
     count = jnp.sum(lane.astype(jnp.int32))
@@ -951,16 +942,12 @@ def evict_if(state: HKVState, cfg: HKVConfig, pred, budget: int, *,
 # =============================================================================
 
 
-def pad_rows(values: jax.Array, plane: jax.Array) -> jax.Array:
-    """Zero-pad caller rows up to the value plane's width (aux optimizer
+def pad_rows(values: jax.Array, cfg: HKVConfig) -> jax.Array:
+    """Zero-pad caller rows up to the logical row width (aux optimizer
     cols) — the ONE padding/dtype point every row-writing path shares
     (flat ops here, the tier hierarchy in `core/tiered.py`)."""
-    vdim = plane.shape[1]
+    vdim = cfg.total_value_dim
     if values.shape[1] == vdim:
-        return values.astype(plane.dtype)
-    pad = jnp.zeros((values.shape[0], vdim - values.shape[1]), plane.dtype)
-    return jnp.concatenate([values.astype(plane.dtype), pad], axis=1)
-
-
-def _pad_aux(values: jax.Array, state: HKVState) -> jax.Array:
-    return pad_rows(values, state.values)
+        return values.astype(cfg.value_dtype)
+    pad = jnp.zeros((values.shape[0], vdim - values.shape[1]), cfg.value_dtype)
+    return jnp.concatenate([values.astype(cfg.value_dtype), pad], axis=1)

@@ -394,7 +394,7 @@ class TieredHKVTable:
         also covers hot-rejected pairs absorbed by the cold tier."""
         k = normalize_keys(keys)
         cs = _opt_keys(custom_scores)
-        values = ops_mod.pad_rows(values, self.hot.state.values)
+        values = ops_mod.pad_rows(values, self.hot.cfg)
         res = ops_mod.insert_and_evict(
             self.hot.state, self.hot.cfg, k, values,
             custom_scores=cs, backend=self.hot.backend, telemetry=telemetry,
@@ -444,7 +444,7 @@ class TieredHKVTable:
         # optimizer columns travel with a promoted embedding
         cold_rows = self.cold.find_rows(_mask_keys(k, ~hot_pre))
         cold_hit = cold_rows.found
-        init_full = ops_mod.pad_rows(init_values, self.hot.state.values)
+        init_full = ops_mod.pad_rows(init_values, self.hot.cfg)
         admit_rows = jnp.where(cold_hit[:, None], cold_rows.rows, init_full)
         res = ops_mod.find_or_insert(
             self.hot.state, self.hot.cfg, k, admit_rows, custom_scores=cs,

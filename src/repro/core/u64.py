@@ -165,17 +165,32 @@ def add(a: U64, b: U64) -> U64:
 
 
 # ---------------------------------------------------------------------------
-# Sortable encoding: map a U64 batch to a single sortable array.
+# Multi-key sorting.
 #
-# TPU sorts are cheapest on a single 32-bit key. Where full 64-bit order is
-# required we sort on two keys via lax.sort; where an *approximate-but-total*
-# order suffices (never here) one could pack. These helpers produce the
-# operand lists for jax.lax.sort.
+# A u64 order needs two 32-bit sort keys (hi major, lo minor), and the batch
+# closure orders by up to six.  The TPU compiler builds one `lax.sort` with k
+# keys and m operands in time that grows steeply with both (a 5-key,
+# 11-operand sort of 2^16 elements takes minutes), so orders are built as
+# one stable single-key sort of (key, permutation) per key, least
+# significant first, and payloads are gathered through the permutation.
 # ---------------------------------------------------------------------------
 
-def sort_operands(a: U64) -> list:
-    """Operands establishing u64 order for jax.lax.sort (hi major, lo minor)."""
-    return [a.hi, a.lo]
+def lexsort(keys, axis: int = 0) -> jax.Array:
+    """int32 permutation ordering `keys` (most significant first)
+    lexicographically along `axis`; ties keep their index order, as a
+    stable `lax.sort` of (*keys, iota) would."""
+    axis = axis % keys[0].ndim
+    perm = None
+    for k in reversed(keys):
+        if perm is None:
+            iota = jax.lax.broadcasted_iota(jnp.int32, k.shape, axis)
+            _, perm = jax.lax.sort((k, iota), dimension=axis, num_keys=1,
+                                   is_stable=True)
+        else:
+            kp = jnp.take_along_axis(k, perm, axis=axis)
+            _, perm = jax.lax.sort((kp, perm), dimension=axis, num_keys=1,
+                                   is_stable=True)
+    return perm
 
 
 # ---------------------------------------------------------------------------

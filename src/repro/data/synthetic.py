@@ -42,9 +42,14 @@ def _fmix64(x: np.ndarray) -> np.ndarray:
     return x
 
 
+def rank_keys(ranks: np.ndarray) -> np.ndarray:
+    """The uint64 feature ID of each popularity rank (fmix64, a bijection)."""
+    return _fmix64(ranks)
+
+
 def zipf_keys(rng: np.random.Generator, n: int, alpha: float, key_space: int) -> np.ndarray:
     """Power-law uint64 feature IDs: rank -> fmix64(rank) (hot set scattered)."""
-    return _fmix64(zipf_ranks(rng, n, alpha, key_space))
+    return rank_keys(zipf_ranks(rng, n, alpha, key_space))
 
 
 # =============================================================================

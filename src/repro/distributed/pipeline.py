@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(mesh, axis: str, stage_fn, stage_params, x_microbatches):

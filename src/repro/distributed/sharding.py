@@ -24,23 +24,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across JAX generations.
-
-    Newer releases expose it as `jax.shard_map(..., check_vma=...)`; older
-    ones as `jax.experimental.shard_map.shard_map(..., check_rep=...)` (the
-    same replication-checking switch under its pre-rename spelling).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 _RULES = {
     # leaf name -> base spec (without leading stack dims)
     "table": ("model", None),

@@ -51,8 +51,15 @@ from repro.core.merge import EvictionStream
 from repro.core.ops import ExportResult
 from repro.core.tiered import TieredHKVTable, TieredState
 from repro.core.u64 import U64
-from repro.distributed.sharding import shard_map
 from repro.embedding.dynamic import HKVEmbedding
+
+
+def _smap(body, *, mesh, in_specs, out_specs):
+    """`jax.shard_map` compiled as one program.  Called eagerly, a
+    shard_map body runs primitive by primitive, compiling each one afresh
+    on every call; under an enclosing jit this inner jit is inlined."""
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _obs_tel():
@@ -70,6 +77,16 @@ class ShardedHKVEmbedding:
     emb: HKVEmbedding              # GLOBAL capacity; local = capacity / n_shards
     axis_names: tuple              # mesh axes the table shards over
     capacity_factor: float = 2.0
+
+    def __post_init__(self):
+        e = self.emb
+        if e.value_tier == "hmem" or (e.is_tiered
+                                      and e.cold_value_tier == "hmem"):
+            raise NotImplementedError(
+                "a sharded table keeps every value plane in device memory: "
+                "a shard_map body cannot place an array in host memory, so "
+                "value_tier='hmem' / cold_value_tier='hmem' is not supported "
+                "here (pass 'hbm')")
 
     def local_embedding(self, n_shards: int) -> HKVEmbedding:
         def shard_cap(c):
@@ -275,10 +292,7 @@ class ShardedHKVEmbedding:
             return local.create().state
 
         specs = self.state_specs()
-        return jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(), out_specs=specs,
-                          check_vma=False)
-        )()
+        return _smap(body, mesh=mesh, in_specs=(), out_specs=specs)()
 
     def state_specs(self):
         ax = self.axis_names
@@ -317,11 +331,10 @@ class ShardedHKVEmbedding:
             return state, rows[inv], ovf.reshape(1)  # rank-1 for out_specs
 
         specs = self.state_specs()
-        out = shard_map(
+        out = _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp, None)),
             out_specs=(specs, P(dp, None), P(dp)),
-            check_vma=False,
         )(state, tokens.reshape(tokens.shape[0], -1))
         state, rows, ovf = out
         return state, rows.reshape(tokens.shape + (self.emb.dim,)), jnp.sum(ovf)
@@ -368,11 +381,10 @@ class ShardedHKVEmbedding:
         out_specs = (specs, P(dp, None), P(dp), P(dp))
         if with_tel:
             out_specs = out_specs + (P(),)  # psum-replicated counters
-        out = shard_map(
+        out = _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp), P(dp)),
             out_specs=out_specs,
-            check_vma=False,
         )(state, keys.hi, keys.lo)
         if with_tel:
             state, rows, found, ovf, tel = out
@@ -411,11 +423,10 @@ class ShardedHKVEmbedding:
         out_specs = (specs, P(dp), P(dp))
         if with_tel:
             out_specs = out_specs + (P(),)
-        out = shard_map(
+        out = _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp), P(dp), P(dp, None)),
             out_specs=out_specs,
-            check_vma=False,
         )(state, keys.hi, keys.lo, values)
         if with_tel:
             state, status, ovf, tel = out
@@ -435,11 +446,10 @@ class ShardedHKVEmbedding:
             return self._assign_body(n_shards, cap, state, khi, klo, v)
 
         specs = self.state_specs()
-        return shard_map(
+        return _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp), P(dp), P(dp, None)),
             out_specs=specs,
-            check_vma=False,
         )(state, keys.hi, keys.lo, values)
 
     def erase_keys(self, mesh, state, keys: U64):
@@ -453,11 +463,10 @@ class ShardedHKVEmbedding:
             return self._erase_body(n_shards, cap, state, khi, klo)
 
         specs = self.state_specs()
-        return shard_map(
+        return _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp), P(dp)),
             out_specs=specs,
-            check_vma=False,
         )(state, keys.hi, keys.lo)
 
     def apply_grads(self, mesh, state, tokens, grads):
@@ -478,11 +487,10 @@ class ShardedHKVEmbedding:
             return self._grad_body(n_shards, cap, state, uk.hi, uk.lo, g_uniq)
 
         specs = self.state_specs()
-        return shard_map(
+        return _smap(
             body, mesh=mesh,
             in_specs=(specs, P(dp, None), P(dp, None, None)),
             out_specs=specs,
-            check_vma=False,
         )(state, tokens.reshape(tokens.shape[0], -1),
           grads.reshape(tokens.shape[0], -1, self.emb.dim))
 
@@ -649,9 +657,8 @@ class ShardedHKVTable:
         def body(state):
             return local.wrap(state).clear().state
 
-        return self.with_state(shard_map(
+        return self.with_state(_smap(
             body, mesh=self.mesh, in_specs=(specs,), out_specs=specs,
-            check_vma=False,
         )(self.state))
 
     def contains(self, keys) -> jax.Array:
@@ -675,10 +682,10 @@ class ShardedHKVTable:
             r = local.wrap(state).erase_if(p)
             return r.table.state, r.swept.reshape(1)
 
-        state, swept = shard_map(
+        state, swept = _smap(
             body, mesh=self.mesh,
             in_specs=(specs, jax.tree.map(lambda _: P(), pred)),
-            out_specs=(specs, P(ax)), check_vma=False,
+            out_specs=(specs, P(ax)),
         )(self.state, pred)
         return ShardedSweep(table=self.with_state(state),
                             swept=jnp.sum(swept))
@@ -695,10 +702,10 @@ class ShardedHKVTable:
         stream_specs = EvictionStream(
             key_hi=P(ax), key_lo=P(ax), values=P(ax, None),
             score_hi=P(ax), score_lo=P(ax), mask=P(ax))
-        state, stream, count = shard_map(
+        state, stream, count = _smap(
             body, mesh=self.mesh,
             in_specs=(specs, jax.tree.map(lambda _: P(), pred)),
-            out_specs=(specs, tuple(stream_specs), P(ax)), check_vma=False,
+            out_specs=(specs, tuple(stream_specs), P(ax)),
         )(self.state, pred)
         return ShardedEvictIf(table=self.with_state(state),
                               evicted=EvictionStream(*stream),
@@ -753,10 +760,9 @@ class ShardedHKVTable:
             return tuple(local.wrap(state).export_batch(
                 bucket_start, bucket_count))
 
-        out = shard_map(
+        out = _smap(
             body, mesh=self.mesh, in_specs=(specs,),
             out_specs=(P(ax), P(ax), P(ax, None), P(ax), P(ax), P(ax)),
-            check_vma=False,
         )(self.state)
         return ExportResult(*out)
 
@@ -770,9 +776,8 @@ class ShardedHKVTable:
             # hot/cold copies exactly like a single-device tiered table
             return local.wrap(state).size().astype(jnp.int32).reshape(1)
 
-        per_shard = shard_map(
+        per_shard = _smap(
             body, mesh=self.mesh, in_specs=(specs,), out_specs=P(ax),
-            check_vma=False,
         )(self.state)
         return jnp.sum(per_shard)
 

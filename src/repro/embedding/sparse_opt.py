@@ -36,6 +36,19 @@ def _rounded(p: jax.Array) -> jax.Array:
     return p + (p - p)
 
 
+def _mean_sq(col, dim: int) -> jax.Array:
+    """Mean of the squares of columns col(0..dim-1), summed left to right.
+
+    The order is explicit because a reduction's order is the compiler's
+    choice and differs between a [1, dim] row and an [N, dim] batch; an
+    unrolled chain of rounded products rounds alike in both.
+    """
+    total = _rounded(col(0) * col(0))
+    for j in range(1, dim):
+        total = total + _rounded(col(j) * col(j))
+    return total / dim
+
+
 @dataclasses.dataclass(frozen=True)
 class SparseOptimizer:
     name: str = "rowwise_adagrad"
@@ -68,7 +81,7 @@ class SparseOptimizer:
             m = rnd(self.momentum * aux) + g
             return jnp.concatenate([emb - rnd(self.lr * m), m], axis=1)
         if self.name == "rowwise_adagrad":
-            acc = aux[:, 0] + rnd(jnp.mean(g * g, axis=1))
+            acc = aux[:, 0] + _mean_sq(lambda j: g[:, j], dim)
             step = self.lr / (jnp.sqrt(acc) + self.eps)
             return jnp.concatenate(
                 [emb - rnd(step[:, None] * g), acc[:, None]], axis=1)
@@ -79,3 +92,42 @@ class SparseOptimizer:
                 axis=1,
             )
         raise ValueError(self.name)
+
+    def apply_lanes(self, x: jax.Array, g: jax.Array, dim: int,
+                    roll) -> jax.Array:
+        """`apply` on one row laid out along the lanes of a vector row.
+
+        x [1, L] holds [embedding | aux] at lanes [0, dim + aux); g [1, L]
+        the gradient at lanes [0, dim) (zeros beyond); lanes past the row
+        come back unchanged.  `roll(v, shift)` rotates lanes (the kernel's
+        `pltpu.roll`).  Every lane computes `apply`'s own formula, so a
+        kernel row and the batched reference agree bitwise.
+        """
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        lanes = x.shape[1]
+        emb_lane = lane < dim
+        g = g.astype(x.dtype)
+        rnd = _rounded
+        if self.name == "sgd":
+            return jnp.where(emb_lane, x - rnd(self.lr * g), x)
+        if self.name == "rowwise_adagrad":
+            aux0 = jnp.sum(jnp.where(lane == dim, x, 0), axis=1, keepdims=True)
+            col = lambda j: jnp.sum(jnp.where(lane == j, g, 0), axis=1,
+                                    keepdims=True)
+            acc = aux0 + _mean_sq(col, dim)
+            step = self.lr / (jnp.sqrt(acc) + self.eps)
+            out = jnp.where(lane == dim, acc, x)
+            return jnp.where(emb_lane, x - rnd(step * g), out)
+        aux = roll(x, lanes - dim)          # aux lane dim+j -> lane j
+        if self.name == "sgdm":
+            m = rnd(self.momentum * aux) + g
+            emb, new_aux = x - rnd(self.lr * m), m
+        elif self.name == "adagrad":
+            new_aux = aux + rnd(g * g)
+            emb = x - rnd(self.lr * g / (jnp.sqrt(new_aux) + self.eps))
+        else:
+            raise ValueError(self.name)
+        out = jnp.where((lane >= dim) & (lane < 2 * dim),
+                        roll(new_aux, dim), x)
+        return jnp.where(emb_lane, emb, out)
+

@@ -11,18 +11,16 @@ of the hierarchy (DESIGN.md §2):
                             set (the paper's "definitive miss in one
                             transaction" property).
   __vcmpeq4 SIMD scan   ->  a single int-eq over the lane dimension (VPU).
-  __pipeline_memcpy_async-> explicit HBM->VMEM ``make_async_copy`` with a
-                            two-deep double buffer: query q+1's bucket row
-                            streams in while query q is compared (the
-                            paper's Pipeline kernel, §4.3).
+  __pipeline_memcpy_async-> explicit HBM->VMEM ``make_async_copy`` of the
+                            query's bucket rows (``bucket_rows``).
 
-Two variants, mirroring the paper's kernel-selection tiers:
+Two variants, mirroring the paper's kernel-selection tiers, over the same
+tile of queries per grid step (``bucket_rows.query_loop``):
 
-  tlp  (§4.3 TLPv1): one query per grid step; Pallas' pipeline emitter
-       auto-double-buffers the scalar-prefetch-indexed bucket rows.
-  pipeline (§4.3 Pipeline): Q queries per grid step with a manual two-slot
-       DMA pipeline — the latency-hiding structure of the paper's 4-stage
-       warp-cooperative kernel.
+  tlp       fetch, wait and compare one query at a time.
+  pipeline  query q+1's bucket rows stream in while query q is compared
+            (the latency-hiding structure of the paper's 4-stage
+            warp-cooperative kernel, §4.3).
 
 Both compute exactly ``ref.digest_scan_ref`` and are swept against it in
 tests (interpret mode executes the kernel bodies on CPU).
@@ -38,179 +36,66 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.find import match_lanes
-from repro.kernels import compat
-
-LANES = 128  # TPU vreg minor dimension == slots per bucket
+from repro.kernels import bucket_rows as br
 
 
-# =============================================================================
-# TLP variant: one query per grid step, auto-pipelined bucket-row blocks
-# =============================================================================
-
-
-def _tlp_kernel(bidx_ref, qd_ref, qh_ref, ql_ref, td_ref, th_ref, tl_ref,
-                slot_ref, found_ref):
-    i = pl.program_id(0)
-    qd = qd_ref[i]
-    qh = qh_ref[i]
-    ql = ql_ref[i]
-    # one vector compare over the 128-lane digest row = the whole candidate
-    # set; the mask formula is the shared core.find.match_lanes oracle
-    m = match_lanes(th_ref[0, :], tl_ref[0, :], qh, ql,
-                    td_ref[0, :].astype(jnp.uint32), qd)
-    found_ref[0, 0] = jnp.any(m).astype(jnp.int32)
-    slot_ref[0, 0] = jnp.argmax(m).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def digest_scan_tlp(tdigests, tkey_hi, tkey_lo, buckets, qdigest, qkey_hi,
-                    qkey_lo, *, interpret: bool = True):
-    """TLPv1: key-level parallelism, one bucket row per step."""
-    n = buckets.shape[0]
-    s = tdigests.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),  # qdigest (full)
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_hi
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_lo
-            pl.BlockSpec((1, s), lambda i, b: (b[i], 0)),       # digest row
-            pl.BlockSpec((1, s), lambda i, b: (b[i], 0)),       # key_hi row
-            pl.BlockSpec((1, s), lambda i, b: (b[i], 0)),       # key_lo row
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, b: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, b: (i, 0)),
-        ],
-    )
-    slot, found = pl.pallas_call(
-        _tlp_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-        ],
-        interpret=interpret,
-        name="hkv_digest_scan_tlp",
-    )(buckets, qdigest, qkey_hi, qkey_lo, tdigests, tkey_hi, tkey_lo)
-    return slot[:, 0], found[:, 0]
-
-
-# =============================================================================
-# Pipeline variant: Q queries per grid step, manual two-slot DMA double buffer
-# =============================================================================
-
-
-def _pipeline_kernel(q_tile, bidx_ref, qd_ref, qh_ref, ql_ref,
-                     td_hbm, th_hbm, tl_hbm, slot_ref, found_ref,
-                     dbuf, hbuf, lbuf, sems):
+def _kernel(depth, q_tile, group, b_ref, qd_ref, qh_ref, ql_ref,
+            td, th, tl, slot_ref, found_ref, dbuf, hbuf, lbuf, sems):
     i = pl.program_id(0)
 
-    def row_copies(q, slot):
-        b = bidx_ref[i * q_tile + q]
-        return (
-            pltpu.make_async_copy(td_hbm.at[pl.ds(b, 1), :], dbuf.at[slot], sems.at[slot, 0]),
-            pltpu.make_async_copy(th_hbm.at[pl.ds(b, 1), :], hbuf.at[slot], sems.at[slot, 1]),
-            pltpu.make_async_copy(tl_hbm.at[pl.ds(b, 1), :], lbuf.at[slot], sems.at[slot, 2]),
-        )
+    def copies(q, s):
+        b = b_ref[i * q_tile + q]
+        return (br.row_copy(td, dbuf.at[s], sems.at[s, 0], b, group),
+                br.row_copy(th, hbuf.at[s], sems.at[s, 1], b),
+                br.row_copy(tl, lbuf.at[s], sems.at[s, 2], b))
 
-    def issue(q, slot):
-        for c in row_copies(q, slot):
-            c.start()
+    def body(q, s):
+        n = i * q_tile + q
+        # one vector compare over the 128-lane row = the whole candidate
+        # set; the mask formula is the shared core.find.match_lanes oracle
+        m = match_lanes(hbuf[s], lbuf[s],
+                        qh_ref[n].astype(jnp.uint32),
+                        ql_ref[n].astype(jnp.uint32),
+                        br.digest_row(dbuf[s], b_ref[n]),
+                        qd_ref[n].astype(jnp.uint32))
+        hit, slot = br.first_lane(m)
+        found_ref[n] = hit.astype(jnp.int32)
+        slot_ref[n] = slot
 
-    def wait(q, slot):
-        for c in row_copies(q, slot):
-            c.wait()
-
-    # stage 1 prologue: prefetch query 0's bucket row
-    issue(0, 0)
-
-    def body(q, carry):
-        slots, founds = carry
-        cur = jax.lax.rem(q, 2)
-        nxt = jax.lax.rem(q + 1, 2)
-
-        # stage 1: issue next row's DMA while this row is in flight/compared
-        @pl.when(q + 1 < q_tile)
-        def _():
-            issue(q + 1, nxt)
-
-        wait(q, cur)
-        # stage 2: vectorized digest + key compare (one lane-row each),
-        # via the shared core.find.match_lanes oracle
-        m = match_lanes(hbuf[cur, 0, :], lbuf[cur, 0, :],
-                        qh_ref[0, q], ql_ref[0, q],
-                        dbuf[cur, 0, :].astype(jnp.uint32), qd_ref[0, q])
-        # stage 3: reduce to (found, slot)
-        f = jnp.any(m).astype(jnp.int32)
-        s = jnp.argmax(m).astype(jnp.int32)
-        onehot = (jax.lax.iota(jnp.int32, q_tile) == q)
-        return (jnp.where(onehot, s, slots), jnp.where(onehot, f, founds))
-
-    init = (jnp.zeros((q_tile,), jnp.int32), jnp.zeros((q_tile,), jnp.int32))
-    slots, founds = jax.lax.fori_loop(0, q_tile, body, init)
-    # stage 4: one vector writeback per tile
-    slot_ref[0, :] = slots
-    found_ref[0, :] = founds
+    br.query_loop(q_tile, depth, copies, body)
 
 
-@functools.partial(jax.jit, static_argnames=("q_tile", "interpret"))
-def digest_scan_pipeline(tdigests, tkey_hi, tkey_lo, buckets, qdigest,
-                         qkey_hi, qkey_lo, *, q_tile: int = 128,
-                         interpret: bool = True):
-    """Pipeline variant (§4.3): per-tile manual DMA with double buffering.
+@functools.partial(jax.jit, static_argnames=("variant", "q_tile", "interpret"))
+def digest_scan(tdigests, tkey_hi, tkey_lo, buckets, qdigest, qkey_hi,
+                qkey_lo, *, variant: str, q_tile: int, interpret: bool):
+    """(slot int32 [N], found int32 [N]) of each query in its bucket.
 
-    Queries are padded to a multiple of q_tile by the wrapper; the scratch
-    working set is 2 x (128 digests + 2x128 uint32 keys) ≈ 2.3 KB of VMEM
-    plus the (1, q_tile) query block — far under the ~16 MB VMEM budget,
-    leaving headroom for the value-gather kernel's blocks.
+    N must be a multiple of q_tile (the wrapper pads).  Per-query scalars
+    (int32 or bitcast uint32) ride scalar prefetch into SMEM.
     """
     n = buckets.shape[0]
     assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
-    s = tdigests.shape[1]
-    tiles = n // q_tile
+    nb, s = tdigests.shape
+    group = br.digest_group(nb)
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    (out_spec, out_shape) = br.smem_out(n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec((1, q_tile), lambda i, b: (i, 0),
-                         memory_space=compat.SMEM),
-            pl.BlockSpec((1, q_tile), lambda i, b: (i, 0),
-                         memory_space=compat.SMEM),
-            pl.BlockSpec((1, q_tile), lambda i, b: (i, 0),
-                         memory_space=compat.SMEM),
-            pl.BlockSpec(memory_space=compat.HBM),  # digest plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_hi plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_lo plane
-        ],
-        out_specs=[
-            pl.BlockSpec((1, q_tile), lambda i, b: (i, 0)),
-            pl.BlockSpec((1, q_tile), lambda i, b: (i, 0)),
-        ],
+        num_scalar_prefetch=4,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()] * 3,
+        out_specs=[out_spec] * 2,
         scratch_shapes=[
-            pltpu.VMEM((2, 1, s), jnp.uint8),
+            pltpu.VMEM((2, group, s), jnp.uint8),
             pltpu.VMEM((2, 1, s), jnp.uint32),
             pltpu.VMEM((2, 1, s), jnp.uint32),
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
-    slot, found = pl.pallas_call(
-        functools.partial(_pipeline_kernel, q_tile),
+    return pl.pallas_call(
+        functools.partial(_kernel, br.variant_depth(variant), q_tile, group),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-        ],
+        out_shape=[out_shape] * 2,
         interpret=interpret,
-        name="hkv_digest_scan_pipeline",
-    )(
-        buckets,
-        qdigest.reshape(tiles, q_tile),
-        qkey_hi.reshape(tiles, q_tile),
-        qkey_lo.reshape(tiles, q_tile),
-        tdigests,
-        tkey_hi,
-        tkey_lo,
-    )
-    return slot.reshape(n), found.reshape(n)
+        name=f"hkv_digest_scan_{variant}",
+    )(buckets, i32(qdigest), i32(qkey_hi), i32(qkey_lo),
+      tdigests, tkey_hi, tkey_lo)

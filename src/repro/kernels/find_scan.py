@@ -30,17 +30,12 @@ One scalar-prefetched pass per query over BOTH candidate bucket rows:
                           index maps cannot depend on in-kernel values,
                           but an explicit DMA can.
 
-Two variants, mirroring ``digest_scan``'s kernel-selection tiers:
-
-  tlp      one query per grid step; Pallas auto-double-buffers the ten
-           scalar-prefetch-indexed metadata rows (5 planes x 2 buckets,
-           the ``upsert_probe`` layout); the value row is an in-kernel DMA.
-  pipeline Q queries per grid step with a manual two-slot DMA pipeline.
-           Query q+1's metadata rows stream while query q is compared, and
-           query q's value-row DMA is issued immediately after its match
-           resolves and retired one iteration later — so the value copy of
-           q overlaps the metadata fetch + compare of q+1 (the paper's
-           4-stage latency-hiding structure, now including stage 4).
+Two variants over the same tile of queries per grid step
+(``bucket_rows.query_loop``): ``tlp`` fetches, waits and compares one
+query at a time; ``pipeline`` streams query q+1's ten metadata rows
+(5 planes x 2 buckets) while query q is compared.  The hit's value row is
+one 128-lane row of the packed value plane (``core.table``), rotated in
+VMEM so the slot's lanes come first.
 
 Both compute exactly ``ref.find_scan_ref`` and are swept against it and
 against the jnp ``core.find`` oracle in tests/test_find_kernel.py
@@ -57,81 +52,104 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.find import match_lanes
-from repro.kernels import compat
+from repro.core.table import value_pitch
+from repro.kernels import bucket_rows as br
 
-LANES = 128  # TPU vreg minor dimension == slots per bucket
 
-
-def _merge_hits(slots, sel_and_hits):
+def _merge_hits(hit1, slot1, hit2, slot2):
     """Shared dual-bucket merge: (found, sel, slot) from per-bucket hits —
     the exact `core.find.locate` merge (hit1 wins, miss defaults to b1)."""
-    hit1, slot1, hit2, slot2 = sel_and_hits
     found = hit1 | hit2
     sel = jnp.where(hit1, 0, jnp.where(hit2, 1, 0)).astype(jnp.int32)
     slot = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
     return found, sel, slot
 
 
-# =============================================================================
-# TLP variant: one query per grid step, auto-pipelined metadata row blocks
-# =============================================================================
+def slot_lanes(row, width: int, lanes: int):
+    """(plane row, first lane) of logical value row `row` in the packed
+    plane (`core.table.value_coords`, scalar form)."""
+    per = lanes // value_pitch(width)
+    return row // per, (row % per) * value_pitch(width)
 
 
-def _tlp_kernel(use_digest, slots, b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
-                d1_ref, h1_ref, l1_ref, s1h_ref, s1l_ref,
-                d2_ref, h2_ref, l2_ref, s2h_ref, s2l_ref, v_hbm,
-                found_ref, sel_ref, slot_ref, shi_ref, slo_ref, val_ref,
-                vbuf, vsem):
+def to_front(x, lane0, lanes: int, width: int):
+    """Rotate a [1, L] plane row so lanes [lane0, lane0 + width) come
+    first (no-op where one slot fills the row)."""
+    if lanes == value_pitch(width):
+        return x
+    return pltpu.roll(x, (lanes - lane0) % lanes, 1)
+
+
+def _kernel(depth, q_tile, group, width, use_digest,
+            b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
+            td, th, tl, tsh, tsl, tv,
+            found_ref, sel_ref, slot_ref, shi_ref, slo_ref, val_ref,
+            d1, h1, l1, sh1, sl1, d2, h2, l2, sh2, sl2, vbuf, sems, vsem):
     i = pl.program_id(0)
-    qd = qd_ref[i]
-    qh = qh_ref[i]
-    ql = ql_ref[i]
+    lanes = tv.shape[1]
+    slots = th.shape[1]
+    planes = (td, th, tl, tsh, tsl)
 
-    def row_match(d_ref, h_ref, l_ref):
-        # full-key compare, gated by the one-lane-row digest pre-filter —
-        # the shared `core.find.match_lanes` oracle, so kernel and jnp
-        # reference cannot fork
-        if use_digest:
-            m = match_lanes(h_ref[0, :], l_ref[0, :], qh, ql,
-                            d_ref[0, :].astype(jnp.uint32), qd)
-        else:
-            m = match_lanes(h_ref[0, :], l_ref[0, :], qh, ql)
-        return jnp.any(m), jnp.argmax(m).astype(jnp.int32)
+    def copies(q, s):
+        n = i * q_tile + q
+        cps = []
+        for k, (b, bufs) in enumerate(((b1_ref[n], (d1, h1, l1, sh1, sl1)),
+                                       (b2_ref[n], (d2, h2, l2, sh2, sl2)))):
+            for j, (p, buf) in enumerate(zip(planes, bufs)):
+                cps.append(br.row_copy(p, buf.at[s], sems.at[s, 5 * k + j],
+                                       b, group if j == 0 else 1))
+        return cps
 
-    hit1, slot1 = row_match(d1_ref, h1_ref, l1_ref)
-    hit2, slot2 = row_match(d2_ref, h2_ref, l2_ref)
-    found, sel, slot = _merge_hits(slots, (hit1, slot1, hit2, slot2))
+    def body(q, s):
+        n = i * q_tile + q
+        qh = qh_ref[n].astype(jnp.uint32)
+        ql = ql_ref[n].astype(jnp.uint32)
+        qd = qd_ref[n].astype(jnp.uint32)
 
-    # score readout from the already-streamed metadata rows (one onehot
-    # lane reduction — no second metadata probe for FindResult scores)
-    lane = jax.lax.iota(jnp.int32, slots) == slot
-    pick = lambda a_ref, b_ref: jnp.max(jnp.where(
-        lane, jnp.where(sel == 0, a_ref[0, :], b_ref[0, :]), jnp.uint32(0)))
-    shi = jnp.where(found, pick(s1h_ref, s2h_ref), jnp.uint32(0))
-    slo = jnp.where(found, pick(s1l_ref, s2l_ref), jnp.uint32(0))
+        def row_match(b, d, h, l):
+            # full-key compare, gated by the one-lane-row digest pre-filter
+            # — the shared `core.find.match_lanes` oracle, so kernel and
+            # jnp reference cannot fork
+            if use_digest:
+                m = match_lanes(h[s], l[s], qh, ql, br.digest_row(d[s], b), qd)
+            else:
+                m = match_lanes(h[s], l[s], qh, ql)
+            return br.first_lane(m)
 
-    found_ref[0, 0] = found.astype(jnp.int32)
-    sel_ref[0, 0] = sel
-    slot_ref[0, 0] = slot
-    shi_ref[0, 0] = shi
-    slo_ref[0, 0] = slo
+        b1, b2 = b1_ref[n], b2_ref[n]
+        hit1, slot1 = row_match(b1, d1, h1, l1)
+        hit2, slot2 = row_match(b2, d2, h2, l2)
+        found, sel, slot = _merge_hits(hit1, slot1, hit2, slot2)
 
-    # in-line value gather: position addressing (§3.6) resolved in-kernel.
-    # Misses fetch row b1*S+0 (a valid address) and mask to zeros below —
-    # the same contract as `find.gather_values`.
-    b = jnp.where(sel == 0, b1_ref[i], b2_ref[i])
-    row = b * slots + slot
-    cp = pltpu.make_async_copy(v_hbm.at[pl.ds(row, 1), :], vbuf, vsem)
-    cp.start()
-    cp.wait()
-    val_ref[0, :] = jnp.where(found, vbuf[0, :], jnp.zeros_like(vbuf[0, :]))
+        # score readout from the already-streamed metadata rows
+        lane = br.lane_iota(slots) == slot
+        pick = lambda a, b: br.pick(jnp.where(sel == 0, a[s], b[s]), lane)
+        found_ref[n] = found.astype(jnp.int32)
+        sel_ref[n] = sel
+        slot_ref[n] = slot
+        shi_ref[n] = jnp.where(found, pick(sh1, sh2), 0)
+        slo_ref[n] = jnp.where(found, pick(sl1, sl2), 0)
+
+        # in-line value gather: position addressing (§3.6) resolved
+        # in-kernel.  Misses fetch row b1*S+0 (a valid address) and mask
+        # to zeros — the same contract as `find.gather_values`.
+        row = jnp.where(sel == 0, b1, b2) * slots + slot
+        prow, lane0 = slot_lanes(row, width, lanes)
+        cp = pltpu.make_async_copy(tv.at[pl.ds(prow, 1), :], vbuf, vsem)
+        cp.start()
+        cp.wait()
+        x = to_front(vbuf[...], lane0, lanes, width)[:, :width]
+        val_ref[pl.ds(q, 1), :] = jnp.where(found, x, jnp.zeros_like(x))
+
+    br.query_loop(q_tile, depth, copies, body)
 
 
-@functools.partial(jax.jit, static_argnames=("use_digest", "interpret"))
-def find_scan_tlp(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
-                  bucket1, bucket2, qdigest, qkey_hi, qkey_lo, *,
-                  use_digest: bool = True, interpret: bool = True):
-    """Fused find, TLP tier: one query per grid step.
+@functools.partial(jax.jit, static_argnames=(
+    "width", "variant", "q_tile", "use_digest", "interpret"))
+def find_scan(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
+              bucket1, bucket2, qdigest, qkey_hi, qkey_lo, *, width: int,
+              variant: str, q_tile: int, use_digest: bool, interpret: bool):
+    """Fused find over a tile-padded batch.
 
     Returns (found, sel, slot, score_hi, score_lo, values):
       found     int32 [N] — 1 iff the key matched in either candidate bucket
@@ -139,287 +157,41 @@ def find_scan_tlp(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
       slot      int32 [N] — matching slot (0 on miss)
       score_hi  uint32 [N] — hit entry's score planes (0 on miss)
       score_lo  uint32 [N]
-      values    [N, V] — the hit row of the value plane (zeros on miss)
+      values    [N, width] — the hit's logical value row (zeros on miss)
 
+    `tvalues` is the packed value plane; `width` its logical row width.
     Single-bucket mode: pass bucket2 == bucket1 (sel collapses to 0).
     """
     n = bucket1.shape[0]
-    s = tdigests.shape[1]
-    v = tvalues.shape[1]
-    row = lambda i, b1, b2: (b1[i], 0)
-    row2 = lambda i, b1, b2: (b2[i], 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),  # qdigest
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_hi
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_lo
-            pl.BlockSpec((1, s), row),    # bucket1 digest row
-            pl.BlockSpec((1, s), row),    # bucket1 key_hi row
-            pl.BlockSpec((1, s), row),    # bucket1 key_lo row
-            pl.BlockSpec((1, s), row),    # bucket1 score_hi row
-            pl.BlockSpec((1, s), row),    # bucket1 score_lo row
-            pl.BlockSpec((1, s), row2),   # bucket2 digest row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_hi row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_lo row
-            pl.BlockSpec((1, s), row2),   # bucket2 score_hi row
-            pl.BlockSpec((1, s), row2),   # bucket2 score_lo row
-            pl.BlockSpec(memory_space=compat.HBM),  # value plane (in-kernel DMA)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec((1, v), lambda i, b1, b2: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, v), tvalues.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    found, sel, slot, shi, slo, vals = pl.pallas_call(
-        functools.partial(_tlp_kernel, use_digest, s),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((n, v), tvalues.dtype),
-        ],
-        interpret=interpret,
-        name="hkv_find_scan_tlp",
-    )(
-        bucket1, bucket2, qdigest, qkey_hi, qkey_lo,
-        tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
-        tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
-        tvalues,
-    )
-    return found[:, 0], sel[:, 0], slot[:, 0], shi[:, 0], slo[:, 0], vals
-
-
-# =============================================================================
-# Pipeline variant: Q queries per grid step, manual two-slot DMA double buffer
-# =============================================================================
-
-
-def _pipeline_kernel(use_digest, q_tile, slots,
-                     b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
-                     td, th, tl, tsh, tsl, tv,
-                     found_ref, sel_ref, slot_ref, shi_ref, slo_ref, val_ref,
-                     d1b, h1b, l1b, sh1b, sl1b,
-                     d2b, h2b, l2b, sh2b, sl2b,
-                     vbuf, sems, vsem):
-    i = pl.program_id(0)
-    v = tv.shape[1]
-
-    def meta_copies(q, slot):
-        base = i * q_tile + q
-        b1 = b1_ref[base]
-        b2 = b2_ref[base]
-        planes = (td, th, tl, tsh, tsl)
-        bufs1 = (d1b, h1b, l1b, sh1b, sl1b)
-        bufs2 = (d2b, h2b, l2b, sh2b, sl2b)
-        cps = []
-        for j, (p, bf) in enumerate(zip(planes, bufs1)):
-            cps.append(pltpu.make_async_copy(
-                p.at[pl.ds(b1, 1), :], bf.at[slot], sems.at[slot, j]))
-        for j, (p, bf) in enumerate(zip(planes, bufs2)):
-            cps.append(pltpu.make_async_copy(
-                p.at[pl.ds(b2, 1), :], bf.at[slot], sems.at[slot, 5 + j]))
-        return cps
-
-    def issue(q, slot):
-        for c in meta_copies(q, slot):
-            c.start()
-
-    def wait(q, slot):
-        for c in meta_copies(q, slot):
-            c.wait()
-
-    def vcopy(row, slot):
-        return pltpu.make_async_copy(
-            tv.at[pl.ds(row, 1), :], vbuf.at[slot], vsem.at[slot])
-
-    # stage 1 prologue: prefetch query 0's two candidate bucket rows
-    issue(0, 0)
-
-    q_iota = jax.lax.iota(jnp.int32, q_tile)
-    lane_iota = jax.lax.iota(jnp.int32, slots)
-
-    def body(q, carry):
-        founds, sels, slotsv, shis, slos, valsm, prev_row = carry
-        cur = jax.lax.rem(q, 2)
-        nxt = jax.lax.rem(q + 1, 2)
-
-        # stage 1: issue next query's metadata DMAs while q's are compared
-        @pl.when(q + 1 < q_tile)
-        def _():
-            issue(q + 1, nxt)
-
-        wait(q, cur)
-        qd = qd_ref[0, q]
-        qh = qh_ref[0, q]
-        ql = ql_ref[0, q]
-
-        # stage 2: vectorized digest + key compare per candidate bucket,
-        # via the shared `core.find.match_lanes` oracle
-        def row_match(db, hb, lb):
-            if use_digest:
-                m = match_lanes(hb[cur, 0, :], lb[cur, 0, :], qh, ql,
-                                db[cur, 0, :].astype(jnp.uint32), qd)
-            else:
-                m = match_lanes(hb[cur, 0, :], lb[cur, 0, :], qh, ql)
-            return jnp.any(m), jnp.argmax(m).astype(jnp.int32)
-
-        hit1, slot1 = row_match(d1b, h1b, l1b)
-        hit2, slot2 = row_match(d2b, h2b, l2b)
-        # stage 3: dual-bucket merge + score readout
-        found, sel, slot = _merge_hits(slots, (hit1, slot1, hit2, slot2))
-        lane = lane_iota == slot
-        pick = lambda a, b: jnp.max(jnp.where(
-            lane, jnp.where(sel == 0, a[cur, 0, :], b[cur, 0, :]),
-            jnp.uint32(0)))
-        shi = jnp.where(found, pick(sh1b, sh2b), jnp.uint32(0))
-        slo = jnp.where(found, pick(sl1b, sl2b), jnp.uint32(0))
-
-        base = i * q_tile + q
-        b = jnp.where(sel == 0, b1_ref[base], b2_ref[base])
-        row = b * slots + slot
-
-        # stage 4a: issue q's value-row DMA — it overlaps query q+1's
-        # metadata stream and compare, retiring one iteration later
-        vcopy(row, cur).start()
-
-        # stage 4b: retire query q-1's value row (its DMA has had a full
-        # iteration of latency hiding)
-        @pl.when(q >= 1)
-        def _():
-            vcopy(prev_row, nxt).wait()
-        prev_found = jnp.sum(jnp.where(q_iota == q - 1, founds, 0)) != 0
-        rowvec = jnp.where(prev_found, vbuf[nxt, 0, :],
-                           jnp.zeros((v,), tv.dtype))
-        place = (q_iota == q - 1) & (q >= 1)
-        valsm = jnp.where(place[:, None], rowvec[None, :], valsm)
-
-        onehot = q_iota == q
-        return (
-            jnp.where(onehot, found.astype(jnp.int32), founds),
-            jnp.where(onehot, sel, sels),
-            jnp.where(onehot, slot, slotsv),
-            jnp.where(onehot, shi, shis),
-            jnp.where(onehot, slo, slos),
-            valsm,
-            row,
-        )
-
-    init = (
-        jnp.zeros((q_tile,), jnp.int32),
-        jnp.zeros((q_tile,), jnp.int32),
-        jnp.zeros((q_tile,), jnp.int32),
-        jnp.zeros((q_tile,), jnp.uint32),
-        jnp.zeros((q_tile,), jnp.uint32),
-        jnp.zeros((q_tile, v), tv.dtype),
-        jnp.int32(0),
-    )
-    founds, sels, slotsv, shis, slos, valsm, prev_row = jax.lax.fori_loop(
-        0, q_tile, body, init)
-
-    # epilogue: retire the last query's value row
-    last = q_tile - 1
-    vcopy(prev_row, last % 2).wait()
-    rowvec = jnp.where(founds[last] != 0, vbuf[last % 2, 0, :],
-                       jnp.zeros((v,), tv.dtype))
-    valsm = jnp.where((q_iota == last)[:, None], rowvec[None, :], valsm)
-
-    # one vector writeback per tile
-    found_ref[0, :] = founds
-    sel_ref[0, :] = sels
-    slot_ref[0, :] = slotsv
-    shi_ref[0, :] = shis
-    slo_ref[0, :] = slos
-    val_ref[...] = valsm
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("q_tile", "use_digest", "interpret"))
-def find_scan_pipeline(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
-                       tvalues, bucket1, bucket2, qdigest, qkey_hi, qkey_lo,
-                       *, q_tile: int = 128, use_digest: bool = True,
-                       interpret: bool = True):
-    """Fused find, Pipeline tier: Q queries per grid step, manual DMA.
-
-    Same outputs as `find_scan_tlp`.  Queries are padded to a multiple of
-    q_tile by the wrapper.  Scratch working set: 2 x (10 metadata rows +
-    one value row) ≈ 2 x (4.2 KB + V*4 B) — far under the VMEM budget even
-    at the widest value rows, because the value plane itself stays in HBM
-    and only the two in-flight hit rows are resident.
-    """
-    n = bucket1.shape[0]
     assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
-    s = tdigests.shape[1]
-    v = tvalues.shape[1]
-    tiles = n // q_tile
-    smem_block = lambda: pl.BlockSpec((1, q_tile), lambda i, b1, b2: (i, 0),
-                                      memory_space=compat.SMEM)
-    out_block = lambda: pl.BlockSpec((1, q_tile), lambda i, b1, b2: (i, 0))
+    nb, s = tdigests.shape
+    lanes = tvalues.shape[1]
+    group = br.digest_group(nb)
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    out_spec, out_shape = br.smem_out(n)
+    meta = lambda dt, rows=1: pltpu.VMEM((2, rows, s), dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(tiles,),
-        in_specs=[
-            smem_block(),   # qdigest
-            smem_block(),   # qkey_hi
-            smem_block(),   # qkey_lo
-            pl.BlockSpec(memory_space=compat.HBM),  # digest plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_hi plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_lo plane
-            pl.BlockSpec(memory_space=compat.HBM),  # score_hi plane
-            pl.BlockSpec(memory_space=compat.HBM),  # score_lo plane
-            pl.BlockSpec(memory_space=compat.HBM),  # value plane
-        ],
-        out_specs=[
-            out_block(), out_block(), out_block(), out_block(), out_block(),
-            pl.BlockSpec((q_tile, v), lambda i, b1, b2: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, s), jnp.uint8),    # bucket1 digests
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 key_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 key_lo
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 score_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 score_lo
-            pltpu.VMEM((2, 1, s), jnp.uint8),    # bucket2 digests
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 key_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 key_lo
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 score_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 score_lo
-            pltpu.VMEM((2, 1, v), tvalues.dtype),  # value double buffer
-            pltpu.SemaphoreType.DMA((2, 10)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        num_scalar_prefetch=5,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()] * 6,
+        out_specs=[out_spec] * 5 + [
+            pl.BlockSpec((q_tile, width), lambda i, *_: (i, 0))],
+        scratch_shapes=(
+            [meta(jnp.uint8, group)] + [meta(jnp.uint32)] * 4
+            + [meta(jnp.uint8, group)] + [meta(jnp.uint32)] * 4
+            + [pltpu.VMEM((1, lanes), tvalues.dtype),
+               pltpu.SemaphoreType.DMA((2, 10)),
+               pltpu.SemaphoreType.DMA]),
     )
     found, sel, slot, shi, slo, vals = pl.pallas_call(
-        functools.partial(_pipeline_kernel, use_digest, q_tile, s),
+        functools.partial(_kernel, br.variant_depth(variant), q_tile, group,
+                          width, use_digest),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.uint32),
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.uint32),
-            jax.ShapeDtypeStruct((n, v), tvalues.dtype),
-        ],
+        out_shape=[out_shape] * 5 + [
+            jax.ShapeDtypeStruct((n, width), tvalues.dtype)],
         interpret=interpret,
-        name="hkv_find_scan_pipeline",
-    )(
-        bucket1, bucket2,
-        qdigest.reshape(tiles, q_tile),
-        qkey_hi.reshape(tiles, q_tile),
-        qkey_lo.reshape(tiles, q_tile),
-        tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
-    )
-    return (found.reshape(n), sel.reshape(n), slot.reshape(n),
-            shi.reshape(n), slo.reshape(n), vals)
+        name=f"hkv_find_scan_{variant}",
+    )(bucket1, bucket2, i32(qdigest), i32(qkey_hi), i32(qkey_lo),
+      tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues)
+    u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return found, sel, slot, u32(shi), u32(slo), vals

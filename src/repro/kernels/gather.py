@@ -2,12 +2,10 @@
 
 After the digest scan resolves (bucket, slot) -> row = bucket*S + slot, the
 value copy is a pure bandwidth problem.  The paper's TLPv2 regroups threads
-into cooperative value-copy gangs with double-buffered shared memory; the
-TPU analogue is a scalar-prefetch-indexed row pipeline: the row index stream
-is prefetched into SMEM, each grid step's BlockSpec selects values[row] as
-its input block, and the Pallas pipeline emitter overlaps row r+1's
-HBM->VMEM DMA with row r's writeback — the same two-deep overlap, driven by
-the hardware DMA engine.
+into cooperative value-copy gangs; the TPU analogue walks a tile of
+scalar-prefetched row ids per grid step and DMAs each row's 128-lane line
+of the packed value plane (``core.table``) into VMEM, rotating the slot's
+lanes to the front.
 
 Rows with mask==0 (misses) produce zero rows, matching `find`'s contract.
 """
@@ -21,33 +19,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
+from repro.kernels import bucket_rows as br
+from repro.kernels.find_scan import slot_lanes, to_front
 
 
-def _gather_kernel(rows_ref, mask_ref, val_ref, out_ref):
+def _gather_kernel(width, q_tile, rows_ref, mask_ref, v_hbm, out_ref,
+                   vbuf, sem):
     i = pl.program_id(0)
-    live = mask_ref[i] != 0
-    out_ref[0, :] = jnp.where(live, val_ref[0, :], jnp.zeros_like(val_ref[0, :]))
+    lanes = v_hbm.shape[1]
+
+    def body(q, carry):
+        n = i * q_tile + q
+        prow, lane0 = slot_lanes(rows_ref[n], width, lanes)
+        cp = pltpu.make_async_copy(v_hbm.at[pl.ds(prow, 1), :], vbuf, sem)
+        cp.start()
+        cp.wait()
+        x = to_front(vbuf[...], lane0, lanes, width)[:, :width]
+        out_ref[pl.ds(q, 1), :] = jnp.where(mask_ref[n] != 0, x,
+                                            jnp.zeros_like(x))
+        return carry
+
+    jax.lax.fori_loop(0, q_tile, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_rows(values, rows, mask, *, interpret: bool = True):
-    """out[i] = mask[i] ? values[rows[i]] : 0   (rows pre-clipped in wrapper)."""
+@functools.partial(jax.jit, static_argnames=("width", "q_tile", "interpret"))
+def gather_rows(values, rows, mask, *, width: int, q_tile: int,
+                interpret: bool):
+    """out[i] = mask[i] ? logical row rows[i] : 0, of the packed plane
+    `values` (rows pre-clipped; N a multiple of q_tile — the wrapper pads)."""
     n = rows.shape[0]
-    d = values.shape[1]
+    assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),        # mask
-            pl.BlockSpec((1, d), lambda i, r: (r[i], 0)),             # values row
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i, r: (i, 0)),
+        num_scalar_prefetch=2,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()],
+        out_specs=pl.BlockSpec((q_tile, width), lambda i, *_: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((1, values.shape[1]), values.dtype),
+                        pltpu.SemaphoreType.DMA],
     )
     return pl.pallas_call(
-        _gather_kernel,
+        functools.partial(_gather_kernel, width, q_tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, d), values.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, width), values.dtype),
         interpret=interpret,
         name="hkv_gather_rows",
     )(rows, mask, values)

@@ -1,9 +1,12 @@
-"""Jit'd wrappers over the Pallas kernels (+ dispatch and padding logic).
+"""Jit'd wrappers over the Pallas kernels (+ dispatch, padding and tiling).
 
-`interpret` defaults to True off-TPU: the kernel bodies execute in Python
-on CPU (the validation mode this container supports) and compile to Mosaic
-on real TPUs.  The wrappers are drop-in equivalents of the pure-jnp paths
-in `repro.core` and are cross-checked against them in tests.
+The raw kernels take `interpret` with no default; these wrappers are the
+one place that chooses it (`default_interpret`: compiled on a TPU,
+interpreted elsewhere).  They also pad every batch to the kernels' query
+tile and cut it into chunks of at most `CHUNK` queries, because each
+kernel holds its chunk's per-query scalars in SMEM: any batch size runs.
+The wrappers are drop-in equivalents of the pure-jnp paths in
+`repro.core` and are cross-checked against them in tests.
 
 Role taxonomy coverage (paper §3.5; see also `repro.core.ops`):
 
@@ -33,7 +36,6 @@ Role taxonomy coverage (paper §3.5; see also `repro.core.ops`):
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -56,6 +58,9 @@ from repro.kernels import update_scan as _upd
 from repro.kernels import upsert_scan as _us
 
 
+CHUNK = 4096  # queries per kernel call (their scalars live in SMEM)
+
+
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -65,6 +70,55 @@ def _pad_to(x: jax.Array, n: int, fill=0):
         return x
     pad = jnp.full((n - x.shape[0],) + x.shape[1:], fill, x.dtype)
     return jnp.concatenate([x, pad], axis=0)
+
+
+def _plan(n: int) -> tuple[int, int]:
+    """(query tile, padded batch) for a batch of n queries."""
+    if n <= CHUNK:
+        q_tile = min(128, max(8, -(-n // 8) * 8))
+        return q_tile, -(-n // q_tile) * q_tile
+    return 128, -(-n // CHUNK) * CHUNK
+
+
+def _chunks(args, fills, npad):
+    """Pad per-query arrays to npad; split into [k, CHUNK, ...] if needed."""
+    args = [_pad_to(a, npad, f) for a, f in zip(args, fills)]
+    if npad <= CHUNK:
+        return args, 0
+    k = npad // CHUNK
+    return [a.reshape((k, CHUNK) + a.shape[1:]) for a in args], k
+
+
+def _per_query(call, args, fills):
+    """Run a read-only per-query kernel `call(q_tile, *args)` over a batch:
+    padded to its tile, one call per chunk.  Outputs trimmed to the batch."""
+    n = args[0].shape[0]
+    q_tile, npad = _plan(n)
+    args, k = _chunks(args, fills, npad)
+    if not k:
+        outs = call(q_tile, *args)
+    else:
+        outs = jax.lax.map(lambda xs: call(q_tile, *xs), args)
+        outs = jax.tree.map(lambda o: o.reshape((npad,) + o.shape[2:]), outs)
+    return jax.tree.map(lambda o: o[:n], outs)
+
+
+def _per_query_write(call, plane, args, fills):
+    """`_per_query` for a kernel that rewrites the value plane:
+    `call(q_tile, plane, *args) -> (out, plane)`, chunks in batch order."""
+    n = args[0].shape[0]
+    q_tile, npad = _plan(n)
+    args, k = _chunks(args, fills, npad)
+    if not k:
+        out, plane = call(q_tile, plane, *args)
+        return out[:n], plane
+
+    def step(p, xs):
+        out, p = call(q_tile, p, *xs)
+        return p, out
+
+    plane, out = jax.lax.scan(step, plane, args)
+    return out.reshape(npad)[:n], plane
 
 
 def locate_kernel(
@@ -78,33 +132,17 @@ def locate_kernel(
     """Kernel-backed drop-in for core.find.locate (single & dual bucket)."""
     if interpret is None:
         interpret = default_interpret()
-    n = keys.hi.shape[0]
     probe = find_mod.probe_keys(cfg, keys)
     qd = probe.digest.astype(jnp.uint32)
-
-    if variant == "pipeline":
-        q_tile = min(128, n) if n % 128 else 128
-        npad = -(-n // q_tile) * q_tile
-        scan = functools.partial(
-            _ds.digest_scan_pipeline, q_tile=q_tile, interpret=interpret
-        )
-    elif variant == "tlp":
-        npad = n
-        scan = functools.partial(_ds.digest_scan_tlp, interpret=interpret)
-    else:
-        raise ValueError(f"unknown variant {variant!r}")
+    scan = lambda q_tile, *a: _ds.digest_scan(
+        state.digests, state.key_hi, state.key_lo, *a,
+        variant=variant, q_tile=q_tile, interpret=interpret)
 
     def run(bucket):
-        slot, found = scan(
-            state.digests,
-            state.key_hi,
-            state.key_lo,
-            _pad_to(bucket, npad),
-            _pad_to(qd, npad),
-            _pad_to(keys.hi, npad, u64.EMPTY_HI),
-            _pad_to(keys.lo, npad, u64.EMPTY_LO),
-        )
-        return slot[:n], found[:n].astype(bool)
+        slot, found = _per_query(
+            scan, (bucket, qd, keys.hi, keys.lo),
+            (0, 0, u64.EMPTY_HI, u64.EMPTY_LO))
+        return slot, found.astype(bool)
 
     slot1, hit1 = run(probe.bucket1)
     if cfg.buckets_per_key == 2:
@@ -161,53 +199,43 @@ def find_fused_kernel(
         # resolves on the kernel locate path
         loc = locate_kernel(state, cfg, keys, variant=variant,
                             interpret=interpret)
-        vals = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        vals = find_mod.gather_values(state, loc, None, cfg)
         shi = jnp.where(loc.found, state.score_hi[loc.bucket, loc.slot], 0)
         slo = jnp.where(loc.found, state.score_lo[loc.bucket, loc.slot], 0)
         return FusedFind(values=vals, found=loc.found, bucket=loc.bucket,
                          slot=loc.slot, row=loc.row, score_hi=shi,
                          score_lo=slo)
 
-    n = keys.hi.shape[0]
     probe = find_mod.probe_keys(cfg, keys)
-    qd = probe.digest.astype(jnp.uint32)
-    if variant == "pipeline":
-        q_tile = min(128, n) if n % 128 else 128
-        npad = -(-n // q_tile) * q_tile
-        scan = functools.partial(_fs.find_scan_pipeline, q_tile=q_tile,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    elif variant == "tlp":
-        npad = n
-        scan = functools.partial(_fs.find_scan_tlp,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    else:
-        raise ValueError(f"unknown variant {variant!r}")
     b2 = probe.bucket2 if cfg.buckets_per_key == 2 else probe.bucket1
-    found, sel, slot, shi, slo, vals = scan(
-        state.digests, state.key_hi, state.key_lo,
-        state.score_hi, state.score_lo, state.values,
-        _pad_to(probe.bucket1, npad),
-        _pad_to(b2, npad),
-        _pad_to(qd, npad),
-        _pad_to(keys.hi, npad, u64.EMPTY_HI),
-        _pad_to(keys.lo, npad, u64.EMPTY_LO),
-    )
+    found, sel, slot, shi, slo, vals = _find_scan(
+        (state.digests, state.key_hi, state.key_lo, state.score_hi,
+         state.score_lo, state.values),
+        cfg, probe.bucket1, b2, probe.digest.astype(jnp.uint32), keys,
+        variant, interpret)
     # re-mask by probe validity: an EMPTY padding key may alias empty slots
     # in-kernel; the reference masks those out via probe.valid
-    found = found[:n].astype(bool) & probe.valid
-    sel, slot = sel[:n], slot[:n]
+    found = found.astype(bool) & probe.valid
     bucket = jnp.where(sel == 1, b2, probe.bucket1)
     return FusedFind(
-        values=jnp.where(found[:, None], vals[:n], 0),
+        values=jnp.where(found[:, None], vals, 0),
         found=found,
         bucket=bucket,
         slot=slot,
         row=bucket * cfg.slots_per_bucket + slot,
-        score_hi=jnp.where(found, shi[:n], 0),
-        score_lo=jnp.where(found, slo[:n], 0),
+        score_hi=jnp.where(found, shi, 0),
+        score_lo=jnp.where(found, slo, 0),
     )
+
+
+def _find_scan(planes, cfg: HKVConfig, b1, b2, qd, keys: U64, variant: str,
+               interpret: bool):
+    """The fused find kernel over a whole batch (padded and chunked)."""
+    scan = lambda q_tile, *a: _fs.find_scan(
+        *planes, *a, width=cfg.total_value_dim, variant=variant,
+        q_tile=q_tile, use_digest=cfg.use_digest, interpret=interpret)
+    return _per_query(scan, (b1, b2, qd, keys.hi, keys.lo),
+                      (0, 0, 0, u64.EMPTY_HI, u64.EMPTY_LO))
 
 
 def find_kernel(
@@ -239,10 +267,7 @@ def find_composed_kernel(
     if interpret is None:
         interpret = default_interpret()
     loc = locate_kernel(state, cfg, keys, variant=variant, interpret=interpret)
-    rows = jnp.clip(loc.row, 0, state.values.shape[0] - 1)
-    vals = _ga.gather_rows(
-        state.values, rows, loc.found.astype(jnp.int32), interpret=interpret
-    )
+    vals = _gather(state.values, cfg, loc.row, loc.found, interpret)
     return vals[:, : cfg.dim], loc.found
 
 
@@ -284,33 +309,14 @@ def find_many_kernel(
     qd = jnp.concatenate([p.digest.astype(jnp.uint32) for p in probes])
     qh = jnp.concatenate([k.hi for k in keys_list])
     ql = jnp.concatenate([k.lo for k in keys_list])
-    n = qh.shape[0]
 
-    if variant == "pipeline":
-        q_tile = min(128, n) if n % 128 else 128
-        npad = -(-n // q_tile) * q_tile
-        scan = functools.partial(_fs.find_scan_pipeline, q_tile=q_tile,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    elif variant == "tlp":
-        npad = n
-        scan = functools.partial(_fs.find_scan_tlp,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    else:
-        raise ValueError(f"unknown variant {variant!r}")
-
+    # stacked planes: table t's logical row g is global row t*B*S + g, and
+    # its packed plane rows follow table t-1's, so the stacking agrees
     stack = lambda f: jnp.concatenate([f(st) for st in states], axis=0)
-    found, sel, slot, shi, slo, vals = scan(
-        stack(lambda st: st.digests),
-        stack(lambda st: st.key_hi),
-        stack(lambda st: st.key_lo),
-        stack(lambda st: st.score_hi),
-        stack(lambda st: st.score_lo),
-        stack(lambda st: st.values),
-        _pad_to(b1, npad), _pad_to(b2, npad), _pad_to(qd, npad),
-        _pad_to(qh, npad, u64.EMPTY_HI), _pad_to(ql, npad, u64.EMPTY_LO),
-    )
+    planes = tuple(stack(lambda st, f=f: getattr(st, f)) for f in (
+        "digests", "key_hi", "key_lo", "score_hi", "score_lo", "values"))
+    found, sel, slot, shi, slo, vals = _find_scan(
+        planes, cfg, b1, b2, qd, U64(qh, ql), variant, interpret)
     out: list[FusedFind] = []
     start = 0
     for t, (p, cnt) in enumerate(zip(probes, counts)):
@@ -348,18 +354,38 @@ def assign_kernel(
     if interpret is None:
         interpret = default_interpret()
     loc = locate_kernel(state, cfg, keys, interpret=interpret)
-    vdim = state.values.shape[1]
+    vdim = cfg.total_value_dim
     if values.shape[1] < vdim:
         values = jnp.concatenate(
             [values, jnp.zeros((values.shape[0], vdim - values.shape[1]), values.dtype)],
             axis=1,
         )
-    rows = jnp.clip(loc.row, 0, state.values.shape[0] - 1)
-    new_values = _sc.scatter_rows(
-        state.values, rows, values, loc.found.astype(jnp.int32), add=add,
-        interpret=interpret,
-    )
+    new_values = _scatter(state.values, cfg, loc.row, values, loc.found,
+                          add, interpret)
     return state._replace(values=new_values)
+
+
+def _gather(values, cfg: HKVConfig, rows, mask, interpret: bool):
+    """gather_rows over a whole batch: logical rows, zeros where ~mask."""
+    rows = jnp.clip(rows, 0, cfg.capacity - 1)
+    call = lambda q_tile, r, m: _ga.gather_rows(
+        values, r, m, width=cfg.total_value_dim, q_tile=q_tile,
+        interpret=interpret)
+    return _per_query(call, (rows, mask.astype(jnp.int32)), (0, 0))
+
+
+def _scatter(values, cfg: HKVConfig, rows, updates, mask, add: bool,
+             interpret: bool):
+    """scatter_rows over a whole batch (masked-out lanes write nothing)."""
+    rows = jnp.clip(rows, 0, cfg.capacity - 1)
+
+    def call(q_tile, plane, r, u, m):
+        return jnp.zeros((r.shape[0],), jnp.int32), _sc.scatter_rows(
+            plane, r, u, m, add=add, q_tile=q_tile, interpret=interpret)
+
+    return _per_query_write(
+        call, values, (rows, updates.astype(values.dtype),
+                       mask.astype(jnp.int32)), (0, 0, 0))[1]
 
 
 class UpdateRows(NamedTuple):
@@ -396,52 +422,38 @@ def update_rows_kernel(
     """
     if interpret is None:
         interpret = default_interpret()
-    b, s = cfg.num_buckets, cfg.slots_per_bucket
     if cfg.value_tier != "hbm":
         loc = locate_kernel(state, cfg, keys, variant=variant,
                             interpret=interpret)
-        rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+        rows = find_mod.gather_values(state, loc, None, cfg)
         new_rows = opt.apply(rows, grads, cfg.dim).astype(state.values.dtype)
         new_rows = jnp.where(loc.found[:, None], new_rows, rows)
         new_values = table_mod.tier_scatter(
             cfg.value_tier, state.values,
-            jnp.where(loc.found, loc.row, b * s), new_rows)
+            jnp.where(loc.found, loc.row, cfg.capacity), new_rows)
         return UpdateRows(state=state._replace(values=new_values),
                           found=loc.found)
 
-    n = keys.hi.shape[0]
     probe = find_mod.probe_keys(cfg, keys)
-    qd = probe.digest.astype(jnp.uint32)
-    if variant == "pipeline":
-        q_tile = min(128, n) if n % 128 else 128
-        npad = -(-n // q_tile) * q_tile
-        scan = functools.partial(_upd.update_scan_pipeline, q_tile=q_tile,
-                                 opt=opt, dim=cfg.dim,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    elif variant == "tlp":
-        npad = n
-        scan = functools.partial(_upd.update_scan_tlp, opt=opt, dim=cfg.dim,
-                                 use_digest=cfg.use_digest,
-                                 interpret=interpret)
-    else:
-        raise ValueError(f"unknown variant {variant!r}")
     b2 = probe.bucket2 if cfg.buckets_per_key == 2 else probe.bucket1
+
+    def call(q_tile, plane, *a):
+        return _upd.update_scan(
+            state.digests, state.key_hi, state.key_lo, plane, *a, opt=opt,
+            dim=cfg.dim, width=cfg.total_value_dim, variant=variant,
+            q_tile=q_tile, use_digest=cfg.use_digest, interpret=interpret)
+
     # the qvalid gate travels INTO the kernel: EMPTY padding lanes match
     # empty slots, and unlike the read-only find pass an updater cannot
     # re-mask after the fact — the gate must dominate the store
-    found, new_values = scan(
-        state.digests, state.key_hi, state.key_lo, state.values,
-        _pad_to(probe.bucket1, npad),
-        _pad_to(b2, npad),
-        _pad_to(qd, npad),
-        _pad_to(keys.hi, npad, u64.EMPTY_HI),
-        _pad_to(keys.lo, npad, u64.EMPTY_LO),
-        _pad_to(probe.valid.astype(jnp.int32), npad),
-        _pad_to(grads.astype(state.values.dtype), npad),
-    )
+    found, new_values = _per_query_write(
+        call, state.values,
+        (probe.bucket1, b2, probe.digest.astype(jnp.uint32), keys.hi,
+         keys.lo, probe.valid.astype(jnp.int32),
+         grads.astype(state.values.dtype)),
+        (0, 0, 0, u64.EMPTY_HI, u64.EMPTY_LO, 0, 0))
     return UpdateRows(state=state._replace(values=new_values),
-                      found=found[:n].astype(bool))
+                      found=found.astype(bool))
 
 
 def update_composed_kernel(
@@ -461,13 +473,10 @@ def update_composed_kernel(
     if interpret is None:
         interpret = default_interpret()
     loc = locate_kernel(state, cfg, keys, variant=variant, interpret=interpret)
-    rows_idx = jnp.clip(loc.row, 0, state.values.shape[0] - 1)
-    rows = _ga.gather_rows(state.values, rows_idx,
-                           loc.found.astype(jnp.int32), interpret=interpret)
+    rows = _gather(state.values, cfg, loc.row, loc.found, interpret)
     new_rows = opt.apply(rows, grads, cfg.dim).astype(state.values.dtype)
-    new_values = _sc.scatter_rows(
-        state.values, rows_idx, new_rows, loc.found.astype(jnp.int32),
-        add=False, interpret=interpret)
+    new_values = _scatter(state.values, cfg, loc.row, new_rows, loc.found,
+                          False, interpret)
     return UpdateRows(state=state._replace(values=new_values),
                       found=loc.found)
 
@@ -495,7 +504,7 @@ def bucket_stats_kernel(state: HKVState, *, interpret: bool | None = None):
     if interpret is None:
         interpret = default_interpret()
     b = state.key_hi.shape[0]
-    tile = 8 if b % 8 == 0 else 1
+    tile = 8 if b % 8 == 0 else b
     return _ss.bucket_stats(
         state.key_hi, state.key_lo, state.score_hi, state.score_lo,
         bucket_tile=tile, interpret=interpret,
@@ -505,6 +514,17 @@ def bucket_stats_kernel(state: HKVState, *, interpret: bool | None = None):
 # =============================================================================
 # Inserter path: fused upsert/evict kernels (upsert_scan + gather/scatter)
 # =============================================================================
+
+
+def _upsert_probe(state: HKVState, cfg: HKVConfig, probe, qd, keys: U64,
+                  interpret: bool):
+    """upsert_probe over a whole batch (padded and chunked)."""
+    scan = lambda q_tile, *a: _us.upsert_probe(
+        state.digests, state.key_hi, state.key_lo, state.score_hi,
+        state.score_lo, *a, q_tile=q_tile, use_digest=cfg.use_digest,
+        interpret=interpret)
+    return _per_query(scan, (probe.bucket1, probe.bucket2, qd, keys.hi,
+                             keys.lo), (0, 0, 0, u64.EMPTY_HI, u64.EMPTY_LO))
 
 
 def _kernel_locate_stage(cfg: HKVConfig, interpret: bool):
@@ -522,13 +542,9 @@ def _kernel_locate_stage(cfg: HKVConfig, interpret: bool):
         return locate_single
 
     def locate(state: HKVState, _cfg: HKVConfig, keys: U64, probe):
-        found, hit_sel, hit_slot, _tgt = _us.upsert_probe(
-            state.digests, state.key_hi, state.key_lo,
-            state.score_hi, state.score_lo,
-            probe.bucket1, probe.bucket2,
-            probe.digest.astype(jnp.uint32), keys.hi, keys.lo,
-            use_digest=cfg.use_digest, interpret=interpret,
-        )
+        found, hit_sel, hit_slot, _tgt = _upsert_probe(
+            state, cfg, probe, probe.digest.astype(jnp.uint32), keys,
+            interpret)
         fnd = found.astype(bool) & probe.valid
         bucket = jnp.where(
             found.astype(bool) & (hit_sel == 1), probe.bucket2, probe.bucket1
@@ -553,13 +569,9 @@ def _kernel_select_stage(cfg: HKVConfig, interpret: bool):
         if cfg.buckets_per_key == 1:
             return probe.bucket1
         zeros = jnp.zeros_like(probe.bucket1, jnp.uint32)
-        _f, _hs, _sl, tgt_sel = _us.upsert_probe(
-            state.digests, state.key_hi, state.key_lo,
-            state.score_hi, state.score_lo,
-            probe.bucket1, probe.bucket2,
-            zeros, zeros, zeros,  # match result unused: stats-only pass
-            use_digest=cfg.use_digest, interpret=interpret,
-        )
+        # match result unused: stats-only pass
+        _f, _hs, _sl, tgt_sel = _upsert_probe(
+            state, cfg, probe, zeros, U64(zeros, zeros), interpret)
         return jnp.where(tgt_sel == 1, probe.bucket2, probe.bucket1)
 
     return select
@@ -570,10 +582,11 @@ def _kernel_victim_stage(cfg: HKVConfig, interpret: bool):
 
     def victim(state: HKVState, _cfg: HKVConfig, bkt_g, rank):
         s = cfg.slots_per_bucket
-        vslot, vocc, vsh, vsl, vkh, vkl = _us.claim_scan(
-            state.key_hi, state.key_lo, state.score_hi, state.score_lo,
-            bkt_g, jnp.clip(rank, 0, s - 1), interpret=interpret,
-        )
+        scan = lambda q_tile, *a: _us.claim_scan(
+            state.key_hi, state.key_lo, state.score_hi, state.score_lo, *a,
+            q_tile=q_tile, interpret=interpret)
+        vslot, vocc, vsh, vsl, vkh, vkl = _per_query(
+            scan, (bkt_g, jnp.clip(rank, 0, s - 1)), (0, 0))
         return vslot, vocc.astype(bool), U64(vsh, vsl), U64(vkh, vkl)
 
     return victim
@@ -585,9 +598,7 @@ def _kernel_gather_stage(cfg: HKVConfig, interpret: bool):
     def gather(_cfg: HKVConfig, values, rows, mask):
         if cfg.value_tier != "hbm":  # host-tier rows cross via the jnp path
             return jnp_gather(cfg, values, rows, mask)
-        rows = jnp.clip(rows, 0, values.shape[0] - 1)
-        return _ga.gather_rows(values, rows, mask.astype(jnp.int32),
-                               interpret=interpret)
+        return _gather(values, cfg, rows, mask, interpret)
 
     return gather
 
@@ -598,10 +609,7 @@ def _kernel_scatter_stage(cfg: HKVConfig, interpret: bool):
     def scatter(_cfg: HKVConfig, values, rows, updates, mask):
         if cfg.value_tier != "hbm":
             return jnp_scatter(cfg, values, rows, updates, mask)
-        rows = jnp.clip(rows, 0, values.shape[0] - 1)
-        return _sc.scatter_rows(values, rows, updates.astype(values.dtype),
-                                mask.astype(jnp.int32), add=False,
-                                interpret=interpret)
+        return _scatter(values, cfg, rows, updates, mask, False, interpret)
 
     return scatter
 
@@ -669,17 +677,14 @@ def insert_and_evict_kernel(
     )
 
 
-def gather_rows_kernel(state: HKVState, loc: find_mod.Locate, dim: int,
-                       *, interpret: bool | None = None) -> jax.Array:
+def gather_rows_kernel(state: HKVState, cfg: HKVConfig, loc: find_mod.Locate,
+                       dim: int, *, interpret: bool | None = None) -> jax.Array:
     """Position-addressed value gather at `loc` via the row-pipeline kernel
     (hbm tier only — tier crossings stay on the jnp `tier_gather` path).
     Missing keys return zero rows, matching `find_mod.gather_values`."""
     if interpret is None:
         interpret = default_interpret()
-    rows = jnp.clip(loc.row, 0, state.values.shape[0] - 1)
-    return _ga.gather_rows(
-        state.values, rows, loc.found.astype(jnp.int32), interpret=interpret,
-    )[:, :dim]
+    return _gather(state.values, cfg, loc.row, loc.found, interpret)[:, :dim]
 
 
 def find_or_insert_kernel(
@@ -712,11 +717,10 @@ def find_or_insert_kernel(
         write_hit_values=False, interpret=interpret,
     )
     if cfg.value_tier == "hbm":
-        vals = gather_rows_kernel(res.state, res.loc, cfg.dim,
+        vals = gather_rows_kernel(res.state, cfg, res.loc, cfg.dim,
                                   interpret=interpret)
     else:
-        vals = find_mod.gather_values(res.state, res.loc, cfg.dim,
-                                      cfg.value_tier)
+        vals = find_mod.gather_values(res.state, res.loc, cfg.dim, cfg)
     vals = jnp.where(res.loc.found[:, None], vals, init_values[:, : cfg.dim])
     return res.state, vals, res.found, res.status
 

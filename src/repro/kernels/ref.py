@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import find, u64
+from repro.core.table import tier_gather, tier_scatter
 
 
 def digest_scan_ref(
@@ -43,12 +44,13 @@ def find_scan_ref(
     tkey_lo: jax.Array,    # uint32 [B, S]
     tscore_hi: jax.Array,  # uint32 [B, S]
     tscore_lo: jax.Array,  # uint32 [B, S]
-    tvalues: jax.Array,    # [B*S, V] value plane (position addressing §3.6)
+    tvalues: jax.Array,    # packed value plane (position addressing §3.6)
     bucket1: jax.Array,    # int32  [N] primary candidate bucket
     bucket2: jax.Array,    # int32  [N] secondary candidate (== bucket1 single)
     qdigest: jax.Array,    # uint32 [N]
     qkey_hi: jax.Array,    # uint32 [N]
     qkey_lo: jax.Array,    # uint32 [N]
+    width: int,            # logical value row width
     use_digest: bool = True,
 ):
     """Ground truth for the fused find kernel (find_scan.py).
@@ -82,7 +84,7 @@ def find_scan_ref(
     bucket = jnp.where(sel == 1, bucket2, bucket1)
     shi = jnp.where(found, tscore_hi[bucket, slot], 0)
     slo = jnp.where(found, tscore_lo[bucket, slot], 0)
-    vals = tvalues[bucket * s + slot]
+    vals = tier_gather("hbm", tvalues, bucket * s + slot, width)
     vals = jnp.where(found[:, None], vals, jnp.zeros_like(vals))
     return found.astype(jnp.int32), sel, slot, shi, slo, vals
 
@@ -91,7 +93,7 @@ def update_scan_ref(
     tdigests: jax.Array,   # uint8  [B, S]
     tkey_hi: jax.Array,    # uint32 [B, S]
     tkey_lo: jax.Array,    # uint32 [B, S]
-    tvalues: jax.Array,    # [B*S, V] value plane (position addressing §3.6)
+    tvalues: jax.Array,    # packed value plane (position addressing §3.6)
     bucket1: jax.Array,    # int32  [N] primary candidate bucket
     bucket2: jax.Array,    # int32  [N] secondary candidate (== bucket1 single)
     qdigest: jax.Array,    # uint32 [N]
@@ -101,6 +103,7 @@ def update_scan_ref(
     grads: jax.Array,      # [N, dim] segment-summed gradient rows
     opt,                   # SparseOptimizer (static variant)
     dim: int,
+    width: int,            # logical value row width (dim + aux)
     use_digest: bool = True,
 ):
     """Ground truth for the fused updater kernel (update_scan.py).
@@ -115,7 +118,7 @@ def update_scan_ref(
     empty slots (both are the all-ones sentinel in the key planes): a
     read-only kernel can re-mask afterwards, a writing kernel cannot.
 
-    Returns (found i32 [N], new_values [B*S, V]).
+    Returns (found i32 [N], new_values — the updated plane).
     """
     s = tdigests.shape[1]
 
@@ -137,36 +140,36 @@ def update_scan_ref(
     slot = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
     bucket = jnp.where(sel == 1, bucket2, bucket1)
     row = bucket * s + slot
-    raw = tvalues[row]
+    raw = tier_gather("hbm", tvalues, row, width)
     new_rows = opt.apply(raw, grads, dim).astype(tvalues.dtype)
-    r = jnp.where(found, row, tvalues.shape[0])  # OOB -> dropped
-    new_values = tvalues.at[r].set(
-        jnp.where(found[:, None], new_rows, raw), mode="drop")
+    r = jnp.where(found, row, tkey_hi.size)  # past the last slot: dropped
+    new_values = tier_scatter("hbm", tvalues, r,
+                              jnp.where(found[:, None], new_rows, raw))
     return found.astype(jnp.int32), new_values
 
 
 def gather_rows_ref(
-    values: jax.Array,  # [R, D]
-    rows: jax.Array,    # int32 [N]
+    values: jax.Array,  # packed value plane
+    rows: jax.Array,    # int32 [N] logical rows (in range)
     mask: jax.Array,    # int32/bool [N] — rows with mask==0 return zeros
+    width: int,         # logical row width
 ) -> jax.Array:
-    """Position-addressed value gather (§3.6): out[i] = values[rows[i]]."""
-    out = values[jnp.clip(rows, 0, values.shape[0] - 1)]
+    """Position-addressed value gather (§3.6): out[i] = row rows[i]."""
+    out = tier_gather("hbm", values, rows, width)
     return jnp.where(mask.astype(bool)[:, None], out, jnp.zeros_like(out))
 
 
 def scatter_rows_ref(
-    values: jax.Array,  # [R, D]
+    values: jax.Array,  # packed value plane
     rows: jax.Array,    # int32 [N] — must be unique where mask set
-    updates: jax.Array,  # [N, D]
+    updates: jax.Array,  # [N, W]
     mask: jax.Array,    # [N]
     add: bool,
+    num_rows: int,      # logical rows in the plane (the table's capacity)
 ) -> jax.Array:
-    """Updater-role write-back: values[rows[i]] (+)= updates[i] where mask."""
-    r = jnp.where(mask.astype(bool), rows, values.shape[0])  # OOB -> dropped
-    if add:
-        return values.at[r].add(updates.astype(values.dtype), mode="drop")
-    return values.at[r].set(updates.astype(values.dtype), mode="drop")
+    """Updater-role write-back: row rows[i] (+)= updates[i] where mask."""
+    r = jnp.where(mask.astype(bool), rows, num_rows)  # past the end: dropped
+    return tier_scatter("hbm", values, r, updates, add=add)
 
 
 def bucket_stats_ref(

@@ -25,26 +25,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.find import match_lanes
 from repro.core.u64 import empty_lanes
+from repro.kernels.bucket_rows import ordered
 
 
 def _stats_kernel(kh_ref, kl_ref, sh_ref, sl_ref, occ_ref, mh_ref, ml_ref, am_ref):
-    ONES = jnp.uint32(0xFFFFFFFF)
+    # uint32 scores are reduced in `ordered` int32 form (the chip reduces
+    # no unsigned integers); flipping the sign bit back restores them
+    top = jnp.iinfo(jnp.int32).max
     occ_mask = ~empty_lanes(kh_ref[...], kl_ref[...])
-    occ_ref[:, 0] = jnp.sum(occ_mask.astype(jnp.int32), axis=1)
-    shi = jnp.where(occ_mask, sh_ref[...], ONES)
-    slo = jnp.where(occ_mask, sl_ref[...], ONES)
-    min_hi = jnp.min(shi, axis=1)
-    lo_cand = jnp.where(shi == min_hi[:, None], slo, ONES)
-    min_lo = jnp.min(lo_cand, axis=1)
-    mh_ref[:, 0] = min_hi
-    ml_ref[:, 0] = min_lo
-    is_min = match_lanes(shi, slo, min_hi[:, None], min_lo[:, None])
-    am_ref[:, 0] = jnp.argmax(is_min, axis=1).astype(jnp.int32)
+    occ_ref[...] = jnp.sum(occ_mask.astype(jnp.int32), axis=1, keepdims=True)
+    shi = jnp.where(occ_mask, ordered(sh_ref[...]), top)
+    slo = jnp.where(occ_mask, ordered(sl_ref[...]), top)
+    min_hi = jnp.min(shi, axis=1, keepdims=True)
+    min_lo = jnp.min(jnp.where(shi == min_hi, slo, top), axis=1, keepdims=True)
+    mh_ref[...] = min_hi
+    ml_ref[...] = min_lo
+    is_min = match_lanes(shi, slo, min_hi, min_lo)
+    lane = jax.lax.broadcasted_iota(jnp.int32, is_min.shape, 1)
+    first = jnp.min(jnp.where(is_min, lane, is_min.shape[1]), axis=1,
+                    keepdims=True)
+    am_ref[...] = jnp.where(first < is_min.shape[1], first, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("bucket_tile", "interpret"))
-def bucket_stats(tkey_hi, tkey_lo, score_hi, score_lo, *, bucket_tile: int = 8,
-                 interpret: bool = True):
+def bucket_stats(tkey_hi, tkey_lo, score_hi, score_lo, *, bucket_tile: int,
+                 interpret: bool):
     """Per-bucket (occ, min_hi, min_lo, argmin) over the whole table.
 
     bucket_tile=8 keeps each block at the natural (8, 128) vreg shape:
@@ -62,11 +67,12 @@ def bucket_stats(tkey_hi, tkey_lo, score_hi, score_lo, *, bucket_tile: int = 8,
         out_specs=[out_spec] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((b, 1), jnp.uint32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         interpret=interpret,
         name="hkv_bucket_stats",
     )(tkey_hi, tkey_lo, score_hi, score_lo)
-    return occ[:, 0], mh[:, 0], ml[:, 0], am[:, 0]
+    unorder = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32) ^ jnp.uint32(0x80000000)
+    return occ[:, 0], unorder(mh[:, 0]), unorder(ml[:, 0]), am[:, 0]

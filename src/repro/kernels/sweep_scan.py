@@ -54,7 +54,7 @@ def _sweep_kernel(kind, kh_ref, kl_ref, sh_ref, sl_ref,
                    static_argnames=("kind", "bucket_tile", "interpret"))
 def sweep_match(tkey_hi, tkey_lo, score_hi, score_lo,
                 a_hi, a_lo, b_hi, b_lo, *, kind: str,
-                bucket_tile: int = 8, interpret: bool = True):
+                bucket_tile: int = 8, interpret: bool):
     """Whole-table predicate evaluation.
 
     Returns (match bool [B, S], per-bucket count int32 [B]); `match` is

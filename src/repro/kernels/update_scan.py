@@ -37,11 +37,14 @@ Mask domination (cache semantics — rejected embeddings do not train):
 
 Write-after-read ordering: each query's value RMW is fully serialized
 (read.wait before apply, write.wait before the next query's read) because
-miss lanes alias row ``b1*S+0`` and may collide with a hit lane's row.
-The pipeline variant keeps its two-slot metadata double buffer — query
-q+1's bucket rows stream while query q's row is read-modified-written —
-so the latency hiding lives where the traffic is (metadata), and the
-serialized value row is the correctness anchor.
+miss lanes alias row ``b1*S+0`` and distinct slots share a 128-lane row
+of the packed value plane.  The pipeline variant keeps its two-slot
+metadata double buffer — query q+1's bucket rows stream while query q's
+row is read-modified-written — so the latency hiding lives where the
+traffic is (metadata), and the serialized value row is the correctness
+anchor.  The optimizer runs on the plane row rotated so the slot's lanes
+come first (``SparseOptimizer.apply_lanes``) and the result is rotated
+back and lane-masked into the row.
 
 PRECONDITION (enforced by callers, asserted in tests): query keys are
 unique within a batch (the embedding layer dedupes and segment-sums
@@ -63,71 +66,86 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.find import match_lanes
-from repro.kernels import compat
-from repro.kernels.find_scan import _merge_hits
-
-LANES = 128  # TPU vreg minor dimension == slots per bucket
+from repro.kernels import bucket_rows as br
+from repro.kernels.find_scan import _merge_hits, slot_lanes, to_front
 
 
-# =============================================================================
-# TLP variant: one query per grid step, auto-pipelined metadata row blocks
-# =============================================================================
-
-
-def _tlp_kernel(opt, dim, use_digest, slots,
-                b1_ref, b2_ref, qd_ref, qh_ref, ql_ref, qv_ref,
-                d1_ref, h1_ref, l1_ref, d2_ref, h2_ref, l2_ref,
-                g_ref, v_hbm, found_ref, out_hbm, vbuf, rsem, wsem):
+def _kernel(opt, dim, width, depth, q_tile, group, use_digest,
+            b1_ref, b2_ref, qd_ref, qh_ref, ql_ref, qv_ref,
+            td, th, tl, g_ref, v_hbm, found_ref, out_hbm,
+            d1, h1, l1, d2, h2, l2, vbuf, sems, rsem, wsem):
     del v_hbm  # aliased with out_hbm — all row traffic goes through out_hbm
     i = pl.program_id(0)
-    qd = qd_ref[i]
-    qh = qh_ref[i]
-    ql = ql_ref[i]
+    lanes = out_hbm.shape[1]
+    planes = (td, th, tl)
 
-    def row_match(d_ref, h_ref, l_ref):
-        if use_digest:
-            m = match_lanes(h_ref[0, :], l_ref[0, :], qh, ql,
-                            d_ref[0, :].astype(jnp.uint32), qd)
-        else:
-            m = match_lanes(h_ref[0, :], l_ref[0, :], qh, ql)
-        return jnp.any(m), jnp.argmax(m).astype(jnp.int32)
+    def copies(q, s):
+        n = i * q_tile + q
+        cps = []
+        for k, (b, bufs) in enumerate(((b1_ref[n], (d1, h1, l1)),
+                                       (b2_ref[n], (d2, h2, l2)))):
+            for j, (p, buf) in enumerate(zip(planes, bufs)):
+                cps.append(br.row_copy(p, buf.at[s], sems.at[s, 3 * k + j],
+                                       b, group if j == 0 else 1))
+        return cps
 
-    hit1, slot1 = row_match(d1_ref, h1_ref, l1_ref)
-    hit2, slot2 = row_match(d2_ref, h2_ref, l2_ref)
-    found, sel, slot = _merge_hits(slots, (hit1, slot1, hit2, slot2))
-    # qvalid gate must dominate the store: an EMPTY-padded query matches
-    # empty slots (both are the all-ones key sentinel) and would otherwise
-    # train a vacant row.
-    found = found & (qv_ref[i] != 0)
-    found_ref[0, 0] = found.astype(jnp.int32)
+    def body(q, s):
+        n = i * q_tile + q
+        qh = qh_ref[n].astype(jnp.uint32)
+        ql = ql_ref[n].astype(jnp.uint32)
+        qd = qd_ref[n].astype(jnp.uint32)
 
-    b = jnp.where(sel == 0, b1_ref[i], b2_ref[i])
-    row = b * slots + slot
+        def row_match(b, d, h, l):
+            if use_digest:
+                m = match_lanes(h[s], l[s], qh, ql, br.digest_row(d[s], b), qd)
+            else:
+                m = match_lanes(h[s], l[s], qh, ql)
+            return br.first_lane(m)
 
-    # serialized row RMW: read.wait -> apply -> masked write -> write.wait
-    rd = pltpu.make_async_copy(out_hbm.at[pl.ds(row, 1), :], vbuf, rsem)
-    rd.start()
-    rd.wait()
-    raw = vbuf[0, :]
-    new = opt.apply(raw[None, :], g_ref[0, :][None, :], dim)[0]
-    vbuf[0, :] = jnp.where(found, new.astype(raw.dtype), raw)
-    wr = pltpu.make_async_copy(vbuf, out_hbm.at[pl.ds(row, 1), :], wsem)
-    wr.start()
-    wr.wait()
+        b1, b2 = b1_ref[n], b2_ref[n]
+        hit1, slot1 = row_match(b1, d1, h1, l1)
+        hit2, slot2 = row_match(b2, d2, h2, l2)
+        found, sel, slot = _merge_hits(hit1, slot1, hit2, slot2)
+        # qvalid gate must dominate the store: an EMPTY-padded query
+        # matches empty slots (both are the all-ones key sentinel) and
+        # would otherwise train a vacant row.
+        found = found & (qv_ref[n] != 0)
+        found_ref[n] = found.astype(jnp.int32)
+
+        row = jnp.where(sel == 0, b1, b2) * th.shape[1] + slot
+        prow, lane0 = slot_lanes(row, width, lanes)
+        # serialized row RMW: read.wait -> apply -> masked write -> write.wait
+        rd = pltpu.make_async_copy(out_hbm.at[pl.ds(prow, 1), :], vbuf, rsem)
+        rd.start()
+        rd.wait()
+        raw = vbuf[...]
+        roll = lambda v, k: pltpu.roll(v, k % lanes, 1)
+        new = opt.apply_lanes(to_front(raw, lane0, lanes, width),
+                              g_ref[pl.ds(q, 1), :], dim, roll)
+        if lanes != width:
+            new = roll(new, lane0)
+        lane = br.lane_iota(lanes)
+        mine = found & (lane >= lane0) & (lane < lane0 + width)
+        vbuf[...] = jnp.where(mine, new.astype(raw.dtype), raw)
+        wr = pltpu.make_async_copy(vbuf, out_hbm.at[pl.ds(prow, 1), :], wsem)
+        wr.start()
+        wr.wait()
+
+    br.query_loop(q_tile, depth, copies, body)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("opt", "dim", "use_digest", "interpret"))
-def update_scan_tlp(tdigests, tkey_hi, tkey_lo, tvalues,
-                    bucket1, bucket2, qdigest, qkey_hi, qkey_lo, qvalid,
-                    grads, *, opt, dim: int,
-                    use_digest: bool = True, interpret: bool = True):
-    """Fused update, TLP tier: one query per grid step.
+@functools.partial(jax.jit, static_argnames=(
+    "opt", "dim", "width", "variant", "q_tile", "use_digest", "interpret"))
+def update_scan(tdigests, tkey_hi, tkey_lo, tvalues,
+                bucket1, bucket2, qdigest, qkey_hi, qkey_lo, qvalid,
+                grads, *, opt, dim: int, width: int, variant: str,
+                q_tile: int, use_digest: bool, interpret: bool):
+    """Fused update over a tile-padded batch.
 
-    tvalues is updated IN PLACE (input/output aliased).  Returns
-    (found i32 [N], new_values [B*S, V]):
+    tvalues (the packed value plane, logical row width `width`) is updated
+    IN PLACE (input/output aliased).  Returns (found i32 [N], new_values):
       found       1 iff the key matched a live slot AND qvalid[i] != 0
-      new_values  the value plane with each hit row replaced by
+      new_values  the plane with each hit row replaced by
                   ``opt.apply(row, grads[i], dim)``; miss/invalid lanes
                   leave their (aliased) rows bit-identical.
 
@@ -136,215 +154,37 @@ def update_scan_tlp(tdigests, tkey_hi, tkey_lo, tvalues,
     Single-bucket mode: pass bucket2 == bucket1.
     """
     n = bucket1.shape[0]
-    s = tdigests.shape[1]
-    row = lambda i, b1, b2: (b1[i], 0)
-    row2 = lambda i, b1, b2: (b2[i], 0)
-    v = tvalues.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),  # qdigest
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_hi
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_lo
-            pl.BlockSpec(memory_space=compat.SMEM),  # qvalid
-            pl.BlockSpec((1, s), row),    # bucket1 digest row
-            pl.BlockSpec((1, s), row),    # bucket1 key_hi row
-            pl.BlockSpec((1, s), row),    # bucket1 key_lo row
-            pl.BlockSpec((1, s), row2),   # bucket2 digest row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_hi row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_lo row
-            pl.BlockSpec((1, grads.shape[1]), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec(memory_space=compat.HBM),  # value plane (aliased)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec(memory_space=compat.HBM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, v), tvalues.dtype),
-            pltpu.SemaphoreType.DMA,   # read semaphore
-            pltpu.SemaphoreType.DMA,   # write semaphore
-        ],
-    )
-    found, vals = pl.pallas_call(
-        functools.partial(_tlp_kernel, opt, dim, use_digest, s),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct(tvalues.shape, tvalues.dtype),
-        ],
-        input_output_aliases={13: 1},  # value plane updated in place
-        interpret=interpret,
-        name="hkv_update_scan_tlp",
-    )(
-        bucket1, bucket2, qdigest, qkey_hi, qkey_lo, qvalid,
-        tdigests, tkey_hi, tkey_lo,
-        tdigests, tkey_hi, tkey_lo,
-        grads, tvalues,
-    )
-    return found[:, 0], vals
-
-
-# =============================================================================
-# Pipeline variant: Q queries per grid step, manual two-slot metadata buffer
-# =============================================================================
-
-
-def _pipeline_kernel(opt, dim, use_digest, q_tile, slots,
-                     b1_ref, b2_ref, qd_ref, qh_ref, ql_ref, qv_ref,
-                     td, th, tl, g_ref, v_hbm,
-                     found_ref, out_hbm,
-                     d1b, h1b, l1b, d2b, h2b, l2b,
-                     vbuf, sems, rsem, wsem):
-    del v_hbm  # aliased with out_hbm — all row traffic goes through out_hbm
-    i = pl.program_id(0)
-
-    def meta_copies(q, slot_):
-        base = i * q_tile + q
-        b1 = b1_ref[base]
-        b2 = b2_ref[base]
-        planes = (td, th, tl)
-        cps = []
-        for j, (p, bf) in enumerate(zip(planes, (d1b, h1b, l1b))):
-            cps.append(pltpu.make_async_copy(
-                p.at[pl.ds(b1, 1), :], bf.at[slot_], sems.at[slot_, j]))
-        for j, (p, bf) in enumerate(zip(planes, (d2b, h2b, l2b))):
-            cps.append(pltpu.make_async_copy(
-                p.at[pl.ds(b2, 1), :], bf.at[slot_], sems.at[slot_, 3 + j]))
-        return cps
-
-    def issue(q, slot_):
-        for c in meta_copies(q, slot_):
-            c.start()
-
-    def wait(q, slot_):
-        for c in meta_copies(q, slot_):
-            c.wait()
-
-    # prologue: prefetch query 0's two candidate bucket rows
-    issue(0, 0)
-
-    q_iota = jax.lax.iota(jnp.int32, q_tile)
-
-    def body(q, founds):
-        cur = jax.lax.rem(q, 2)
-        nxt = jax.lax.rem(q + 1, 2)
-
-        # overlap: issue q+1's metadata DMAs while q is compared + RMW'd
-        @pl.when(q + 1 < q_tile)
-        def _():
-            issue(q + 1, nxt)
-
-        wait(q, cur)
-        qd = qd_ref[0, q]
-        qh = qh_ref[0, q]
-        ql = ql_ref[0, q]
-
-        def row_match(db, hb, lb):
-            if use_digest:
-                m = match_lanes(hb[cur, 0, :], lb[cur, 0, :], qh, ql,
-                                db[cur, 0, :].astype(jnp.uint32), qd)
-            else:
-                m = match_lanes(hb[cur, 0, :], lb[cur, 0, :], qh, ql)
-            return jnp.any(m), jnp.argmax(m).astype(jnp.int32)
-
-        hit1, slot1 = row_match(d1b, h1b, l1b)
-        hit2, slot2 = row_match(d2b, h2b, l2b)
-        found, sel, slot = _merge_hits(slots, (hit1, slot1, hit2, slot2))
-        found = found & (qv_ref[0, q] != 0)  # gate dominates the store
-
-        base = i * q_tile + q
-        b = jnp.where(sel == 0, b1_ref[base], b2_ref[base])
-        row = b * slots + slot
-
-        # serialized row RMW — miss lanes alias row b1*S+0, so query q's
-        # write must retire before query q+1's read (no value-row overlap)
-        rd = pltpu.make_async_copy(out_hbm.at[pl.ds(row, 1), :], vbuf, rsem)
-        rd.start()
-        rd.wait()
-        raw = vbuf[0, :]
-        new = opt.apply(raw[None, :], g_ref[pl.ds(q, 1), :], dim)[0]
-        vbuf[0, :] = jnp.where(found, new.astype(raw.dtype), raw)
-        wr = pltpu.make_async_copy(vbuf, out_hbm.at[pl.ds(row, 1), :], wsem)
-        wr.start()
-        wr.wait()
-
-        return jnp.where(q_iota == q, found.astype(jnp.int32), founds)
-
-    founds = jax.lax.fori_loop(
-        0, q_tile, body, jnp.zeros((q_tile,), jnp.int32))
-    found_ref[0, :] = founds
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "q_tile", "opt", "dim", "use_digest", "interpret"))
-def update_scan_pipeline(tdigests, tkey_hi, tkey_lo, tvalues,
-                         bucket1, bucket2, qdigest, qkey_hi, qkey_lo, qvalid,
-                         grads, *, q_tile: int = 128, opt, dim: int,
-                         use_digest: bool = True, interpret: bool = True):
-    """Fused update, Pipeline tier: Q queries per grid step, manual DMA.
-
-    Same outputs and in-place aliasing as `update_scan_tlp`.  Queries are
-    padded to a multiple of q_tile by the wrapper (padding lanes carry
-    qvalid == 0, so they never write).  Scratch working set: 2 x 6
-    metadata rows + one value row — the value plane itself stays in HBM.
-    """
-    n = bucket1.shape[0]
     assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
-    s = tdigests.shape[1]
-    v = tvalues.shape[1]
-    g = grads.shape[1]
-    tiles = n // q_tile
-    smem_block = lambda: pl.BlockSpec((1, q_tile), lambda i, b1, b2: (i, 0),
-                                      memory_space=compat.SMEM)
+    nb, s = tdigests.shape
+    lanes = tvalues.shape[1]
+    group = br.digest_group(nb)
+    grads = jnp.pad(grads.astype(tvalues.dtype),
+                    ((0, 0), (0, lanes - grads.shape[1])))
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    out_spec, out_shape = br.smem_out(n)
+    meta = lambda dt, rows=1: pltpu.VMEM((2, rows, s), dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(tiles,),
-        in_specs=[
-            smem_block(),   # qdigest
-            smem_block(),   # qkey_hi
-            smem_block(),   # qkey_lo
-            smem_block(),   # qvalid
-            pl.BlockSpec(memory_space=compat.HBM),  # digest plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_hi plane
-            pl.BlockSpec(memory_space=compat.HBM),  # key_lo plane
-            pl.BlockSpec((q_tile, g), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec(memory_space=compat.HBM),  # value plane (aliased)
-        ],
-        out_specs=[
-            pl.BlockSpec((1, q_tile), lambda i, b1, b2: (i, 0)),
-            pl.BlockSpec(memory_space=compat.HBM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, 1, s), jnp.uint8),    # bucket1 digests
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 key_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket1 key_lo
-            pltpu.VMEM((2, 1, s), jnp.uint8),    # bucket2 digests
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 key_hi
-            pltpu.VMEM((2, 1, s), jnp.uint32),   # bucket2 key_lo
-            pltpu.VMEM((1, v), tvalues.dtype),   # value row (serialized RMW)
-            pltpu.SemaphoreType.DMA((2, 6)),
-            pltpu.SemaphoreType.DMA,   # value read semaphore
-            pltpu.SemaphoreType.DMA,   # value write semaphore
-        ],
+        num_scalar_prefetch=6,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()] * 3 + [
+            pl.BlockSpec((q_tile, lanes), lambda i, *_: (i, 0)),
+            br.hbm()],
+        out_specs=[out_spec, br.hbm()],
+        scratch_shapes=(
+            [meta(jnp.uint8, group), meta(jnp.uint32), meta(jnp.uint32)] * 2
+            + [pltpu.VMEM((1, lanes), tvalues.dtype),
+               pltpu.SemaphoreType.DMA((2, 6)),
+               pltpu.SemaphoreType.DMA,    # value read semaphore
+               pltpu.SemaphoreType.DMA]),  # value write semaphore
     )
-    found, vals = pl.pallas_call(
-        functools.partial(_pipeline_kernel, opt, dim, use_digest, q_tile, s),
+    return pl.pallas_call(
+        functools.partial(_kernel, opt, dim, width, br.variant_depth(variant),
+                          q_tile, group, use_digest),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((tiles, q_tile), jnp.int32),
-            jax.ShapeDtypeStruct(tvalues.shape, tvalues.dtype),
-        ],
+        out_shape=[out_shape,
+                   jax.ShapeDtypeStruct(tvalues.shape, tvalues.dtype)],
         input_output_aliases={10: 1},  # value plane updated in place
         interpret=interpret,
-        name="hkv_update_scan_pipeline",
-    )(
-        bucket1, bucket2,
-        qdigest.reshape(tiles, q_tile),
-        qkey_hi.reshape(tiles, q_tile),
-        qkey_lo.reshape(tiles, q_tile),
-        qvalid.reshape(tiles, q_tile),
-        tdigests, tkey_hi, tkey_lo, grads, tvalues,
-    )
-    return found.reshape(n), vals
+        name=f"hkv_update_scan_{variant}",
+    )(bucket1, bucket2, i32(qdigest), i32(qkey_hi), i32(qkey_lo),
+      i32(qvalid), tdigests, tkey_hi, tkey_lo, grads, tvalues)

@@ -23,9 +23,11 @@ kernel-complete the hottest mutation path (DESIGN.md §4).
                 (a 128x128 VPU compare block), so every query is
                 independent — no serialization, conflict-free claims.
 
-Both kernels execute with ``interpret=True`` off-TPU and are swept against
-the pure-jnp stages in tests/test_upsert_kernel.py (bit-identical statuses,
-evicted pairs, and post-state required).
+Both walk a tile of queries per grid step, streaming query q+1's bucket
+rows while query q is resolved (``bucket_rows.query_loop``).  They
+execute in interpret mode off-TPU and are swept against the pure-jnp
+stages in tests/test_upsert_kernel.py (bit-identical statuses, evicted
+pairs, and post-state required).
 """
 
 from __future__ import annotations
@@ -39,7 +41,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.find import match_lanes
 from repro.core.u64 import empty_lanes
-from repro.kernels import compat
+from repro.kernels import bucket_rows as br
+
+_PLANES = 5  # digest, key_hi, key_lo, score_hi, score_lo
+
+
+def _lex_min(hi, lo):
+    """Lexicographic u64 min of (hi, lo) lane rows, in `br.ordered` int32
+    form (order-exact; only ever compared)."""
+    min_hi = jnp.min(hi)
+    min_lo = jnp.min(jnp.where(hi == min_hi, lo, jnp.iinfo(jnp.int32).max))
+    return min_hi, min_lo
 
 
 # =============================================================================
@@ -47,58 +59,72 @@ from repro.kernels import compat
 # =============================================================================
 
 
-def _probe_kernel(use_digest, slots, b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
-                  d1_ref, h1_ref, l1_ref, s1h_ref, s1l_ref,
-                  d2_ref, h2_ref, l2_ref, s2h_ref, s2l_ref,
-                  found_ref, hitsel_ref, slot_ref, tgtsel_ref):
+def _probe_kernel(q_tile, group, use_digest,
+                  b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
+                  td, th, tl, tsh, tsl,
+                  found_ref, hitsel_ref, slot_ref, tgtsel_ref,
+                  d1, h1, l1, sh1, sl1, d2, h2, l2, sh2, sl2, sems):
     i = pl.program_id(0)
-    qd = qd_ref[i]
-    qh = qh_ref[i]
-    ql = ql_ref[i]
-    ONES = jnp.uint32(0xFFFFFFFF)
+    planes = (td, th, tl, tsh, tsl)
+    slots = th.shape[1]
 
-    def row_pass(d_ref, h_ref, l_ref, sh_ref, sl_ref):
-        hh = h_ref[0, :]
-        ll = l_ref[0, :]
-        # full-key compare, gated by the one-lane-row digest pre-filter —
-        # the shared `core.find.match_lanes` oracle
-        if use_digest:
-            m = match_lanes(hh, ll, qh, ql, d_ref[0, :].astype(jnp.uint32), qd)
-        else:
-            m = match_lanes(hh, ll, qh, ql)
-        occ_mask = ~empty_lanes(hh, ll)
-        # lexicographic u64 min over live slots (empties -> +inf sentinel)
-        shi = jnp.where(occ_mask, sh_ref[0, :], ONES)
-        slo = jnp.where(occ_mask, sl_ref[0, :], ONES)
-        min_hi = jnp.min(shi)
-        min_lo = jnp.min(jnp.where(shi == min_hi, slo, ONES))
-        return (
-            jnp.any(m),
-            jnp.argmax(m).astype(jnp.int32),
-            jnp.sum(occ_mask.astype(jnp.int32)),
-            min_hi,
-            min_lo,
-        )
+    def copies(q, s):
+        n = i * q_tile + q
+        cps = []
+        for k, (b, bufs) in enumerate(((b1_ref[n], (d1, h1, l1, sh1, sl1)),
+                                       (b2_ref[n], (d2, h2, l2, sh2, sl2)))):
+            for j, (p, buf) in enumerate(zip(planes, bufs)):
+                cps.append(br.row_copy(p, buf.at[s],
+                                       sems.at[s, _PLANES * k + j],
+                                       b, group if j == 0 else 1))
+        return cps
 
-    hit1, slot1, occ1, m1h, m1l = row_pass(d1_ref, h1_ref, l1_ref, s1h_ref, s1l_ref)
-    hit2, slot2, occ2, m2h, m2l = row_pass(d2_ref, h2_ref, l2_ref, s2h_ref, s2l_ref)
+    def body(q, s):
+        n = i * q_tile + q
+        qh = qh_ref[n].astype(jnp.uint32)
+        ql = ql_ref[n].astype(jnp.uint32)
+        qd = qd_ref[n].astype(jnp.uint32)
 
-    found_ref[0, 0] = (hit1 | hit2).astype(jnp.int32)
-    hitsel_ref[0, 0] = jnp.where(hit1, 0, 1).astype(jnp.int32)
-    slot_ref[0, 0] = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
-    # dual-bucket two-phase policy: D1 less-loaded while free slots exist,
-    # D2 lower-min-score at full occupancy (ties -> primary in both phases)
-    any_free = (occ1 < slots) | (occ2 < slots)
-    d1_sel = (occ2 < occ1).astype(jnp.int32)
-    d2_sel = ((m2h < m1h) | ((m2h == m1h) & (m2l < m1l))).astype(jnp.int32)
-    tgtsel_ref[0, 0] = jnp.where(any_free, d1_sel, d2_sel)
+        def row_pass(b, d, h, l, sh, sl):
+            hh, ll = h[s], l[s]
+            # full-key compare, gated by the one-lane-row digest
+            # pre-filter — the shared `core.find.match_lanes` oracle
+            if use_digest:
+                m = match_lanes(hh, ll, qh, ql, br.digest_row(d[s], b), qd)
+            else:
+                m = match_lanes(hh, ll, qh, ql)
+            occ_mask = ~empty_lanes(hh, ll)
+            # lexicographic u64 min over live slots (empties -> +inf)
+            top = jnp.iinfo(jnp.int32).max
+            min_hi, min_lo = _lex_min(
+                jnp.where(occ_mask, br.ordered(sh[s]), top),
+                jnp.where(occ_mask, br.ordered(sl[s]), top))
+            hit, slot = br.first_lane(m)
+            return hit, slot, jnp.sum(occ_mask.astype(jnp.int32)), min_hi, min_lo
+
+        hit1, slot1, occ1, m1h, m1l = row_pass(b1_ref[n], d1, h1, l1, sh1, sl1)
+        hit2, slot2, occ2, m2h, m2l = row_pass(b2_ref[n], d2, h2, l2, sh2, sl2)
+
+        found_ref[n] = (hit1 | hit2).astype(jnp.int32)
+        hitsel_ref[n] = jnp.where(hit1, 0, 1).astype(jnp.int32)
+        slot_ref[n] = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
+        # dual-bucket two-phase policy: D1 less-loaded while free slots
+        # exist, D2 lower-min-score at full occupancy (ties -> primary)
+        any_free = (occ1 < slots) | (occ2 < slots)
+        d1_sel = (occ2 < occ1).astype(jnp.int32)
+        d2_sel = ((m2h < m1h) | ((m2h == m1h) & (m2l < m1l))).astype(jnp.int32)
+        tgtsel_ref[n] = jnp.where(any_free, d1_sel, d2_sel)
+
+    br.query_loop(q_tile, 2, copies, body)
 
 
-@functools.partial(jax.jit, static_argnames=("use_digest", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("q_tile", "use_digest", "interpret"))
 def upsert_probe(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
                  bucket1, bucket2, qdigest, qkey_hi, qkey_lo, *,
-                 use_digest: bool = True, interpret: bool = True):
-    """Fused per-query probe over both candidate bucket rows.
+                 q_tile: int, use_digest: bool, interpret: bool):
+    """Fused per-query probe over both candidate bucket rows (N a multiple
+    of q_tile; the wrapper pads).
 
     Returns (found, hit_sel, hit_slot, tgt_sel) int32 [N]:
       found    1 iff the key matched in either candidate bucket
@@ -110,41 +136,29 @@ def upsert_probe(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
     to 0 by the tie--> -primary rule.
     """
     n = bucket1.shape[0]
-    s = tdigests.shape[1]
-    row = lambda i, b1, b2: (b1[i], 0)
-    row2 = lambda i, b1, b2: (b2[i], 0)
+    assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
+    nb, s = tdigests.shape
+    group = br.digest_group(nb)
+    i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
+    out_spec, out_shape = br.smem_out(n)
+    meta = lambda dt, rows=1: pltpu.VMEM((2, rows, s), dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),  # qdigest
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_hi
-            pl.BlockSpec(memory_space=compat.SMEM),  # qkey_lo
-            pl.BlockSpec((1, s), row),    # bucket1 digest row
-            pl.BlockSpec((1, s), row),    # bucket1 key_hi row
-            pl.BlockSpec((1, s), row),    # bucket1 key_lo row
-            pl.BlockSpec((1, s), row),    # bucket1 score_hi row
-            pl.BlockSpec((1, s), row),    # bucket1 score_lo row
-            pl.BlockSpec((1, s), row2),   # bucket2 digest row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_hi row
-            pl.BlockSpec((1, s), row2),   # bucket2 key_lo row
-            pl.BlockSpec((1, s), row2),   # bucket2 score_hi row
-            pl.BlockSpec((1, s), row2),   # bucket2 score_lo row
-        ],
-        out_specs=[pl.BlockSpec((1, 1), lambda i, b1, b2: (i, 0))] * 4,
+        num_scalar_prefetch=5,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()] * _PLANES,
+        out_specs=[out_spec] * 4,
+        scratch_shapes=(
+            ([meta(jnp.uint8, group)] + [meta(jnp.uint32)] * 4) * 2
+            + [pltpu.SemaphoreType.DMA((2, 2 * _PLANES))]),
     )
-    found, hit_sel, hit_slot, tgt_sel = pl.pallas_call(
-        functools.partial(_probe_kernel, use_digest, s),
+    return pl.pallas_call(
+        functools.partial(_probe_kernel, q_tile, group, use_digest),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32)] * 4,
+        out_shape=[out_shape] * 4,
         interpret=interpret,
         name="hkv_upsert_probe",
-    )(
-        bucket1, bucket2, qdigest, qkey_hi, qkey_lo,
-        tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
-        tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
-    )
-    return found[:, 0], hit_sel[:, 0], hit_slot[:, 0], tgt_sel[:, 0]
+    )(bucket1, bucket2, i32(qdigest), i32(qkey_hi), i32(qkey_lo),
+      tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo)
 
 
 # =============================================================================
@@ -152,43 +166,58 @@ def upsert_probe(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo,
 # =============================================================================
 
 
-def _claim_kernel(slots, bkt_ref, rank_ref, kh_ref, kl_ref, sh_ref, sl_ref,
-                  vslot_ref, vocc_ref, vsh_ref, vsl_ref, vkh_ref, vkl_ref):
+def _claim_kernel(q_tile, bkt_ref, rank_ref, th, tl, tsh, tsl,
+                  vslot_ref, vocc_ref, vsh_ref, vsl_ref, vkh_ref, vkl_ref,
+                  hb, lb, shb, slb, sems):
     i = pl.program_id(0)
-    r = rank_ref[i]
-    hh = kh_ref[0, :]
-    ll = kl_ref[0, :]
-    occ = (~empty_lanes(hh, ll)).astype(jnp.uint32)
-    shi = sh_ref[0, :]
-    slo = sl_ref[0, :]
-    slot_iota = jax.lax.iota(jnp.int32, slots)
+    slots = th.shape[1]
+    planes = (th, tl, tsh, tsl)
 
-    # Pairwise lexicographic rank under the victim total order
-    # (occupied asc, score_hi asc, score_lo asc, key_hi asc, key_lo asc,
-    # slot asc).  rho[s] = #entries strictly weaker than slot s; since the
-    # 6-tuples are distinct (slot tiebreak), rho is a permutation and the
-    # rank-r victim is the unique slot with rho == r.
-    lt_m = jnp.zeros((slots, slots), jnp.bool_)
-    eq_m = jnp.ones((slots, slots), jnp.bool_)
-    for plane in (occ, shi, slo, hh, ll, slot_iota):
-        lt_m = lt_m | (eq_m & (plane[:, None] < plane[None, :]))
-        eq_m = eq_m & (plane[:, None] == plane[None, :])
-    rho = jnp.sum(lt_m.astype(jnp.int32), axis=0)
+    def copies(q, s):
+        b = bkt_ref[i * q_tile + q]
+        return [br.row_copy(p, buf.at[s], sems.at[s, j], b)
+                for j, (p, buf) in enumerate(zip(planes, (hb, lb, shb, slb)))]
 
-    sel = rho == r
-    pick32 = lambda a: jnp.max(jnp.where(sel, a, jnp.uint32(0)))
-    vslot_ref[0, 0] = jnp.argmax(sel).astype(jnp.int32)
-    vocc_ref[0, 0] = jnp.max(jnp.where(sel, occ, jnp.uint32(0))).astype(jnp.int32)
-    vsh_ref[0, 0] = pick32(shi)
-    vsl_ref[0, 0] = pick32(slo)
-    vkh_ref[0, 0] = pick32(hh)
-    vkl_ref[0, 0] = pick32(ll)
+    def body(q, s):
+        n = i * q_tile + q
+        hh, ll, shi, slo = hb[s], lb[s], shb[s], slb[s]
+        occ = (~empty_lanes(hh, ll)).astype(jnp.int32)
+        lane = br.lane_iota(slots)
+
+        # Pairwise lexicographic rank under the victim total order
+        # (occupied asc, score_hi asc, score_lo asc, key_hi asc, key_lo
+        # asc, slot asc).  rho[t] = #entries strictly weaker than slot t;
+        # since the 6-tuples are distinct (slot tiebreak), rho is a
+        # permutation and the rank-r victim is the unique slot with
+        # rho == r.  Rows compare as `br.ordered` int32 [S, S] blocks:
+        # row[t] along lanes against its transpose along sublanes.
+        lt_m = jnp.zeros((slots, slots), jnp.bool_)
+        eq_m = jnp.ones((slots, slots), jnp.bool_)
+        for plane in (occ, br.ordered(shi), br.ordered(slo),
+                      br.ordered(hh), br.ordered(ll), lane):
+            across = jnp.broadcast_to(plane, (slots, slots))
+            down = across.T
+            lt_m = lt_m | (eq_m & (down < across))
+            eq_m = eq_m & (down == across)
+        rho = jnp.sum(lt_m.astype(jnp.int32), axis=0, keepdims=True)
+
+        sel = rho == rank_ref[n]
+        _, vslot = br.first_lane(sel)
+        vslot_ref[n] = vslot
+        vocc_ref[n] = jnp.sum(jnp.where(sel, occ, 0))
+        vsh_ref[n] = br.pick(shi, sel)
+        vsl_ref[n] = br.pick(slo, sel)
+        vkh_ref[n] = br.pick(hh, sel)
+        vkl_ref[n] = br.pick(ll, sel)
+
+    br.query_loop(q_tile, 2, copies, body)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("q_tile", "interpret"))
 def claim_scan(tkey_hi, tkey_lo, tscore_hi, tscore_lo, buckets, rank, *,
-               interpret: bool = True):
-    """Per-query rank-r victim of each target bucket row.
+               q_tile: int, interpret: bool):
+    """Per-query rank-r victim of each target bucket row (N a multiple of
+    q_tile; the wrapper pads).
 
     buckets : int32 [N] target bucket per (canonically sorted) miss
     rank    : int32 [N] within-bucket canonical rank, pre-clipped to [0, S)
@@ -200,32 +229,23 @@ def claim_scan(tkey_hi, tkey_lo, tscore_hi, tscore_lo, buckets, rank, *,
     queries stay independent and the pass pipelines like the find path.
     """
     n = buckets.shape[0]
+    assert n % q_tile == 0, "wrapper must pad to a q_tile multiple"
     s = tkey_hi.shape[1]
-    row = lambda i, b: (b[i], 0)
+    out_spec, out_shape = br.smem_out(n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(memory_space=compat.SMEM),  # rank
-            pl.BlockSpec((1, s), row),    # key_hi row
-            pl.BlockSpec((1, s), row),    # key_lo row
-            pl.BlockSpec((1, s), row),    # score_hi row
-            pl.BlockSpec((1, s), row),    # score_lo row
-        ],
-        out_specs=[pl.BlockSpec((1, 1), lambda i, b: (i, 0))] * 6,
+        num_scalar_prefetch=2,
+        grid=(n // q_tile,),
+        in_specs=[br.hbm()] * 4,
+        out_specs=[out_spec] * 6,
+        scratch_shapes=([pltpu.VMEM((2, 1, s), jnp.uint32)] * 4
+                        + [pltpu.SemaphoreType.DMA((2, 4))]),
     )
     vslot, vocc, vsh, vsl, vkh, vkl = pl.pallas_call(
-        functools.partial(_claim_kernel, s),
+        functools.partial(_claim_kernel, q_tile),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-        ],
+        out_shape=[out_shape] * 6,
         interpret=interpret,
         name="hkv_claim_scan",
     )(buckets, rank, tkey_hi, tkey_lo, tscore_hi, tscore_lo)
-    return (vslot[:, 0], vocc[:, 0], vsh[:, 0], vsl[:, 0], vkh[:, 0], vkl[:, 0])
+    u32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return vslot, vocc, u32(vsh), u32(vsl), u32(vkh), u32(vkl)

@@ -14,17 +14,25 @@ distributed.pipeline for bandwidth-constrained inter-pod links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis `Auto`: shardings are propagated by
+    the compiler, as the model and table code expects (jax's default of
+    `Explicit` axes makes sharding part of every array's type)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dev_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (CPU tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

@@ -26,9 +26,27 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, NamedTuple, Optional
 
 
-def main():
+class ServeRun(NamedTuple):
+    """What an embedding-mode run leaves behind, for callers that check it."""
+
+    metrics: Any      # EngineMetrics
+    engine: Any       # OnlineEmbeddingEngine
+    publisher: Any    # TablePublisher (holds the served table)
+    trainer: Any      # OnlineTrainer
+    served: list      # the uint64 key batch of each request submitted
+    trained: list     # the uint64 key batch of each training step
+
+
+def main(argv=None, *, table=None):
+    """Parse `argv` (default: the command line) and run.  Embedding mode
+    serves `table` (a `TieredHKVTable`, e.g. one restored from a
+    checkpoint) where given, else an empty one of the requested size.
+    The run takes `table` over: its waves and training steps update the
+    table they own in place (donation), so the caller's handle may be
+    consumed."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("embedding", "lm"), default="embedding")
     ap.add_argument("--smoke", action="store_true")
@@ -80,13 +98,16 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--decode-steps", type=int, default=16)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     if args.mode == "lm":
         return _lm_main(args)
-    return _embedding_main(args)
+    return _embedding_main(args, table)
 
 
-def _embedding_main(args):
+def _embedding_main(args, table: Optional[Any] = None) -> ServeRun:
     import jax.numpy as jnp
     import numpy as np
 
@@ -107,11 +128,12 @@ def _embedding_main(args):
 
         tracer = Tracer()
 
-    table = TieredHKVTable.create(
-        hot_capacity=args.hot_capacity, cold_capacity=args.cold_capacity,
-        dim=args.dim)
+    if table is None:
+        table = TieredHKVTable.create(
+            hot_capacity=args.hot_capacity, cold_capacity=args.cold_capacity,
+            dim=args.dim)
     pub = TablePublisher(table, tracer=tracer)
-    trainer = OnlineTrainer(publisher=pub, publish_every=1)
+    trainer = OnlineTrainer(publisher=pub, publish_every=1, donate=True)
     sched = None
     if args.maintain:
         from repro.maintenance import MaintenancePolicy, MaintenanceScheduler
@@ -122,15 +144,15 @@ def _embedding_main(args):
     eng = OnlineEmbeddingEngine(
         pub, wave_size=args.wave_size, miss_policy=args.miss_policy,
         promote=not args.no_promote, scheduler=sched,
-        admission=args.admission,
+        admission=args.admission, donate=True,
         host_budget_s=(args.host_budget_ms / 1e3
                        if args.host_budget_ms is not None else None),
         tracer=tracer)
 
     serve_rng = np.random.default_rng(args.seed)
     train_rng = np.random.default_rng(args.seed + 1)
-    key_space = 2 * args.cold_capacity
-    grads = jnp.ones((args.wave_size, args.dim), jnp.float32)
+    key_space = 2 * table.cold.capacity
+    grads = jnp.ones((args.wave_size, table.dim), jnp.float32)
 
     # per-tick arrivals: 'steady' keeps the legacy one-wave-per-tick
     # load; 'burst'/'diurnal' modulate the offered key count, so the
@@ -140,16 +162,17 @@ def _embedding_main(args):
                           **({"base_load": 1.0}
                              if args.arrival == "steady" else {}))
     due = 0.0
+    served, trained = [], []
     for i, sz in enumerate(sizes):
-        eng.submit(EmbeddingRequest(
-            rid=i,
-            keys=zipf_keys(serve_rng, int(sz), args.zipf_alpha, key_space)))
+        served.append(zipf_keys(serve_rng, int(sz), args.zipf_alpha,
+                                key_space))
+        eng.submit(EmbeddingRequest(rid=i, keys=served[-1]))
         r = eng.step()
         due += args.update_read_ratio
         while due >= 1.0:
-            trainer.train_step(
-                zipf_keys(train_rng, args.wave_size, args.zipf_alpha,
-                          key_space), grads)
+            trained.append(zipf_keys(train_rng, args.wave_size,
+                                     args.zipf_alpha, key_space))
+            trainer.train_step(trained[-1], grads)
             due -= 1.0
         if r is not None and (i + 1) % max(args.waves // 4, 1) == 0:
             print(f"[serve] wave {i+1:4d}: hit={r.hit_rate*100:5.1f}% "
@@ -198,7 +221,8 @@ def _embedding_main(args):
             tracer.save(args.trace_out)
             print(f"[serve] trace ({len(tracer)} events) -> "
                   f"{args.trace_out}")
-    return m
+    return ServeRun(metrics=m, engine=eng, publisher=pub, trainer=trainer,
+                    served=served, trained=trained)
 
 
 def _lm_main(args):

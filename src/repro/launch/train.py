@@ -27,8 +27,10 @@ def main():
     ap.add_argument("--backend", choices=("dense", "hkv"), default="dense")
     ap.add_argument("--hkv-hot-capacity", type=int, default=None,
                     help="run the HKV table as a two-tier hierarchy: this "
-                    "many HBM hot slots in front of the (host-capacity) "
-                    "cold table — DESIGN.md §2.5; requires --backend hkv")
+                    "many hot slots in front of the cold table, both in "
+                    "device memory (a sharded table cannot hold a host-"
+                    "resident plane) — DESIGN.md §2.5; requires --backend "
+                    "hkv")
     ap.add_argument("--optimizer", choices=("adamw", "adamw8bit", "adafactor", "sgdm"),
                     default="adamw")
     ap.add_argument("--ckpt-dir", default="runs/ckpt")
@@ -47,6 +49,10 @@ def main():
 
     import jax
     import jax.numpy as jnp
+
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     from repro.configs import get_arch
     from repro.data import DataCursor, HostPrefetcher, TokenStream
@@ -82,9 +88,10 @@ def main():
                 capacity=max(256, (2 * lm.vocab // 128) * 128),
                 dim=lm.d_model,
                 optimizer=SparseOptimizer("rowwise_adagrad", lr=0.05),
-                # two-tier hierarchy per shard: hot set in HBM, tail in
-                # the host-capacity cold tier (DESIGN.md §2.5)
+                # two-tier hierarchy per shard (DESIGN.md §2.5); the cold
+                # plane stays in device memory under shard_map
                 hot_capacity=args.hkv_hot_capacity,
+                cold_value_tier="hbm",
             ),
         )
         builder = StepBuilder(model, opt)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 
 from repro.core.api import table_signature
 from repro.core.predicates import SweepPredicate
+from repro.core.table import jit_returning_table
 from repro.core.tiered import TieredHKVTable
 from repro.maintenance.rebalance import rebalance as _rebalance
 from repro.obs.trace import as_tracer
@@ -171,9 +172,9 @@ class MaintenanceScheduler:
                     high_watermark=pol.high_watermark,
                     budget=pol.sweep_budget)
                 t, demoted, dropped = rb.table, rb.moved, rb.dropped
-            return t, expired, demoted, dropped
+            return t, (expired, demoted, dropped)
 
-        return jax.jit(step)
+        return jit_returning_table(step)
 
     # -- driving ---------------------------------------------------------------
 
@@ -190,7 +191,7 @@ class MaintenanceScheduler:
             self._step_sig = sig
         t0 = time.perf_counter()
         with self.tracer.span("maintenance.run", version=version):
-            t2, expired, demoted, dropped = self._step_fn(table)
+            t2, (expired, demoted, dropped) = self._step_fn(table)
             expired, demoted, dropped = jax.block_until_ready(
                 (expired, demoted, dropped))
         elapsed = time.perf_counter() - t0

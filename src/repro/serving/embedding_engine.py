@@ -96,6 +96,7 @@ import numpy as np
 
 from repro.core import u64
 from repro.core.api import table_signature
+from repro.core.table import jit_returning_table
 from repro.core.tiered import TieredHKVTable
 from repro.core.u64 import U64
 from repro.obs.trace import as_tracer
@@ -203,7 +204,8 @@ class EngineMetrics(NamedTuple):
 class _Inflight(NamedTuple):
     """A dispatched, not-yet-retired wave (continuous mode holds one)."""
 
-    out: tuple          # (succ, vals, found, hot, dem) — async device values
+    out: tuple          # (vals, found, hot, dem) — async device values; the
+                        # successor table lives in the source, not here
     segments: list      # (request, key offset, lane0, take)
     used: int
     lanes: np.ndarray
@@ -242,6 +244,13 @@ class OnlineEmbeddingEngine:
     offered to the scheduler, which defers its step when its estimated
     cost exceeds the remaining slack.  `None` (default) leaves the
     scheduler cadence-only (the pre-continuous contract).
+
+    `donate=True` lets a wave consume its input table when the input is
+    the successor this engine itself offered and the source still serves
+    it: such a handle has no other holder, so the wave updates it in
+    place instead of writing a whole new table (on a tiered table, a
+    whole new host-resident value plane).  A snapshot taken from the
+    source is then valid only until the next wave.
     """
 
     def __init__(self, table: Any, *, wave_size: int,
@@ -251,6 +260,7 @@ class OnlineEmbeddingEngine:
                  scheduler: Optional[Any] = None,
                  admission: str = "wave",
                  host_budget_s: Optional[float] = None,
+                 donate: bool = False,
                  tracer: Optional[Any] = None):
         if miss_policy not in MISS_POLICIES:
             raise ValueError(
@@ -265,6 +275,8 @@ class OnlineEmbeddingEngine:
         self.promote = promote
         self.admission = admission
         self.host_budget_s = host_budget_s
+        self.donate = donate
+        self._owned_version = None        # the version our accepted offer made
         self._default_row = default_row
         # span tracing (repro.obs.trace): engine.submit / wave.splice /
         # wave.dispatch / wave.reap / request lifetimes.  `as_tracer`
@@ -286,8 +298,8 @@ class OnlineEmbeddingEngine:
         self._stage_used = 0
         self._stage_age = 0               # steps a partial stage has waited
         self._flights: deque = deque()    # dispatched, not yet retired
-        self._wave_fn = None              # jitted; keyed on table signature
-        self._wave_sig = None
+        self._wave_fn = None              # (keeping, donating) jitted pair,
+        self._wave_sig = None             # rebuilt on a new table signature
         self._mutates = False             # resolved with the wave fn
         self.completed: list = []
         self.reports: list[WaveReport] = []
@@ -390,7 +402,7 @@ class OnlineEmbeddingEngine:
                 # reactive demotion count: what THIS wave's admissions
                 # pushed hot->cold in-line (tiered handles report it)
                 dem = getattr(r, "demoted", zero)
-                return r.table, vals, r.found, r.found, dem
+                return r.table, (vals, r.found, r.found, dem)
             # readonly: READER role — default-row fallback on miss.  Wave
             # lookups inherit the handle's backend, so kernel-backed
             # tables serve each wave with the fused find pass
@@ -402,14 +414,17 @@ class OnlineEmbeddingEngine:
                 succ = table
             vals = jnp.where(r.found[:, None], r.values[:, : table.dim], init)
             dem = getattr(r, "demoted", zero) if promote else zero
-            return (succ, vals, r.found, getattr(r, "hot_hit", r.found), dem)
+            return succ, (vals, r.found, getattr(r, "hot_hit", r.found), dem)
 
         if is_sharded:
-            return wave   # shard_map ops jit internally; outer jit is per-mesh
-        return jax.jit(wave)
+            # shard_map ops jit internally; outer jit is per-mesh
+            return wave, wave
+        return (jit_returning_table(wave),
+                jit_returning_table(wave, donate_argnums=(0,)))
 
-    def _wave_fn_for(self, table):
-        """The compiled wave closure for this table, rebuilt when the
+    def _wave_fn_for(self, table, donate: bool):
+        """The compiled wave closure for this table (the one that consumes
+        its input table when `donate`), rebuilt when the
         published handle's static signature changed (type / backend /
         dims / score policy) — a trainer may retier or reshape the table
         mid-stream and the closure's baked-in flags must follow."""
@@ -417,7 +432,7 @@ class OnlineEmbeddingEngine:
         if self._wave_fn is None or sig != self._wave_sig:
             self._wave_fn = self._build_wave_fn(table)
             self._wave_sig = sig
-        return self._wave_fn
+        return self._wave_fn[donate]
 
     def _dispatch(self, lanes, segments, used) -> Optional[_Inflight]:
         """Launch one wave asynchronously (no block).  Zero-live waves
@@ -436,15 +451,18 @@ class OnlineEmbeddingEngine:
                 self.tracer.complete_abs("request", req.t_submit, now,
                                          rid=req.rid, keys=len(req.keys))
             return None
-        fn = self._wave_fn_for(table)
+        donate = self.donate and version == self._owned_version
+        fn = self._wave_fn_for(table, donate)
         k = u64.from_uint64(lanes)
         t0 = time.perf_counter()
         with self.tracer.span("wave.dispatch", used=used, version=version):
-            out = fn(table, k.hi, k.lo)
+            succ, out = fn(table, k.hi, k.lo)
             if self._mutates:     # admission / promotion built a successor;
                 # offer the (possibly still computing) handle NOW so the next
-                # dispatch chains on it — XLA orders launches by data deps
-                self.source.offer(version, out[0])
+                # dispatch chains on it — XLA orders launches by data deps.
+                # Accepted, it is version + 1 and only the source holds it
+                accepted = self.source.offer(version, succ)
+                self._owned_version = version + 1 if accepted else None
         for req, _off, _lane0, _take in segments:
             if req.t_admit is None:
                 req.t_admit = t0
@@ -455,7 +473,7 @@ class OnlineEmbeddingEngine:
         """Block on a dispatched wave, unpack results into its requests."""
         with self.tracer.span("wave.reap", used=flight.used,
                               version=flight.version):
-            _succ, vals, found, hot, dem = flight.out
+            vals, found, hot, dem = flight.out
             vals, found, hot, dem = jax.block_until_ready(
                 (vals, found, hot, dem))
             dt = time.perf_counter() - flight.t_dispatch
@@ -497,10 +515,7 @@ class OnlineEmbeddingEngine:
         slack = None
         if self.host_budget_s is not None:
             slack = max(0.0, self.host_budget_s - staging_s)
-        try:
-            self.scheduler.on_wave(self.source, slack_s=slack)
-        except TypeError:   # older scheduler without the slack seam
-            self.scheduler.on_wave(self.source)
+        self.scheduler.on_wave(self.source, slack_s=slack)
 
     def step(self) -> Optional[WaveReport]:
         """Serve one wave; returns its report.
@@ -643,6 +658,6 @@ def _flight_ready(flight: _Inflight) -> bool:
     `is_ready`: report not-ready and let the blocking paths retire."""
     try:
         return all(x.is_ready()
-                   for x in jax.tree_util.tree_leaves(flight.out[1:]))
+                   for x in jax.tree_util.tree_leaves(flight.out))
     except AttributeError:  # pragma: no cover - backend without is_ready
         return False

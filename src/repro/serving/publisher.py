@@ -48,7 +48,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import u64
+from repro.core.api import normalize_keys
+from repro.core.table import jit_returning_table
 from repro.core.u64 import U64
+from repro.obs.telemetry import TelemetrySink
 from repro.obs.trace import as_tracer
 
 
@@ -267,6 +270,12 @@ class OnlineTrainer:
     admission op's device counters across steps — the trainer-side half
     of the op-telemetry story (the update half runs through a session,
     which is out of the telemetry seam's scope).
+
+    `donate=True` lets a step consume the trainer's table once the
+    publisher no longer serves it (an engine offer or a later publish
+    replaced it): the step then updates it in place instead of writing a
+    whole new table.  The table the trainer starts from is consumed too,
+    so a caller hands it over.
     """
 
     publisher: TablePublisher
@@ -275,20 +284,28 @@ class OnlineTrainer:
     update_fn: Optional[Callable] = None
     steps: int = 0
     telemetry: Optional[Any] = None
+    donate: bool = False
 
     def __post_init__(self):
         self._table = self.publisher.table
+        # one compiled program per step: admission + update; the second
+        # consumes the table it is given
+        self._steps = (
+            jit_returning_table(self._step_body, static_argnums=3),
+            jit_returning_table(self._step_body, static_argnums=3,
+                                donate_argnums=(0,)))
 
     @property
     def table(self) -> Any:
         return self._table
 
-    def train_step(self, keys: Any, grads: jax.Array) -> Any:
-        t = self._table
+    def _step_body(self, t: Any, keys: U64, grads: jax.Array,
+                   with_telemetry: bool):
+        sink = TelemetrySink() if with_telemetry else None
         dim = grads.shape[1]
         init = jnp.zeros((grads.shape[0], dim), jnp.float32)
-        if self.telemetry is not None:
-            res = t.find_or_insert(keys, init, telemetry=self.telemetry)
+        if sink is not None:
+            res = t.find_or_insert(keys, init, telemetry=sink)
         else:
             res = t.find_or_insert(keys, init)
         t = res.table
@@ -296,7 +313,14 @@ class OnlineTrainer:
             lambda rows, g: rows.at[:, :dim].add(-self.lr * g))
         s = t.session()
         s.update_rows(keys, lambda rows: fn(rows, grads))
-        t = s.commit()
+        return s.commit(), ({} if sink is None else sink.by_op)
+
+    def train_step(self, keys: Any, grads: jax.Array) -> Any:
+        donate = self.donate and self.publisher.table is not self._table
+        t, by_op = self._steps[donate](self._table, normalize_keys(keys),
+                                       grads, self.telemetry is not None)
+        for op, tel in by_op.items():
+            self.telemetry.record(op, tel)
         self._table = t
         self.steps += 1
         if self.steps % self.publish_every == 0:

@@ -16,7 +16,7 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
+from repro.launch.mesh import make_mesh
 """
 
 
@@ -41,7 +41,7 @@ def test_sharded_hkv_embedding_roundtrip_and_grads():
     from repro.embedding.dynamic import HKVEmbedding
     from repro.embedding.sparse_opt import SparseOptimizer
     from repro.distributed.table_sharding import ShardedHKVEmbedding
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     emb = HKVEmbedding(capacity=8*128*8, dim=8,
                        optimizer=SparseOptimizer("rowwise_adagrad", lr=0.5))
     semb = ShardedHKVEmbedding(emb=emb, axis_names=("data", "model"))
@@ -85,7 +85,7 @@ def test_sharded_lookup_matches_unsharded_init_rows():
     out = _run("""
     from repro.embedding.dynamic import HKVEmbedding
     from repro.distributed.table_sharding import ShardedHKVEmbedding
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     emb = HKVEmbedding(capacity=8*128*8, dim=4)
     semb = ShardedHKVEmbedding(emb=emb, axis_names=("data", "model"))
     state = semb.create_sharded(mesh)
@@ -105,13 +105,13 @@ def test_sharded_lookup_matches_unsharded_init_rows():
 def test_compressed_psum_close_to_exact():
     out = _run("""
     from repro.distributed.compression import compressed_psum
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 1000)), jnp.float32)
 
     def body(x):
         return compressed_psum(x, "d")
 
-    y = jax.jit(shard_map(body, mesh=mesh, in_specs=P("d"),
+    y = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("d"),
                               out_specs=P("d"), check_vma=False))(x)
     exact = jnp.broadcast_to(x.sum(0, keepdims=True), x.shape)
     err = float(jnp.max(jnp.abs(y - exact)) / (jnp.max(jnp.abs(exact)) + 1e-9))
@@ -123,7 +123,7 @@ def test_compressed_psum_close_to_exact():
 def test_error_feedback_accumulates():
     out = _run("""
     from repro.distributed.compression import ef_compress_grads, init_error_state
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(8, 512)), jnp.float32)}
 
     def body(g):
@@ -132,7 +132,7 @@ def test_error_feedback_accumulates():
         # second step: error feedback should be non-zero
         return synced["w"], e2["w"]
 
-    s, e = jax.jit(shard_map(body, mesh=mesh, in_specs=({"w": P("d")},),
+    s, e = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=({"w": P("d")},),
                                  out_specs=(P("d"), P("d")), check_vma=False))(g)
     exact = jnp.broadcast_to(g["w"].mean(0, keepdims=True), g["w"].shape)
     rel = float(jnp.max(jnp.abs(s - exact)) / (jnp.max(jnp.abs(exact)) + 1e-9))
@@ -145,7 +145,7 @@ def test_error_feedback_accumulates():
 def test_pipeline_matches_sequential():
     out = _run("""
     from repro.distributed.pipeline import pipeline_apply
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     rng = np.random.default_rng(2)
     ws = jnp.asarray(rng.normal(size=(4, 16, 16)) / 4.0, jnp.float32)
     xs = jnp.asarray(rng.normal(size=(6, 8, 16)), jnp.float32)  # 6 microbatches

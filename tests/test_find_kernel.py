@@ -63,7 +63,7 @@ def _filled_table(rng, cfg, n_fill):
 def _ref_find(state, cfg, keys):
     """The jnp oracle assembled exactly as core.ops.find/find_rows do."""
     loc = find_mod.locate(state, cfg, keys)
-    rows = find_mod.gather_values(state, loc, None, cfg.value_tier)
+    rows = find_mod.gather_values(state, loc, None, cfg)
     shi = jnp.where(loc.found, state.score_hi[loc.bucket, loc.slot], 0)
     slo = jnp.where(loc.found, state.score_lo[loc.bucket, loc.slot], 0)
     return loc, rows, shi, slo
@@ -87,6 +87,14 @@ def _assert_fused_equal(r, state, cfg, keys, ctx=""):
                                   err_msg=f"{ctx}: score_lo")
 
 
+def _raw_find(variant, *args, width, use_digest=True):
+    """The raw fused-find kernel over one tile-divisible batch."""
+    n = args[6].shape[0]
+    return _fs.find_scan(*args, width=width, variant=variant,
+                         q_tile=8 if n % 8 == 0 else n,
+                         use_digest=use_digest, interpret=True)
+
+
 # =============================================================================
 # Raw kernel vs the pure-jnp oracle (ref.find_scan_ref)
 # =============================================================================
@@ -107,11 +115,8 @@ def test_find_scan_matches_ref(variant, dual):
     args = (state.digests, state.key_hi, state.key_lo, state.score_hi,
             state.score_lo, state.values, probe.bucket1, b2,
             probe.digest.astype(jnp.uint32), k.hi, k.lo)
-    want = ref.find_scan_ref(*args)
-    if variant == "tlp":
-        got = _fs.find_scan_tlp(*args, interpret=True)
-    else:
-        got = _fs.find_scan_pipeline(*args, q_tile=128, interpret=True)
+    want = ref.find_scan_ref(*args, cfg.total_value_dim)
+    got = _raw_find(variant, *args, width=cfg.total_value_dim)
     for g, w, name in zip(got, want,
                           ("found", "sel", "slot", "shi", "slo", "vals")):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
@@ -130,12 +135,9 @@ def test_find_scan_use_digest_false_matches_ref(variant):
     args = (state.digests, state.key_hi, state.key_lo, state.score_hi,
             state.score_lo, state.values, probe.bucket1, probe.bucket1,
             probe.digest.astype(jnp.uint32), k.hi, k.lo)
-    want = ref.find_scan_ref(*args, use_digest=False)
-    if variant == "tlp":
-        got = _fs.find_scan_tlp(*args, use_digest=False, interpret=True)
-    else:
-        got = _fs.find_scan_pipeline(*args, q_tile=128, use_digest=False,
-                                     interpret=True)
+    want = ref.find_scan_ref(*args, cfg.total_value_dim, use_digest=False)
+    got = _raw_find(variant, *args, width=cfg.total_value_dim,
+                    use_digest=False)
     for g, w, name in zip(got, want,
                           ("found", "sel", "slot", "shi", "slo", "vals")):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
@@ -302,9 +304,10 @@ def test_fused_find_under_jit_and_vmap():
     half = len(q) // 2
     stacked = tuple(jnp.stack([a, b]) for a, b in
                     zip(args(slice(0, half)), args(slice(half, 2 * half))))
-    fn = lambda b1, b2, qd, qh, ql: _fs.find_scan_tlp(
-        state.digests, state.key_hi, state.key_lo, state.score_hi,
-        state.score_lo, state.values, b1, b2, qd, qh, ql, interpret=True)
+    fn = lambda b1, b2, qd, qh, ql: _raw_find(
+        "tlp", state.digests, state.key_hi, state.key_lo, state.score_hi,
+        state.score_lo, state.values, b1, b2, qd, qh, ql,
+        width=cfg.total_value_dim)
     vout = jax.vmap(fn)(*stacked)
     solo0 = fn(*args(slice(0, half)))
     solo1 = fn(*args(slice(half, 2 * half)))
@@ -335,10 +338,8 @@ class TestLaunchBudget:
 
             monkeypatch.setattr(mod, name, counting)
 
-        wrap(_fs, "find_scan_tlp", "find_scan")
-        wrap(_fs, "find_scan_pipeline", "find_scan")
-        wrap(_ds, "digest_scan_tlp", "digest_scan")
-        wrap(_ds, "digest_scan_pipeline", "digest_scan")
+        wrap(_fs, "find_scan", "find_scan")
+        wrap(_ds, "digest_scan", "digest_scan")
         wrap(_ga, "gather_rows", "gather")
         return counts
 
@@ -413,13 +414,13 @@ def test_find_many_is_one_launch(monkeypatch):
         states.append(state)
         keysets.append(u64.from_uint64(resident[:32]))
     counts = {"find_scan": 0}
-    orig = _fs.find_scan_pipeline
+    orig = _fs.find_scan
 
     def counting(*a, **kw):
         counts["find_scan"] += 1
         return orig(*a, **kw)
 
-    monkeypatch.setattr(_fs, "find_scan_pipeline", counting)
+    monkeypatch.setattr(_fs, "find_scan", counting)
     kops.find_many_kernel(states, cfg, keysets, interpret=True)
     assert counts["find_scan"] == 1  # 4 tables, ONE launch
 

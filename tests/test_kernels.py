@@ -43,12 +43,9 @@ def test_digest_scan_matches_ref(capacity, queries, fill, variant):
     from repro.core import find as find_mod
 
     probe = find_mod.probe_keys(cfg, qk)
-    fn = (
-        digest_scan.digest_scan_tlp
-        if variant == "tlp"
-        else lambda *a, **k: digest_scan.digest_scan_pipeline(*a, q_tile=32, **k)
-    )
-    npad = -(-queries // 32) * 32 if variant == "pipeline" else queries
+    fn = lambda *a, **k: digest_scan.digest_scan(*a, variant=variant,
+                                                 q_tile=32, **k)
+    npad = -(-queries // 32) * 32
     pad = lambda x, f=0: jnp.concatenate(
         [x, jnp.full((npad - queries,), f, x.dtype)]
     ) if npad != queries else x
@@ -83,28 +80,44 @@ def test_locate_kernel_matches_core_locate():
         np.testing.assert_array_equal(np.asarray(lk.row)[m], np.asarray(lr.row)[m])
 
 
+def _plane(logical):
+    """Pack logical rows [N, W] into a packed value plane."""
+    n, w = logical.shape
+    zero = jnp.zeros(table.value_plane_shape(n, w), logical.dtype)
+    return table.tier_scatter("hbm", zero, jnp.arange(n, dtype=jnp.int32),
+                              logical)
+
+
 @pytest.mark.parametrize("dim", [4, 32, 128, 200])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gather_rows_matches_ref(dim, dtype):
     rng = np.random.default_rng(dim)
-    values = jnp.asarray(rng.normal(size=(512, dim)), dtype)
+    logical = jnp.asarray(rng.normal(size=(512, dim)), dtype)
+    values = _plane(logical)
     rows = jnp.asarray(rng.integers(0, 512, size=100), jnp.int32)
     mask = jnp.asarray(rng.integers(0, 2, size=100), jnp.int32)
-    got = gather.gather_rows(values, rows, mask, interpret=True)
-    want = ref.gather_rows_ref(values, rows, mask)
+    got = gather.gather_rows(values, rows, mask, width=dim, q_tile=4,
+                             interpret=True)
+    want = ref.gather_rows_ref(values, rows, mask, dim)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.where(np.asarray(mask)[:, None] != 0,
+                 np.asarray(logical)[np.asarray(rows)], 0))
 
 
 @pytest.mark.parametrize("dim", [8, 64, 256])
 @pytest.mark.parametrize("add", [False, True])
 def test_scatter_rows_matches_ref(dim, add):
     rng = np.random.default_rng(dim + add)
-    values = jnp.asarray(rng.normal(size=(256, dim)), jnp.float32)
+    values = _plane(jnp.asarray(rng.normal(size=(256, dim)), jnp.float32))
     rows = jnp.asarray(rng.permutation(256)[:64], jnp.int32)  # unique rows
     updates = jnp.asarray(rng.normal(size=(64, dim)), jnp.float32)
     mask = jnp.asarray(rng.integers(0, 2, size=64), jnp.int32)
-    got = scatter.scatter_rows(values, rows, updates, mask, add=add, interpret=True)
-    want = ref.scatter_rows_ref(values, rows, updates, mask, add=add)
+    got = scatter.scatter_rows(values, rows, updates, mask, add=add,
+                               q_tile=8, interpret=True)
+    want = ref.scatter_rows_ref(values, rows, updates, mask, add=add,
+                                num_rows=256)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 

@@ -30,6 +30,7 @@ from repro.core import HKVTable, KVTable, TieredHKVTable, U64
 from repro.core import ops as core_ops
 from repro.baselines import DictKVTable
 from repro.embedding.sparse_opt import SparseOptimizer
+from repro.launch.mesh import make_mesh
 
 BATCH = 64     # one jit cache entry per op across every test
 DIM = 4
@@ -82,7 +83,7 @@ def _mesh():
     if _MESH is None:
         import jax
 
-        _MESH = jax.make_mesh((1,), ("d",))
+        _MESH = make_mesh((1,), ("d",))
     return _MESH
 
 

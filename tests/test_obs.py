@@ -173,10 +173,8 @@ class TestLaunchNeutrality:
 
             monkeypatch.setattr(mod, name, counting)
 
-        wrap(_fs, "find_scan_tlp", "find_scan")
-        wrap(_fs, "find_scan_pipeline", "find_scan")
-        wrap(_ds, "digest_scan_tlp", "digest_scan")
-        wrap(_ds, "digest_scan_pipeline", "digest_scan")
+        wrap(_fs, "find_scan", "find_scan")
+        wrap(_ds, "digest_scan", "digest_scan")
         wrap(_ga, "gather_rows", "gather")
         return counts
 

@@ -4,6 +4,7 @@ metrics sanity against the oracle, and the delta publication path."""
 import threading
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -632,3 +633,53 @@ class TestContinuousAdmission:
         # zero-slack budget defers every interval
         assert sched.totals.runs == 1
         assert sched.totals.deferred == 4
+
+
+class TestDonation:
+    """`donate=True` engines and trainers update the tables they own in
+    place; what they serve and train is unchanged."""
+
+    @staticmethod
+    def _run(donate, cold_tier):
+        t = TieredHKVTable.create(hot_capacity=128, cold_capacity=4 * 128,
+                                  dim=DIM, cold_value_tier=cold_tier)
+        first = t.hot.state.key_hi
+        pub = TablePublisher(t)
+        del t
+        tr = OnlineTrainer(publisher=pub, publish_every=1, lr=0.5,
+                           donate=donate)
+        eng = OnlineEmbeddingEngine(pub, wave_size=32, miss_policy="admit",
+                                    admission="continuous", donate=donate)
+        rng = np.random.default_rng(3)
+        reqs, offered = [], []
+        for i in range(10):
+            keys = rng.integers(0, 600, size=32).astype(np.uint64)
+            reqs.append(EmbeddingRequest(rid=i, keys=keys))
+            eng.submit(reqs[-1])
+            eng.step()
+            offered.append(pub.table.hot.state.key_hi)
+            if i % 3 == 2:
+                tr.train_step(keys, jnp.ones((32, DIM)))
+        eng.run_until_drained()
+        return reqs, pub.table, tr.table, first, offered
+
+    @pytest.mark.parametrize("cold_tier", ["hbm", "hmem"])
+    def test_donating_serve_matches_copying_serve(self, cold_tier):
+        want = self._run(False, cold_tier)
+        got = self._run(True, cold_tier)
+        for a, b in zip(want[0], got[0]):
+            assert a.done and b.done
+            np.testing.assert_array_equal(a.found, b.found)
+            np.testing.assert_array_equal(a.values, b.values)
+        for a, b in zip(want[1:3], got[1:3]):
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        first, offered = got[3], got[4]
+        assert not want[3].is_deleted()
+        assert not any(x.is_deleted() for x in want[4])
+        # the engine consumed successors it had offered itself
+        assert any(x.is_deleted() for x in offered)
+        # the trainer consumed the table it started from, except where
+        # that table holds a pinned_host plane on the CPU (no placement
+        # is declared there, so it is never donated)
+        assert first.is_deleted() == (cold_tier == "hbm")

@@ -21,21 +21,44 @@ from repro.core import HKVTable, U64, u64
 from repro.core import table as table_mod
 
 
+def _plane(rows: np.ndarray) -> jax.Array:
+    """Pack logical rows [N, W] into a packed value plane."""
+    n, w = rows.shape
+    zero = jnp.zeros(table_mod.value_plane_shape(n, w), jnp.float32)
+    return table_mod.tier_scatter("hbm", zero, jnp.arange(n, dtype=jnp.int32),
+                                  jnp.asarray(rows, jnp.float32))
+
+
+def _on(tier: str, plane: jax.Array) -> jax.Array:
+    """The plane where `tier` keeps it: host memory for 'hmem'."""
+    return table_mod.place_on_host(plane) if tier == "hmem" else plane
+
+
+def _rows(tier: str, plane: jax.Array, n: int, w: int) -> np.ndarray:
+    """Every logical row of a plane, read back through `tier` (an 'hbm'
+    read of a host-tier plane first moves the plane to the device)."""
+    if tier == "hbm":
+        plane = jax.device_put(plane, jax.memory.Space.Device)
+    return np.asarray(table_mod.tier_gather(
+        tier, plane, jnp.arange(n, dtype=jnp.int32), w))
+
+
 class TestGatherScatterRoundTrip:
     def test_jit_gather_matches_plain_indexing(self):
         rng = np.random.default_rng(0)
-        values = jnp.asarray(rng.normal(size=(256, 8)), jnp.float32)
+        logical = rng.normal(size=(256, 8)).astype(np.float32)
+        values = _plane(logical)
         rows = jnp.asarray(rng.integers(0, 256, size=64), jnp.int32)
         for tier in ("hbm", "hmem"):
             got = jax.jit(
-                lambda v, r: table_mod.tier_gather(tier, v, r)
-            )(values, rows)
+                lambda v, r: table_mod.tier_gather(tier, v, r, 8)
+            )(_on(tier, values), rows)
             np.testing.assert_array_equal(np.asarray(got),
-                                          np.asarray(values)[np.asarray(rows)])
+                                          logical[np.asarray(rows)])
 
     def test_jit_scatter_then_gather_round_trips(self):
         rng = np.random.default_rng(1)
-        values = jnp.zeros((256, 4), jnp.float32)
+        values = _plane(np.zeros((256, 4), np.float32))
         rows = jnp.asarray(rng.permutation(256)[:64], jnp.int32)  # unique
         updates = jnp.asarray(rng.normal(size=(64, 4)), jnp.float32)
 
@@ -43,40 +66,94 @@ class TestGatherScatterRoundTrip:
             @jax.jit
             def rt(v, r, up):
                 v2 = table_mod.tier_scatter(tier, v, r, up)
-                return table_mod.tier_gather(tier, v2, r), v2
+                return table_mod.tier_gather(tier, v2, r, 4), v2
 
-            back, v2 = rt(values, rows, updates)
+            back, v2 = rt(_on(tier, values), rows, updates)
             np.testing.assert_array_equal(np.asarray(back), np.asarray(updates))
             # untouched rows stay zero
             mask = np.ones(256, bool)
             mask[np.asarray(rows)] = False
-            assert not np.asarray(v2)[mask].any()
+            assert not _rows("hbm", v2, 256, 4)[mask].any()
 
     def test_jit_scatter_add_accumulates(self):
-        values = jnp.ones((64, 2), jnp.float32)
+        values = _plane(np.ones((64, 2), np.float32))
         rows = jnp.asarray([3, 3, 7], jnp.int32)  # duplicate rows accumulate
         updates = jnp.full((3, 2), 2.0, jnp.float32)
         for tier in ("hbm", "hmem"):
             v2 = jax.jit(
                 lambda v, r, up: table_mod.tier_scatter(tier, v, r, up, add=True)
-            )(values, rows, updates)
-            got = np.asarray(v2)
+            )(_on(tier, values), rows, updates)
+            got = _rows("hbm", v2, 64, 2)
             np.testing.assert_allclose(got[3], 5.0)   # 1 + 2 + 2
             np.testing.assert_allclose(got[7], 3.0)
             np.testing.assert_allclose(got[1], 1.0)
 
     def test_oob_drop_mode_under_jit(self):
-        """mode='drop' is the masked-lane contract every op relies on."""
-        values = jnp.zeros((16, 2), jnp.float32)
-        rows = jnp.asarray([2, 16], jnp.int32)    # 16 = one past the end
+        """Rows past the last slot are dropped: the masked-lane contract
+        every op relies on."""
+        values = _plane(np.zeros((128, 2), np.float32))
+        rows = jnp.asarray([2, 128], jnp.int32)   # 128 = one past the end
         updates = jnp.ones((2, 2), jnp.float32)
         for tier in ("hbm", "hmem"):
             v2 = jax.jit(
                 lambda v, r, up: table_mod.tier_scatter(tier, v, r, up)
-            )(values, rows, updates)
-            got = np.asarray(v2)
+            )(_on(tier, values), rows, updates)
+            got = _rows("hbm", v2, 128, 2)
             np.testing.assert_allclose(got[2], 1.0)
             assert got.sum() == 2.0               # OOB lane dropped
+            assert float(np.asarray(v2).sum()) == 2.0  # nothing else in the plane
+
+    def test_mask_rows_clears_only_dropped_rows(self):
+        rng = np.random.default_rng(5)
+        logical = rng.normal(size=(256, 5)).astype(np.float32)
+        keep = rng.random(256) < 0.5
+        for tier in ("hbm", "hmem"):
+            v2 = jax.jit(
+                lambda v, k: table_mod.tier_mask_rows(tier, v, k, 5)
+            )(_on(tier, _plane(logical)), jnp.asarray(keep))
+            np.testing.assert_array_equal(
+                _rows("hbm", v2, 256, 5),
+                np.where(keep[:, None], logical, 0.0))
+
+    @pytest.mark.parametrize("op", ["gather", "scatter", "mask"])
+    def test_hmem_plane_in_device_memory_is_refused(self, op):
+        """An 'hmem' plane found in device memory raises: a host-tier
+        table never silently runs as an HBM table."""
+        values = _plane(np.zeros((128, 4), np.float32))
+        rows = jnp.arange(8, dtype=jnp.int32)
+        call = {
+            "gather": lambda: table_mod.tier_gather("hmem", values, rows, 4),
+            "scatter": lambda: table_mod.tier_scatter(
+                "hmem", values, rows, jnp.ones((8, 4), jnp.float32)),
+            "mask": lambda: table_mod.tier_mask_rows(
+                "hmem", values, jnp.ones((128,), bool), 4),
+        }[op]
+        with pytest.raises(ValueError, match="pinned_host"):
+            call()
+
+
+class TestJitReturningTable:
+    def test_successor_declared_where_the_table_lives(self, monkeypatch):
+        """The successor's placement is the table's own, and one program
+        serves every table with that placement."""
+        monkeypatch.setattr(table_mod, "_declares_memory_kinds", lambda: True)
+        traces = []
+
+        def step(t, k):
+            traces.append(1)
+            r = t.insert_or_assign(k, jnp.ones((4, 3), jnp.float32))
+            return r.table, r.status
+
+        f = table_mod.jit_returning_table(step, donate_argnums=(0,))
+        t = HKVTable.create(capacity=128, dim=3)
+        want = [x.sharding for x in jax.tree.leaves(t)]
+        for i in range(3):
+            k = u64.from_uint64(np.arange(4 * i + 1, 4 * i + 5, dtype=np.uint64))
+            t, status = f(t, k)
+            assert status.shape == (4,)
+        assert [x.sharding for x in jax.tree.leaves(t)] == want
+        assert len(traces) == 1
+        assert int(t.size()) == 12
 
 
 def _twin_tables(dim=6, capacity=2 * 128):

@@ -32,6 +32,7 @@ from repro.core import (
 )
 from repro.core.scores import get_policy
 from repro.data import zipf_keys
+from repro.launch.mesh import make_mesh
 
 
 def _tiered(hot=2 * 128, cold=8 * 128, dim=4, **kw):
@@ -545,6 +546,19 @@ class TestTieredCheckpoint:
 # =============================================================================
 
 
+@pytest.mark.parametrize("tiers", [dict(value_tier="hmem"),
+                                   dict(hot_capacity=128)])
+def test_sharded_refuses_a_host_value_tier(tiers):
+    """A sharded table cannot hold a host-resident plane (a shard_map body
+    cannot place one): asking for it raises instead of running in HBM."""
+    from repro.distributed.table_sharding import ShardedHKVEmbedding
+    from repro.embedding.dynamic import HKVEmbedding
+
+    emb = HKVEmbedding(capacity=4 * 128, dim=3, **tiers)
+    with pytest.raises(NotImplementedError, match="host memory"):
+        ShardedHKVEmbedding(emb=emb, axis_names=("data",))
+
+
 @pytest.mark.slow  # shard_map compiles per op: minutes on CPU
 def test_sharded_over_tiered_protocol_conformance():
     from tests.test_kvtable_conformance import protocol_roundtrip as \
@@ -554,11 +568,12 @@ def test_sharded_over_tiered_protocol_conformance():
     from repro.embedding.dynamic import HKVEmbedding
     from repro.embedding.sparse_opt import SparseOptimizer
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     table = ShardedHKVTable.create(
         mesh,
         HKVEmbedding(capacity=4 * 128, dim=3, hot_capacity=128,
-                     optimizer=SparseOptimizer("sgd")),
+                     optimizer=SparseOptimizer("sgd"),
+                     cold_value_tier="hbm"),
     )
     table = _protocol_roundtrip(table)
     r = table.find_or_insert(np.arange(1, 65, dtype=np.uint64))

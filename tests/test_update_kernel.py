@@ -92,7 +92,7 @@ def _ref_update(state, cfg, k, grads, opt):
         state.digests, state.key_hi, state.key_lo, state.values,
         probe.bucket1, b2, probe.digest.astype(jnp.uint32), k.hi, k.lo,
         probe.valid.astype(jnp.int32), grads, opt, cfg.dim,
-        use_digest=cfg.use_digest)
+        cfg.total_value_dim, use_digest=cfg.use_digest)
 
 
 def _assert_update_equal(res, want_found, want_values, ctx=""):
@@ -124,13 +124,11 @@ def test_update_scan_matches_ref(variant, dual):
     args = (state.digests, state.key_hi, state.key_lo, state.values,
             probe.bucket1, b2, probe.digest.astype(jnp.uint32), k.hi, k.lo,
             probe.valid.astype(jnp.int32), grads)
-    want_found, want_values = ref.update_scan_ref(*args, opt=opt, dim=cfg.dim)
-    if variant == "tlp":
-        got_found, got_values = _upd.update_scan_tlp(
-            *args, opt=opt, dim=cfg.dim, interpret=True)
-    else:
-        got_found, got_values = _upd.update_scan_pipeline(
-            *args, q_tile=128, opt=opt, dim=cfg.dim, interpret=True)
+    want_found, want_values = ref.update_scan_ref(
+        *args, opt=opt, dim=cfg.dim, width=cfg.total_value_dim)
+    got_found, got_values = _upd.update_scan(
+        *args, opt=opt, dim=cfg.dim, width=cfg.total_value_dim,
+        variant=variant, q_tile=8, use_digest=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(got_found),
                                   np.asarray(want_found),
                                   err_msg=f"{variant} dual={dual} found")
@@ -267,9 +265,11 @@ def test_secondary_bucket_residents_train():
         _assert_update_equal(res, want_found, want_values,
                              f"{variant} secondary")
     # the secondary-bucket residents actually changed
-    rows_b2 = np.asarray(loc.row)[in_b2]
-    assert (np.asarray(want_values)[rows_b2]
-            != np.asarray(state.values)[rows_b2]).any()
+    rows_b2 = loc.row[in_b2]
+    w = cfg.total_value_dim
+    assert (np.asarray(table.tier_gather("hbm", want_values, rows_b2, w))
+            != np.asarray(table.tier_gather("hbm", state.values, rows_b2, w))
+            ).any()
 
 
 # =============================================================================
@@ -492,10 +492,8 @@ class TestLaunchBudget:
 
             monkeypatch.setattr(mod, name, counting)
 
-        wrap(_upd, "update_scan_tlp", "update_scan")
-        wrap(_upd, "update_scan_pipeline", "update_scan")
-        wrap(_ds, "digest_scan_tlp", "digest_scan")
-        wrap(_ds, "digest_scan_pipeline", "digest_scan")
+        wrap(_upd, "update_scan", "update_scan")
+        wrap(_ds, "digest_scan", "digest_scan")
         wrap(_ga, "gather_rows", "gather")
         wrap(_sc, "scatter_rows", "scatter")
         return counts

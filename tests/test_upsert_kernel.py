@@ -129,7 +129,7 @@ class TestFindOrInsertSinglePass:
         pre = find_mod.locate(state, cfg, k)
         res = merge.upsert(state, cfg, k, init, write_hit_values=False)
         post = find_mod.locate(res.state, cfg, k)
-        vals = find_mod.gather_values(res.state, post, cfg.dim, cfg.value_tier)
+        vals = find_mod.gather_values(res.state, post, cfg.dim, cfg)
         vals = jnp.where(post.found[:, None], vals, init[:, : cfg.dim])
         return res.state, vals, pre.found, res.status
 

@@ -71,7 +71,7 @@ def main() -> None:
         sys.exit("error: --json-out requires --timestamp (the driver passes "
                  "the clock in; artifacts never read one)")
     known = {"exp1", "exp2", "exp3", "exp4", "exp5", "exp6_online",
-             "exp7_maintenance", "exp9_train_apply", "roofline", "obs"}
+             "exp7_maintenance", "exp9_train_apply", "roofline"}
     bad = [a for a in args if a not in known]
     if bad:
         sys.exit(f"error: unknown argument(s) {bad}; experiments: {sorted(known)}, "
@@ -81,9 +81,9 @@ def main() -> None:
         sys.exit("error: --backend only applies to exp2; add exp2 to the "
                  "selection or drop the flag")
     if smoke and args and not ({"exp5", "exp6_online",
-                                "exp7_maintenance", "obs"} & set(args)):
+                                "exp7_maintenance"} & set(args)):
         sys.exit("error: --smoke only applies to exp5/exp6_online/"
-                 "exp7_maintenance/obs; add one to the selection or drop "
+                 "exp7_maintenance; add one to the selection or drop "
                  "the flag")
     sel = set(args)
     commit = _commit() if json_out else ""
@@ -136,10 +136,6 @@ def main() -> None:
         from benchmarks import exp9_train_apply
 
         emit("exp9_train_apply", exp9_train_apply.run())
-    if want("obs"):
-        from benchmarks import exp_obs
-
-        emit("obs", exp_obs.run(smoke=bool(smoke)))
     if want("roofline"):
         from benchmarks import roofline
 

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import find as find_mod
+from repro.core import roles
 from repro.core import table as table_mod
 from repro.core import u64
 from repro.core.table import HKVConfig, HKVState
@@ -169,6 +170,7 @@ class MergeResult(NamedTuple):
                                  # (loc.found = present now: hit or admitted)
 
 
+@roles.scoped("hkv.closure")
 def _dedupe_sort(keys: U64):
     """Sort batch by key; derive group ids / multiplicities / last-writer index.
 
@@ -211,6 +213,7 @@ class DedupeResult(NamedTuple):
     inverse: jax.Array     # int32 [N] original position -> its rep's sorted slot
 
 
+@roles.scoped("hkv.closure")
 def dedupe_keys(keys: U64) -> DedupeResult:
     """Public dedupe over the canonical key sort (shared by the engine and
     the api layer — see `repro.core.api.dedupe_keys` for the normalizing
@@ -244,6 +247,7 @@ def _bucket_minscore_and_occ(state: HKVState, bucket: jax.Array):
     return occ, U64(min_hi, min_lo)
 
 
+@roles.scoped("hkv.closure")
 def _select_target_bucket(
     state: HKVState, cfg: HKVConfig, probe: find_mod.Probe
 ) -> jax.Array:
@@ -264,6 +268,7 @@ def _select_target_bucket(
     return jnp.where(any_free, d1, d2)
 
 
+@roles.scoped("hkv.closure")
 def _jnp_victim_at_rank(state: HKVState, cfg: HKVConfig, bkt_g: jax.Array,
                         rank: jax.Array):
     """Rank-th weakest entry per gathered bucket row, via a per-row sort.
@@ -317,6 +322,7 @@ def jnp_stages() -> UpsertStages:
     )
 
 
+@roles.scoped("hkv.closure")
 def upsert(
     state: HKVState,
     cfg: HKVConfig,

@@ -18,11 +18,20 @@ ops and to fence at inserters.  hkv-lint's role checker
 ``core/ops.py`` to carry one of these annotations and cross-checks the
 session's recorded roles against them, so a new op cannot silently join
 the session machinery with the wrong commutativity class.
+
+Each annotated op also runs under the named scope ``hkv.op.<name>``, so
+every instruction it stages carries the scope in its HLO ``op_name``
+metadata: a device profile joined to the compiled module attributes the
+op's own glue to the op layer (DESIGN.md §Observability).  A scope is
+metadata only; the compiled program is otherwise unchanged.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, TypeVar
+
+import jax
 
 READER = "reader"
 UPDATER = "updater"
@@ -37,17 +46,35 @@ F = TypeVar("F", bound=Callable)
 def role(name: str) -> Callable[[F], F]:
     """Decorator declaring an op entry point's §3.5 role.
 
-    ``@role(roles.READER)`` etc.  The annotation is metadata only — it does
-    not wrap the function — so jit/static-argnum behaviour is untouched.
+    ``@role(roles.READER)`` etc.  The op is wrapped (``functools.wraps``,
+    so its signature and source stay visible) to run under the named scope
+    ``hkv.op.<fn name>``; jit/static-argnum behaviour is untouched.
     """
     if name not in ROLES:
         raise ValueError(f"unknown op role {name!r}; expected one of {ROLES}")
 
     def mark(fn: F) -> F:
-        setattr(fn, _ATTR, name)
-        return fn
+        op = scoped("hkv.op." + fn.__name__)(fn)
+        setattr(op, _ATTR, name)
+        return op
 
     return mark
+
+
+def scoped(scope: str) -> Callable[[F], F]:
+    """Decorator running a function under the named scope `scope`: one of
+    the program's layers, ``hkv.closure`` (the batch closure) or
+    ``hkv.kernel_io`` (the kernel wrappers), or ``hkv.emb.<method>``."""
+
+    def wrap(fn: F) -> F:
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
 
 
 def reader(fn: F) -> F:

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import roles
+
 UINT32_MASK = np.uint64(0xFFFFFFFF)
 
 # Reserved sentinel: the all-ones key marks an empty slot (the paper reserves
@@ -175,6 +177,7 @@ def add(a: U64, b: U64) -> U64:
 # significant first, and payloads are gathered through the permutation.
 # ---------------------------------------------------------------------------
 
+@roles.scoped("hkv.closure")
 def lexsort(keys, axis: int = 0) -> jax.Array:
     """int32 permutation ordering `keys` (most significant first)
     lexicographically along `axis`; ties keep their index order, as a

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ops as ops_mod
+from repro.core import roles
 from repro.core import u64
 from repro.core.api import HKVTable, dedupe_keys
 from repro.core.table import HKVConfig
@@ -129,6 +130,7 @@ class HKVEmbedding:
 
     # -- roles -------------------------------------------------------------
 
+    @roles.scoped("hkv.emb.lookup_train")
     def lookup_train(self, table: HKVTable, tokens: jax.Array):
         """INSERTER: find_or_insert the token batch. Returns (table, rows)."""
         keys = self.keys_of(tokens)
@@ -136,6 +138,7 @@ class HKVEmbedding:
         emb = res.values.reshape(tokens.shape + (self.dim,))
         return res.table, emb
 
+    @roles.scoped("hkv.emb.lookup_serve")
     def lookup_serve(self, table, tokens: jax.Array) -> jax.Array:
         """READER: find; misses fall back to the deterministic init row.
 
@@ -150,6 +153,7 @@ class HKVEmbedding:
         vals = jnp.where(res.found[:, None], res.values, self.default_rows(keys))
         return vals.reshape(tokens.shape + (self.dim,))
 
+    @roles.scoped("hkv.emb.apply_grads")
     def apply_grads(
         self, table: HKVTable, tokens: jax.Array, grads: jax.Array
     ) -> HKVTable:

@@ -5,6 +5,9 @@ one place that chooses it (`default_interpret`: compiled on a TPU,
 interpreted elsewhere).  They also pad every batch to the kernels' query
 tile and cut it into chunks of at most `CHUNK` queries, because each
 kernel holds its chunk's per-query scalars in SMEM: any batch size runs.
+That padding, chunking and the unpacking and masking of each kernel's
+outputs run under the named scope `hkv.kernel_io` (the kernel-wrapper
+layer of a device profile; DESIGN.md §Observability).
 The wrappers are drop-in equivalents of the pure-jnp paths in
 `repro.core` and are cross-checked against them in tests.
 
@@ -43,6 +46,7 @@ import jax.numpy as jnp
 
 from repro.core import find as find_mod
 from repro.core import merge as merge_mod
+from repro.core import roles
 from repro.core import table as table_mod
 from repro.core import u64
 from repro.core.table import HKVConfig, HKVState
@@ -59,6 +63,8 @@ from repro.kernels import upsert_scan as _us
 
 
 CHUNK = 4096  # queries per kernel call (their scalars live in SMEM)
+KERNEL_IO = "hkv.kernel_io"
+_io = roles.scoped(KERNEL_IO)
 
 
 def default_interpret() -> bool:
@@ -89,6 +95,7 @@ def _chunks(args, fills, npad):
     return [a.reshape((k, CHUNK) + a.shape[1:]) for a in args], k
 
 
+@_io
 def _per_query(call, args, fills):
     """Run a read-only per-query kernel `call(q_tile, *args)` over a batch:
     padded to its tile, one call per chunk.  Outputs trimmed to the batch."""
@@ -103,6 +110,7 @@ def _per_query(call, args, fills):
     return jax.tree.map(lambda o: o[:n], outs)
 
 
+@_io
 def _per_query_write(call, plane, args, fills):
     """`_per_query` for a kernel that rewrites the value plane:
     `call(q_tile, plane, *args) -> (out, plane)`, chunks in batch order."""
@@ -144,17 +152,18 @@ def locate_kernel(
             (0, 0, u64.EMPTY_HI, u64.EMPTY_LO))
         return slot, found.astype(bool)
 
-    slot1, hit1 = run(probe.bucket1)
-    if cfg.buckets_per_key == 2:
-        slot2, hit2 = run(probe.bucket2)
-        found = (hit1 | hit2) & probe.valid
-        bucket = jnp.where(hit1, probe.bucket1, jnp.where(hit2, probe.bucket2, probe.bucket1))
-        slot = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
-    else:
-        found = hit1 & probe.valid
-        bucket, slot = probe.bucket1, jnp.where(hit1, slot1, 0)
-    s = cfg.slots_per_bucket
-    return find_mod.Locate(found=found, bucket=bucket, slot=slot, row=bucket * s + slot)
+    with jax.named_scope(KERNEL_IO):
+        slot1, hit1 = run(probe.bucket1)
+        if cfg.buckets_per_key == 2:
+            slot2, hit2 = run(probe.bucket2)
+            found = (hit1 | hit2) & probe.valid
+            bucket = jnp.where(hit1, probe.bucket1, jnp.where(hit2, probe.bucket2, probe.bucket1))
+            slot = jnp.where(hit1, slot1, jnp.where(hit2, slot2, 0))
+        else:
+            found = hit1 & probe.valid
+            bucket, slot = probe.bucket1, jnp.where(hit1, slot1, 0)
+        s = cfg.slots_per_bucket
+        return find_mod.Locate(found=found, bucket=bucket, slot=slot, row=bucket * s + slot)
 
 
 class FusedFind(NamedTuple):
@@ -215,17 +224,18 @@ def find_fused_kernel(
         variant, interpret)
     # re-mask by probe validity: an EMPTY padding key may alias empty slots
     # in-kernel; the reference masks those out via probe.valid
-    found = found.astype(bool) & probe.valid
-    bucket = jnp.where(sel == 1, b2, probe.bucket1)
-    return FusedFind(
-        values=jnp.where(found[:, None], vals, 0),
-        found=found,
-        bucket=bucket,
-        slot=slot,
-        row=bucket * cfg.slots_per_bucket + slot,
-        score_hi=jnp.where(found, shi, 0),
-        score_lo=jnp.where(found, slo, 0),
-    )
+    with jax.named_scope(KERNEL_IO):
+        found = found.astype(bool) & probe.valid
+        bucket = jnp.where(sel == 1, b2, probe.bucket1)
+        return FusedFind(
+            values=jnp.where(found[:, None], vals, 0),
+            found=found,
+            bucket=bucket,
+            slot=slot,
+            row=bucket * cfg.slots_per_bucket + slot,
+            score_hi=jnp.where(found, shi, 0),
+            score_lo=jnp.where(found, slo, 0),
+        )
 
 
 def _find_scan(planes, cfg: HKVConfig, b1, b2, qd, keys: U64, variant: str,
@@ -365,6 +375,7 @@ def assign_kernel(
     return state._replace(values=new_values)
 
 
+@_io
 def _gather(values, cfg: HKVConfig, rows, mask, interpret: bool):
     """gather_rows over a whole batch: logical rows, zeros where ~mask."""
     rows = jnp.clip(rows, 0, cfg.capacity - 1)
@@ -374,6 +385,7 @@ def _gather(values, cfg: HKVConfig, rows, mask, interpret: bool):
     return _per_query(call, (rows, mask.astype(jnp.int32)), (0, 0))
 
 
+@_io
 def _scatter(values, cfg: HKVConfig, rows, updates, mask, add: bool,
              interpret: bool):
     """scatter_rows over a whole batch (masked-out lanes write nothing)."""
@@ -541,6 +553,7 @@ def _kernel_locate_stage(cfg: HKVConfig, interpret: bool):
     if cfg.buckets_per_key == 1:
         return locate_single
 
+    @_io
     def locate(state: HKVState, _cfg: HKVConfig, keys: U64, probe):
         found, hit_sel, hit_slot, _tgt = _upsert_probe(
             state, cfg, probe, probe.digest.astype(jnp.uint32), keys,
@@ -565,6 +578,7 @@ def _kernel_select_stage(cfg: HKVConfig, interpret: bool):
     batch's score touches.
     """
 
+    @_io
     def select(state: HKVState, _cfg: HKVConfig, probe):
         if cfg.buckets_per_key == 1:
             return probe.bucket1
@@ -580,6 +594,7 @@ def _kernel_select_stage(cfg: HKVConfig, interpret: bool):
 def _kernel_victim_stage(cfg: HKVConfig, interpret: bool):
     """UpsertStages.victim_at_rank backed by the claim_scan rank kernel."""
 
+    @_io
     def victim(state: HKVState, _cfg: HKVConfig, bkt_g, rank):
         s = cfg.slots_per_bucket
         scan = lambda q_tile, *a: _us.claim_scan(
@@ -721,7 +736,9 @@ def find_or_insert_kernel(
                                   interpret=interpret)
     else:
         vals = find_mod.gather_values(res.state, res.loc, cfg.dim, cfg)
-    vals = jnp.where(res.loc.found[:, None], vals, init_values[:, : cfg.dim])
+    with jax.named_scope(KERNEL_IO):
+        vals = jnp.where(res.loc.found[:, None], vals,
+                         init_values[:, : cfg.dim])
     return res.state, vals, res.found, res.status
 
 

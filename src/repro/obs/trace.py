@@ -1,10 +1,14 @@
 """Span tracing — a low-overhead host-side tracer with Chrome export.
 
 Records nestable spans (``with tracer.span("wave.dispatch"): ...``) and
-instant events (``tracer.instant("maintenance.deferred")``) against a
-single `perf_counter` epoch, then exports the Chrome trace-event JSON
-format (the ``{"traceEvents": [...]}`` object form) loadable in Perfetto
-or ``chrome://tracing``.
+instant events (``tracer.instant("maintenance.deferred")``) on the
+profiler's clock (microseconds since the Unix epoch, the clock of the host
+events in a `jax.profiler` trace), then exports the Chrome trace-event
+JSON format (the ``{"traceEvents": [...]}`` object form) loadable in
+Perfetto or ``chrome://tracing``.  A span also enters
+`jax.profiler.TraceAnnotation("hkv." + name)`, so under a profiler trace it
+lands in the host plane beside the device's ops, where it names the device
+idle gaps it covers.
 
 Span taxonomy wired by the serving stack (see DESIGN.md §Observability):
 
@@ -33,15 +37,20 @@ import time
 from contextlib import contextmanager
 from typing import Optional
 
+import jax
+
 
 class Tracer:
     """Collects trace events in memory; thread-safe (the serving engine
     dispatches and reaps from the caller thread but maintenance may run
-    from a helper).  Timestamps are microseconds since the tracer's
-    creation — one shared epoch so spans from all components align."""
+    from a helper).  Intervals are taken on `perf_counter` from the
+    tracer's epoch and exported on the profiler's clock: microseconds since
+    the Unix epoch, so spans from all components and a device trace of the
+    same run align."""
 
     def __init__(self):
         self._t0 = time.perf_counter()
+        self._unix0_us = time.time_ns() / 1e3   # the epoch on that clock
         self._lock = threading.Lock()
         self.events: list[dict] = []
 
@@ -51,29 +60,33 @@ class Tracer:
         """Seconds since the tracer epoch (pair with `complete`)."""
         return time.perf_counter() - self._t0
 
-    def _us(self, t_s: float) -> float:
-        return round(t_s * 1e6, 3)
+    def _ts(self, t_s: float) -> float:
+        """Epoch-relative seconds on the profiler's clock, in µs."""
+        return round(self._unix0_us + t_s * 1e6, 3)
 
     # -- recording -----------------------------------------------------------
 
     @contextmanager
     def span(self, name: str, **args):
-        """Record a complete span (ph="X") around the with-body."""
-        t_start = self.now()
-        try:
-            yield self
-        finally:
-            self.complete(name, t_start, self.now(), **args)
+        """Record a complete span (ph="X") around the with-body, also as
+        the profiler annotation ``hkv.<name>``."""
+        with jax.profiler.TraceAnnotation("hkv." + name):
+            t_start = self.now()
+            try:
+                yield self
+            finally:
+                self.complete(name, t_start, self.now(), **args)
 
     def complete(self, name: str, t_start: float, t_end: float, **args):
         """Record a span from explicit epoch-relative stamps (seconds) —
         for lifetimes that straddle call boundaries, e.g. a request's
         submit→done window stamped by the engine."""
+        ts = self._ts(t_start)
         ev = {
             "name": name,
             "ph": "X",
-            "ts": self._us(t_start),
-            "dur": self._us(max(t_end - t_start, 0.0)),
+            "ts": ts,
+            "dur": max(self._ts(t_end) - ts, 0.0),
             "pid": 0,
             "tid": 0,
         }
@@ -94,7 +107,7 @@ class Tracer:
             "name": name,
             "ph": "i",
             "s": "t",
-            "ts": self._us(self.now()),
+            "ts": self._ts(self.now()),
             "pid": 0,
             "tid": 0,
         }

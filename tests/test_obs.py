@@ -392,3 +392,28 @@ def test_registry_observes_engine_scheduler_and_stats():
     assert reg.get("hkv_maintenance_deferred") == 4.0
     assert reg.get("hkv_hot_capacity") == 256.0
     assert reg.get("hkv_hot_load_factor") == 0.0
+
+
+def test_tracer_spans_land_in_the_profiler_trace_on_its_clock(tmp_path):
+    """A live Tracer's span is also the profiler annotation hkv.<name>, and
+    the Chrome export stamps it on the profiler's clock (Unix-epoch µs)."""
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("wave.dispatch"):
+            jnp.arange(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    data = ProfileData.from_file(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    planes = list(data.planes)
+    start_ns = next(int(dict(p.stats)["profile_start_time"]) for p in planes
+                    if p.name == "Task Environment")
+    spans = [ev for p in planes if p.name.startswith("/host:")
+             for line in p.lines for ev in line.events
+             if ev.name == "hkv.wave.dispatch"]
+    assert len(spans) == 1
+    (chrome,) = [e for e in tr.to_chrome()["traceEvents"]
+                 if e["name"] == "wave.dispatch"]
+    assert abs((start_ns + spans[0].start_ns) / 1e3 - chrome["ts"]) < 1e3

@@ -16,6 +16,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 N, S, V = 4, 8, 8
+RING = 4          # slots of the ring-buffer fixture
 
 
 def _unpaired_dma_kernel(rows_ref, v_hbm, out_ref, vbuf, vsem):
@@ -44,6 +45,38 @@ def unpaired_dma(values, rows, *, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((n, v), values.dtype),
         interpret=interpret,
         name="fixture_unpaired_dma",
+    )(rows, values)
+
+
+def _unpaired_ring_dma_kernel(rows_ref, v_hbm, out_ref, ring, sems):
+    i = pl.program_id(0)
+    row = rows_ref[i]
+    slot = jax.lax.rem(i, RING)
+    # BUG: the ring slot's copy is started to be retired later, and no
+    # later wait on its semaphore exists
+    pltpu.make_async_copy(v_hbm.at[pl.ds(row, 1), :], ring.at[slot],
+                          sems.at[slot]).start()
+    out_ref[0, :] = jnp.where(row >= 0, ring[slot][0, :],
+                              jnp.zeros_like(ring[slot][0, :]))
+
+
+def unpaired_ring_dma(values, rows, *, interpret: bool = True):
+    n = rows.shape[0]
+    v = values.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)],
+        out_specs=pl.BlockSpec((1, v), lambda i, r: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((RING, 1, v), values.dtype),
+                        pltpu.SemaphoreType.DMA((RING,))],
+    )
+    return pl.pallas_call(
+        _unpaired_ring_dma_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, v), values.dtype),
+        interpret=interpret,
+        name="fixture_unpaired_ring_dma",
     )(rows, values)
 
 
@@ -103,6 +136,11 @@ def _args():
 def trace_unpaired_dma():
     vals, rows = _args()
     return jax.make_jaxpr(functools.partial(unpaired_dma))(vals, rows)
+
+
+def trace_unpaired_ring_dma():
+    vals, rows = _args()
+    return jax.make_jaxpr(functools.partial(unpaired_ring_dma))(vals, rows)
 
 
 def trace_unmasked_store():

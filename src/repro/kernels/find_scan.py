@@ -22,7 +22,7 @@ One scalar-prefetched pass per query over BOTH candidate bucket rows:
   4. score readout        the hit slot's (score_hi, score_lo) lifted from
                           the streamed metadata rows, so ``FindResult`` /
                           ``FindRowsResult`` scores need no second probe;
-  5. in-line value gather a data-dependent HBM->VMEM ``make_async_copy``
+  5. value gather         a data-dependent HBM->VMEM ``make_async_copy``
                           of the hit row at ``bucket * S + slot``.  The
                           row index exists only *inside* the kernel (it is
                           the match result), which is precisely why the
@@ -31,9 +31,14 @@ One scalar-prefetched pass per query over BOTH candidate bucket rows:
                           but an explicit DMA can.
 
 Two variants over the same tile of queries per grid step
-(``bucket_rows.query_loop``): ``tlp`` fetches, waits and compares one
-query at a time; ``pipeline`` streams query q+1's ten metadata rows
-(5 planes x 2 buckets) while query q is compared.  The hit's value row is
+(``bucket_rows.query_loop``), differing only in how many queries' DMAs
+are in flight: ``tlp`` fetches, waits and compares one query at a time.
+``pipeline`` streams query q+1's ten metadata rows (5 planes x 2 buckets)
+while query q is compared, and starts query q's value-row DMA into slot
+q mod 2 of a VMEM ring without waiting: query q is retired in query
+q+1's step (wait, rotate, mask, store its row), and the tile's last query
+after the loop.  No query waits on its own value row.  A retired query's
+lane and hit flag are rebuilt from its SMEM outputs.  The value row is
 one 128-lane row of the packed value plane (``core.table``), rotated in
 VMEM so the slot's lanes come first.
 
@@ -84,11 +89,17 @@ def _kernel(depth, q_tile, group, width, use_digest,
             b1_ref, b2_ref, qd_ref, qh_ref, ql_ref,
             td, th, tl, tsh, tsl, tv,
             found_ref, sel_ref, slot_ref, shi_ref, slo_ref, val_ref,
-            d1, h1, l1, sh1, sl1, d2, h2, l2, sh2, sl2, vbuf, sems, vsem):
+            d1, h1, l1, sh1, sl1, d2, h2, l2, sh2, sl2, vbuf, sems, vsems):
     i = pl.program_id(0)
     lanes = tv.shape[1]
     slots = th.shape[1]
     planes = (td, th, tl, tsh, tsl)
+    # The value ring has the metadata ring's depth: query q's row is waited
+    # on `lag` queries after its start.  On a v5e at config A, deeper rings
+    # gained nothing: every (metadata, value) depth in {2, 4, 8} x
+    # {2, 4, 8, 16} ran within 0.2 % of (2, 2) or slower (PERF.md, Findings),
+    # since the compare, not a DMA, is now the longer part of a query.
+    lag = depth - 1
 
     def copies(q, s):
         n = i * q_tile + q
@@ -99,6 +110,26 @@ def _kernel(depth, q_tile, group, width, use_digest,
                 cps.append(br.row_copy(p, buf.at[s], sems.at[s, 5 * k + j],
                                        b, group if j == 0 else 1))
         return cps
+
+    def value_copy(q):
+        """Query q's value-row DMA into ring slot q mod `depth`, and the
+        slot's first lane, rebuilt from the query's SMEM outputs.  Misses
+        fetch row b1*S+0 (a valid address); `retire` masks them to zeros,
+        the same contract as `find.gather_values`."""
+        n = i * q_tile + q
+        row = jnp.where(sel_ref[n] == 0, b1_ref[n], b2_ref[n]) * slots \
+            + slot_ref[n]
+        prow, lane0 = slot_lanes(row, width, lanes)
+        v = jax.lax.rem(q, depth)
+        return (pltpu.make_async_copy(tv.at[pl.ds(prow, 1), :], vbuf.at[v],
+                                      vsems.at[v]), lane0, v)
+
+    def retire(q):
+        cp, lane0, v = value_copy(q)
+        cp.wait()
+        x = to_front(vbuf[v], lane0, lanes, width)[:, :width]
+        found = found_ref[i * q_tile + q] != 0
+        val_ref[pl.ds(q, 1), :] = jnp.where(found, x, jnp.zeros_like(x))
 
     def body(q, s):
         n = i * q_tile + q
@@ -116,9 +147,8 @@ def _kernel(depth, q_tile, group, width, use_digest,
                 m = match_lanes(h[s], l[s], qh, ql)
             return br.first_lane(m)
 
-        b1, b2 = b1_ref[n], b2_ref[n]
-        hit1, slot1 = row_match(b1, d1, h1, l1)
-        hit2, slot2 = row_match(b2, d2, h2, l2)
+        hit1, slot1 = row_match(b1_ref[n], d1, h1, l1)
+        hit2, slot2 = row_match(b2_ref[n], d2, h2, l2)
         found, sel, slot = _merge_hits(hit1, slot1, hit2, slot2)
 
         # score readout from the already-streamed metadata rows
@@ -130,18 +160,19 @@ def _kernel(depth, q_tile, group, width, use_digest,
         shi_ref[n] = jnp.where(found, pick(sh1, sh2), 0)
         slo_ref[n] = jnp.where(found, pick(sl1, sl2), 0)
 
-        # in-line value gather: position addressing (§3.6) resolved
-        # in-kernel.  Misses fetch row b1*S+0 (a valid address) and mask
-        # to zeros — the same contract as `find.gather_values`.
-        row = jnp.where(sel == 0, b1, b2) * slots + slot
-        prow, lane0 = slot_lanes(row, width, lanes)
-        cp = pltpu.make_async_copy(tv.at[pl.ds(prow, 1), :], vbuf, vsem)
-        cp.start()
-        cp.wait()
-        x = to_front(vbuf[...], lane0, lanes, width)[:, :width]
-        val_ref[pl.ds(q, 1), :] = jnp.where(found, x, jnp.zeros_like(x))
+        # value gather: position addressing (§3.6) resolved in-kernel; the
+        # row DMA is waited on `lag` queries later
+        value_copy(q)[0].start()
+        if lag == 0:
+            retire(q)
+        else:
+            @pl.when(q >= lag)
+            def _():
+                retire(q - lag)
 
     br.query_loop(q_tile, depth, copies, body)
+    for q in range(max(0, q_tile - lag), q_tile):
+        retire(q)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -169,7 +200,8 @@ def find_scan(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
     group = br.digest_group(nb)
     i32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)
     out_spec, out_shape = br.smem_out(n)
-    meta = lambda dt, rows=1: pltpu.VMEM((2, rows, s), dt)
+    depth = br.variant_depth(variant)
+    meta = lambda dt, rows=1: pltpu.VMEM((depth, rows, s), dt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(n // q_tile,),
@@ -179,13 +211,12 @@ def find_scan(tdigests, tkey_hi, tkey_lo, tscore_hi, tscore_lo, tvalues,
         scratch_shapes=(
             [meta(jnp.uint8, group)] + [meta(jnp.uint32)] * 4
             + [meta(jnp.uint8, group)] + [meta(jnp.uint32)] * 4
-            + [pltpu.VMEM((1, lanes), tvalues.dtype),
-               pltpu.SemaphoreType.DMA((2, 10)),
-               pltpu.SemaphoreType.DMA]),
+            + [pltpu.VMEM((depth, 1, lanes), tvalues.dtype),
+               pltpu.SemaphoreType.DMA((depth, 10)),
+               pltpu.SemaphoreType.DMA((depth,))]),
     )
     found, sel, slot, shi, slo, vals = pl.pallas_call(
-        functools.partial(_kernel, br.variant_depth(variant), q_tile, group,
-                          width, use_digest),
+        functools.partial(_kernel, depth, q_tile, group, width, use_digest),
         grid_spec=grid_spec,
         out_shape=[out_shape] * 5 + [
             jax.ShapeDtypeStruct((n, width), tvalues.dtype)],

@@ -39,6 +39,14 @@ class TestKernelFixtures:
             "fixture_unpaired_dma", "fixture", bad_kernels.trace_unpaired_dma())
         assert "dma-unpaired" in rules(fs)
 
+    def test_unpaired_ring_slot_dma_flagged(self):
+        """A copy started into a ring slot to be retired later, with no
+        wait on its semaphore anywhere in the body."""
+        fs = kc.check_traced_kernel(
+            "fixture_unpaired_ring_dma", "fixture",
+            bad_kernels.trace_unpaired_ring_dma())
+        assert "dma-unpaired" in rules(fs)
+
     def test_unmasked_store_flagged(self):
         fs = kc.check_traced_kernel(
             "fixture_unmasked_store", "fixture",

@@ -190,19 +190,121 @@ def test_fused_matches_old_composition(variant, dual):
     np.testing.assert_array_equal(np.asarray(v_new), np.asarray(v_old))
 
 
-def test_secondary_bucket_hits_are_exercised_and_identical():
-    """Drive a small dual table to λ=1.0 so some residents live in their
-    SECONDARY bucket, then pin that the fused path resolves them."""
-    rng = np.random.default_rng(5)
+def _raw_vs_ref(state, cfg, k, variant, ctx, q_tile=None):
+    """The raw kernel, against `ref.find_scan_ref`, over the batch padded
+    and tiled as the wrapper plans it (or in tiles of `q_tile`)."""
+    n = k.hi.shape[0]
+    q_tile, npad = kops._plan(n) if q_tile is None else (q_tile, n)
+    probe = find_mod.probe_keys(cfg, k)
+    b2 = probe.bucket2 if cfg.buckets_per_key == 2 else probe.bucket1
+    pad = lambda x, fill=0: kops._pad_to(x, npad, fill)
+    args = (state.digests, state.key_hi, state.key_lo, state.score_hi,
+            state.score_lo, state.values, pad(probe.bucket1), pad(b2),
+            pad(probe.digest.astype(jnp.uint32)), pad(k.hi, u64.EMPTY_HI),
+            pad(k.lo, u64.EMPTY_LO))
+    want = ref.find_scan_ref(*args, cfg.total_value_dim)
+    got = _fs.find_scan(*args, width=cfg.total_value_dim, variant=variant,
+                        q_tile=q_tile, use_digest=True, interpret=True)
+    for g, w, name in zip(got, want,
+                          ("found", "sel", "slot", "shi", "slo", "vals")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=f"{ctx} {name}")
+
+
+def _dual_table_at_full_load(rng):
+    """A small dual-bucket table driven to λ=1.0 by chunked inserts:
+    load-balance picks the emptier candidate per step, so once primaries
+    fill, later keys land in their SECONDARY bucket."""
     cfg = table.HKVConfig(capacity=2 * 128, dim=4, buckets_per_key=2)
-    # chunked inserts: load-balance picks the emptier candidate per step,
-    # so once primaries fill, later keys land in their secondary bucket
     state = table.create(cfg)
     resident = rng.integers(1, 2**50, size=600).astype(np.uint64)
     for chunk in np.split(resident, 12):
         vals = jnp.asarray(rng.normal(size=(len(chunk), cfg.dim)),
                            jnp.float32)
         state = merge.upsert(state, cfg, u64.from_uint64(chunk), vals).state
+    return state, cfg, resident
+
+
+@pytest.mark.parametrize("n", [8, 24, 136])
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_ring_edges_bit_identical(variant, n):
+    """The wrapper's tiles: q_tile 8, 24, and 128 over two tiles, the
+    second mostly padding.  Every query retired in-loop or after the loop
+    must match both oracles."""
+    rng = np.random.default_rng(100 + n)
+    cfg = table.HKVConfig(capacity=4 * 128, dim=8, buckets_per_key=2)
+    state, resident = _filled_table(rng, cfg, 600)
+    q = _query_batch(rng, resident, n - n // 4 - n // 8, n // 4, n // 8)[:n]
+    k = u64.from_uint64(q)
+    r = kops.find_fused_kernel(state, cfg, k, variant=variant,
+                               interpret=True)
+    _assert_fused_equal(r, state, cfg, k, f"{variant} n={n}")
+    _raw_vs_ref(state, cfg, k, variant, f"{variant} n={n}")
+
+
+@pytest.mark.parametrize("q_tile", [1, 3])
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_ring_odd_tiles_match_ref(variant, q_tile):
+    """Raw kernel tiles shorter than, and no multiple of, the rings' two
+    slots: with one query a tile, only the after-loop retire runs."""
+    rng = np.random.default_rng(71 + q_tile)
+    cfg = table.HKVConfig(capacity=4 * 128, dim=8, buckets_per_key=2)
+    state, resident = _filled_table(rng, cfg, 600)
+    q = _query_batch(rng, resident, 6 * q_tile, q_tile, q_tile)
+    _raw_vs_ref(state, cfg, u64.from_uint64(q), variant,
+                f"{variant} q_tile={q_tile}", q_tile=q_tile)
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_ring_tail_misses_and_second_bucket_hits(variant):
+    """Misses and secondary-bucket hits in the last 16 positions of each
+    tile, the last of them retired after the loop, over two tiles of 128."""
+    rng = np.random.default_rng(57)
+    state, cfg, resident = _dual_table_at_full_load(rng)
+    keys = np.unique(resident)
+    k = u64.from_uint64(keys)
+    loc = find_mod.locate(state, cfg, k)
+    probe = find_mod.probe_keys(cfg, k)
+    found = np.asarray(loc.found)
+    in_b2 = found & np.asarray((loc.bucket == probe.bucket2)
+                               & (probe.bucket2 != probe.bucket1))
+    second, first = keys[in_b2], keys[found & ~in_b2]
+    assert len(second) >= 16, "fill did not produce secondary residents"
+    misses = rng.integers(2**50, 2**60, size=16).astype(np.uint64)
+    tail = np.stack([misses, rng.choice(second, 16)], axis=1).reshape(-1)
+    tiles = []
+    for t in range(2):
+        head = rng.choice(first, 128 - 16)
+        tiles.append(np.concatenate([head, tail[16 * t:16 * (t + 1)]]))
+    q = u64.from_uint64(np.concatenate(tiles))
+    assert kops._plan(256) == (128, 256)
+    r = kops.find_fused_kernel(state, cfg, q, variant=variant,
+                               interpret=True)
+    _assert_fused_equal(r, state, cfg, q, f"{variant} ring tail")
+    _raw_vs_ref(state, cfg, q, variant, f"{variant} ring tail")
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_ring_across_chunk_boundary(variant):
+    """A batch of CHUNK + 136 keys runs as two kernel calls of CHUNK
+    queries (the second mostly padding): each call starts and drains its
+    rings on its own."""
+    rng = np.random.default_rng(61)
+    cfg = table.HKVConfig(capacity=4 * 128, dim=8, buckets_per_key=2)
+    state, resident = _filled_table(rng, cfg, 600)
+    n = kops.CHUNK + 136
+    q = _query_batch(rng, resident, n - n // 4 - n // 8, n // 4, n // 8)[:n]
+    k = u64.from_uint64(q)
+    assert kops._plan(n) == (128, 2 * kops.CHUNK)
+    r = kops.find_fused_kernel(state, cfg, k, variant=variant,
+                               interpret=True)
+    _assert_fused_equal(r, state, cfg, k, f"{variant} n={n}")
+
+
+def test_secondary_bucket_hits_are_exercised_and_identical():
+    """Drive a small dual table to λ=1.0 so some residents live in their
+    SECONDARY bucket, then pin that the fused path resolves them."""
+    state, cfg, resident = _dual_table_at_full_load(np.random.default_rng(5))
     assert float(state.load_factor()) == 1.0
     k = u64.from_uint64(np.unique(resident))
     loc = find_mod.locate(state, cfg, k)

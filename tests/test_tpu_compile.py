@@ -76,9 +76,21 @@ def _config_a():
     return PAPER_CONFIGS["A"].embedding().config()
 
 
-@pytest.mark.parametrize("variant", ["tlp", "pipeline"])
-def test_find_scan_compiles(one_chip, variant):
-    cfg = _config_a()
+def _share_config(dim):
+    """Rows of dim + 1 (rowwise_adagrad) floats: config A's table at dim 8,
+    a 2^24-slot share at widths 32 and 64 (their 2^27-slot tables exceed
+    one chip)."""
+    cap = PAPER_CONFIGS["A"].capacity if dim == 8 else 1 << 24
+    return table_mod.HKVConfig(capacity=cap, dim=dim, aux_value_dim=1,
+                               buckets_per_key=2)
+
+
+@pytest.mark.parametrize("variant,dim", [
+    pytest.param(v, d, id=v if d == 8 else f"{v}-{d}")
+    for v in ("tlp", "pipeline") for d in (8, 32, 64)])
+def test_find_scan_compiles(one_chip, variant, dim):
+    """The fused find, its metadata and value rings at every value pitch."""
+    cfg = _config_a() if dim == 8 else _share_config(dim)
     fn = lambda st, kh, kl: kops.find_fused_kernel(
         st, cfg, U64(kh, kl), variant=variant, interpret=False)
     assert "tpu_custom_call" in _compile(fn, _state(one_chip, cfg),
@@ -124,11 +136,7 @@ def test_upsert_probe_and_claim_scan_compile(one_chip):
 
 @pytest.mark.parametrize("dim", [8, 32, 64])
 def test_gather_and_scatter_rows_compile(one_chip, dim):
-    """Rows of dim + 1 (rowwise_adagrad) floats; widths 32 and 64 at a
-    2^24-slot share (their 2^27-slot tables exceed one chip)."""
-    cap = PAPER_CONFIGS["A"].capacity if dim == 8 else 1 << 24
-    cfg = table_mod.HKVConfig(capacity=cap, dim=dim, aux_value_dim=1,
-                              buckets_per_key=2)
+    cfg = _share_config(dim)
     rows = _shape(one_chip, (BATCH,), jnp.int32)
     mask = _shape(one_chip, (BATCH,), jnp.bool_)
     upd = _shape(one_chip, (BATCH, cfg.total_value_dim), jnp.float32)

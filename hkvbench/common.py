@@ -60,6 +60,18 @@ def closed_loop(seconds: float, depth: int, dispatch, done):
     return t_open, time.perf_counter(), i
 
 
+def convert_element_type(x):
+    # named as the program `jnp.uint32(i)` runs, so the module names match
+    return jax.lax.convert_element_type(x, jnp.uint32)
+
+
+def index_program():
+    """(program, arguments) of the `jnp.uint32(i)` a window runs on the
+    device for each batch index, by the name JAX gives it
+    (`jit_convert_element_type`)."""
+    return jax.jit(convert_element_type), (np.uint32(0),)
+
+
 def to_bf16(rows) -> np.ndarray:
     """Rows rounded to bfloat16, the precision below the configuration's
     float32, and back: the control's answers."""

@@ -25,6 +25,9 @@ from hkvbench.harness import Reading, Window
 RETAIN = 4          # batches whose answers are compared in full
 SAMPLE = 4096       # lanes whose residency the reference recomputes
 
+TINY = ({"capacity": 8 * 128}, {"batch": 256})
+FAULTS = ("control", "half_batch", "altered_answer")
+
 
 @dataclasses.dataclass
 class State:
@@ -88,6 +91,13 @@ def window(run, st) -> Window:
         run.seconds, int(run.cell.traffic["in_flight"]), dispatch, done)
     return Window(t_open=t_open, t_close=t_close, attempted=batches,
                   failed=0, keys=batches * n)
+
+
+def programs(run, st) -> list:
+    s = st.table.state
+    args = (s.key_hi, s.key_lo, st.kdata, jnp.uint32(0))
+    return [common.index_program(), (st.keygen, args),
+            (st.find, (st.table,) + tuple(st.keygen(*args)))]
 
 
 def release(run, st, win):

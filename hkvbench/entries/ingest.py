@@ -25,6 +25,9 @@ from hkvbench.harness import Reading, Window
 
 SAMPLE = 4096
 
+TINY = ({"capacity": 8 * 128}, {"batch": 1 << 12})
+FAULTS = ("control", "half_batch", "altered_answer", "unchanged_state")
+
 
 @dataclasses.dataclass
 class State:
@@ -88,6 +91,11 @@ def window(run, st) -> Window:
         run.seconds, int(tr["in_flight"]), dispatch, done)
     return Window(t_open=t_open, t_close=t_close, attempted=st.batches,
                   failed=0, keys=st.batches * n)
+
+
+def programs(run, st) -> list:
+    return [common.index_program(), (st.gen, (st.c, jnp.uint32(0))),
+            (st.insert, None)]
 
 
 def release(run, st, win):

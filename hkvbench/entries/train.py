@@ -33,6 +33,10 @@ SAMPLE = 16384      # ids whose row is read back after the window
 # see PERF.md for the readings it was set from
 ROW_GAP_LIMIT = 1e-4
 
+TINY = ({"capacity": 16 * 128},
+        {"lookups_per_step": 1024, "id_space": 8192, "window_lanes": 256})
+FAULTS = ("control", "half_batch", "altered_answer", "unchanged_state")
+
 
 def _gap(got, want) -> float:
     if not np.size(want):
@@ -118,6 +122,13 @@ def window(run, st) -> Window:
         run.seconds, int(tr["in_flight"]), dispatch, lambda i, out: None)
     return Window(t_open=t_open, t_close=t_close, attempted=st.steps,
                   failed=0, keys=st.steps * int(tr["lookups_per_step"]))
+
+
+def programs(run, st) -> list:
+    tok = _tokens(st, 0)
+    rows = jax.eval_shape(st.step, st.table, tok)[1]
+    return [common.index_program(), (st.gen, (st.kdata, jnp.uint32(0), st.c)),
+            (st.step, (st.table, tok)), (st.pick, (rows, st.lanes))]
 
 
 def release(run, st, win):

@@ -9,7 +9,9 @@ window runs, from the persistent compilation cache in `<checkout>/.jax_cache`
 The window then drives the cell's entry for `--seconds`; the batch in
 flight when the time is up completes and closes the window.  With
 `--trace 1` the window runs under the profiler and the line reports the
-cell's per-layer metrics instead of its end-to-end ones.  After the window
+cell's per-layer metrics instead of its end-to-end ones; the window's
+programs (the entry's `programs`) then compile again, from the cache, so
+that `layers.reduce` can split the trace by program layer.  After the window
 the program's state is freed and the entry's answers are compared with the
 plain reference (`reference.py`); every number compared is printed with
 its limit, on standard error and under `checks` in the result line.
@@ -24,7 +26,7 @@ import shutil
 import sys
 import time
 
-from hkvbench import device, spec
+from hkvbench import device, layers, spec
 from hkvbench import trace as tracemod
 
 FAULTS = ("control", "unchanged_state", "half_batch", "altered_answer")
@@ -77,6 +79,8 @@ class MetricCtx:
     resident_keys: int
     summary: tracemod.Summary | None
     peaks: dict
+    counters: dict          # the entry's own counts (`counters`)
+    layers: layers.Split | None     # the traced window by program layer
 
 
 def process_age_s() -> float:
@@ -135,6 +139,17 @@ def main(argv, t_start: float, age0: float) -> int:
     return 0
 
 
+def read_metrics(wanted, ctx: MetricCtx) -> dict:
+    """{name: {value, unit}} of each metric in `wanted` whose reader finds
+    something to read."""
+    metrics = {}
+    for m in wanted:
+        value = spec.reader(m.name)(ctx)
+        if value is not None:
+            metrics[m.name] = {"value": value, "unit": m.unit}
+    return metrics
+
+
 def measure(run: Run, *, trace: bool, t_start: float, age0: float,
             peaks: dict) -> dict:
     """Set up, measure, check; the result line as a dict."""
@@ -147,7 +162,7 @@ def measure(run: Run, *, trace: bool, t_start: float, age0: float,
     print("hkvbench: set-up " + ", ".join(f"{k} {v:.3f} s"
                                           for k, v in parts.items()),
           file=sys.stderr)
-    summary = None
+    summary = split = None
     if trace:
         tdir = spec.ROOT / ".hkvbench" / "trace" / run.cell.name
         with tracemod.Capture(tdir) as cap:
@@ -156,6 +171,11 @@ def measure(run: Run, *, trace: bool, t_start: float, age0: float,
         if cap.xplane is None:
             raise RuntimeError("the profiler wrote no trace")
         summary = tracemod.reduce_trace(cap.xplane, cap.wall_s)
+        t_split = time.perf_counter()
+        split = layers.reduce(cap.xplane, layers.module_texts(
+            layers.compiled_texts(ent.programs(run, state))))
+        print(f"hkvbench: trace split by layer in "
+              f"{time.perf_counter() - t_split:.3f} s", file=sys.stderr)
         shutil.rmtree(tdir, ignore_errors=True)
     else:
         win = ent.window(run, state)
@@ -163,16 +183,15 @@ def measure(run: Run, *, trace: bool, t_start: float, age0: float,
     resident = ent.resident(run, state)
     in_use = device.memory(run.devs, "bytes_in_use")
     peak = device.memory(run.devs, "peak_bytes_in_use")
+    counters = ent.counters(run, state, win)
     t_check = time.perf_counter()
     readings = ent.check(run, state, win)
     print(f"hkvbench: window {win.seconds:.3f} s, check against the "
           f"reference {time.perf_counter() - t_check:.3f} s", file=sys.stderr)
-    mctx = MetricCtx(run, win, setup_s, in_use, resident, summary, peaks)
-    metrics = {}
-    for m in (run.cell.per_layer if trace else run.cell.end_to_end):
-        value = spec.reader(m.name)(mctx)
-        if value is not None:
-            metrics[m.name] = {"value": value, "unit": m.unit}
+    mctx = MetricCtx(run, win, setup_s, in_use, resident, summary, peaks,
+                     counters, split)
+    metrics = read_metrics(run.cell.per_layer if trace
+                           else run.cell.end_to_end, mctx)
     dev = device.describe(run.devs)
     dev["memory_peak_bytes"] = peak
     line = {"correct": all(r.ok for r in readings),

@@ -28,8 +28,10 @@ else of its operands, else `client`.  A traced module with no text, a
 module name held by two programs, and an instruction missing from its
 module's text are errors (`LayerError`), never guesses.
 
-The programs a cell's window runs are named per entry by `programs`.
-With a chip, one traced window reduced by layer:
+The programs a cell's window runs are named by its entry's
+`programs(run, state)` (`spec.py`).  `hkvbench/run.py --trace 1` reports
+the shares of every traced window.  With a chip, one traced window
+reduced by layer:
 
     python3 hkvbench/layers.py --workload <cell> --seed <n> --seconds <s> [--save <dir>]
 
@@ -248,42 +250,6 @@ def reduce(path, texts: dict) -> Split:
     return Split(seconds, busy, sum(instr_s.values()), mixed)
 
 
-# -- the programs each entry's window runs ------------------------------------
-
-
-def convert_element_type(x):
-    """The program `jnp.uint32(i)` runs on the device, by the name JAX
-    gives it (`jit_convert_element_type`)."""
-    import jax
-    import jax.numpy as jnp
-
-    return jax.lax.convert_element_type(x, jnp.uint32)
-
-
-def programs(entry: str, st) -> list:
-    """(program, arguments) of every program the entry's window runs, with
-    the shapes it ran them at; a compiled program comes with no arguments."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    conv = (jax.jit(convert_element_type), (np.uint32(0),))
-    if entry == "find":
-        s = st.table.state
-        kh, kl = st.keygen(s.key_hi, s.key_lo, st.kdata, jnp.uint32(0))
-        return [conv, (st.keygen, (s.key_hi, s.key_lo, st.kdata,
-                                   jnp.uint32(0))),
-                (st.find, (st.table, kh, kl))]
-    if entry == "train":
-        tok = st.gen(st.kdata, jnp.uint32(0), st.c)
-        rows = jax.eval_shape(st.step, st.table, tok)[1]
-        return [conv, (st.gen, (st.kdata, jnp.uint32(0), st.c)),
-                (st.step, (st.table, tok)), (st.pick, (rows, st.lanes))]
-    if entry == "ingest":
-        return [conv, (st.gen, (st.c, jnp.uint32(0))), (st.insert, None)]
-    raise LayerError(f"no program list for entry {entry!r}")
-
-
 def compiled_texts(progs) -> list:
     """The optimized HLO text of each (program, arguments)."""
     return [(fn if args is None else fn.lower(*args).compile()).as_text()
@@ -315,15 +281,14 @@ def main(argv=None) -> int:
         print(f"hkvbench.layers: {e}", file=sys.stderr)
         return 3
     run = harness.Run(cell, args.seed, args.seconds, None, devs)
-    entry = cell.traffic["entry"]
-    ent = spec.entry(entry)
+    ent = spec.entry(cell.traffic["entry"])
     st = ent.setup(run)
     tdir = spec.ROOT / ".hkvbench" / "trace" / f"layers.{cell.name}"
     with tracemod.Capture(tdir) as cap:
         with tracemod.annotate(tracemod.WINDOW_SPAN):
             win = ent.window(run, st)
     t0 = time.perf_counter()
-    texts = module_texts(compiled_texts(programs(entry, st)))
+    texts = module_texts(compiled_texts(ent.programs(run, st)))
     lower_s = time.perf_counter() - t0
     line = {"workload": cell.name, "seed": args.seed,
             "kv_s_traced": win.keys / win.seconds, "lower_s": lower_s,

@@ -5,6 +5,17 @@ configuration's `file`) and a traffic mix (`traffic/<name>.json`).  The
 mix's `entry` field names the entry module in `entries/<entry>.py`; every metric
 named in `BENCHMARK.json` is read by `metrics/<name>.py`.  Adding a cell,
 a mix or a metric therefore adds files and entries and edits none.
+
+An entry module declares, besides `setup`, `window`, `release`,
+`resident` and `check`:
+
+  TINY       ({configuration keys}, {traffic keys}) a CPU test run shrinks;
+  FAULTS     the planted faults (`harness.FAULTS`) its check must catch;
+  programs   `programs(run, state) -> [(program, arguments)]`: every
+             program its window runs, with the shapes it ran them at (a
+             compiled program comes with `None`), for `layers.reduce`;
+  counters   optional, `counters(run, state, window) -> dict`: the
+             entry's own counts for metric readers (`MetricCtx.counters`).
 """
 
 from __future__ import annotations
@@ -84,8 +95,32 @@ def reader(name: str):
     return load_module(HERE / "metrics" / f"{name}.py").read
 
 
-def entry(name: str):
-    return load_module(HERE / "entries" / f"{name}.py")
+class EntryError(AttributeError):
+    pass
+
+
+class Entry:
+    """Entry module `entries/<name>.py`; a name it does not declare is an
+    `EntryError` naming the entry and the name."""
+
+    def __init__(self, name: str, module):
+        self.name = name
+        self._module = module
+
+    def __getattr__(self, attr: str):
+        try:
+            return getattr(self._module, attr)
+        except AttributeError:
+            raise EntryError(f"entry {self.name!r} declares no {attr}") \
+                from None
+
+    def counters(self, run, state, win) -> dict:
+        fn = getattr(self._module, "counters", None)
+        return {} if fn is None else dict(fn(run, state, win))
+
+
+def entry(name: str) -> Entry:
+    return Entry(name, load_module(HERE / "entries" / f"{name}.py"))
 
 
 def byte_model(op: str):

@@ -148,7 +148,7 @@ def test_programs_of_every_entry_compile_to_distinct_modules(name):
     ent = spec.entry(entry)
     st = ent.setup(run)
     ent.window(run, st)
-    progs = layers.programs(entry, st)
+    progs = ent.programs(run, st)
     texts = layers.module_texts(layers.compiled_texts(progs))
     assert len(texts) == len(progs)
     assert "jit_convert_element_type" in texts

@@ -10,7 +10,9 @@ the `.xplane.pb` with `jax.profiler.ProfileData` and returns a `Summary`:
                loops and calls left out (their ops are listed too);
   gaps         idle stretches between device ops, each named by the
                benchmark's own host annotation (`hkv.*`) covering most
-               of it.
+               of it, longest first, cut to `max_gaps`;
+  idle_by_span idle seconds of the whole window by that name, uncut
+               (the first chip's, as `gaps`).
 
 Device planes are `/device:TPU:<n>`; their op events are on the line named
 `XLA Ops`, each named by its HLO instruction's text, in which a Pallas
@@ -41,6 +43,7 @@ class Summary:
     op_s: dict
     gaps: list            # [(host annotation, seconds)] longest first
     chips: int
+    idle_by_span: dict    # host annotation -> idle seconds in the window
 
 
 def op_name(event) -> str:
@@ -131,9 +134,12 @@ def reduce_trace(path, wall_s: float, max_gaps: int = 10) -> Summary:
                                if e > lo and s < hi], lo, hi):
                 gaps.append((_name_gap(a, b, spans), (b - a) * 1e-9))
     window_s = (win[0][1] - win[0][0]) * 1e-9 if win else wall_s
+    idle = {}
+    for name, s in gaps:
+        idle[name] = idle.get(name, 0.0) + s
     gaps.sort(key=lambda g: -g[1])
     return Summary(busy_s=busy / max(chips, 1), window_s=window_s, op_s=op_s,
-                   gaps=gaps[:max_gaps], chips=chips)
+                   gaps=gaps[:max_gaps], chips=chips, idle_by_span=idle)
 
 
 class Capture:
